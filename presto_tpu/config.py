@@ -153,22 +153,6 @@ class EngineConfig:
         v = self.props.get("query.log-path")
         return v if v and v.strip() not in ("0", "false") else None
 
-    def program_cache_dir(self) -> Optional[str]:
-        """Directory for the persistent XLA program cache
-        (``query.program-cache-dir``; ``0``/``false`` disables).  With
-        no explicit key, defaults under the warehouse root when a
-        warehouse catalog is configured — compiled query programs are
-        engine state and live with the data they serve."""
-        v = self.props.get("query.program-cache-dir")
-        if v is not None:
-            return None if v.strip() in ("", "0", "false") else v
-        for props in self.catalogs.values():
-            if (props.get("connector.name") == "warehouse"
-                    and props.get("warehouse.root")):
-                return os.path.join(props["warehouse.root"],
-                                    ".xla-program-cache")
-        return None
-
     # -- loading ------------------------------------------------------------
     @classmethod
     def from_etc(cls, etc_dir: str) -> "EngineConfig":

@@ -182,7 +182,16 @@ class MemoryConnector:
         return len(self._tables[table])
 
     def page_for_split(self, table: str, split: int, capacity: Optional[int] = None) -> Page:
-        return self._tables[table][split]
+        """Stored pages keep their load-time ladder capacity, which a
+        ragged last split makes smaller than its siblings'; a caller
+        that stacks splits (the mesh tier's waves) names the one
+        ``capacity`` it needs and gets dead-row padding up to it."""
+        page = self._tables[table][split]
+        if capacity is not None:
+            from presto_tpu.exec.local import pad_page_to
+
+            page = pad_page_to(page, capacity)
+        return page
 
     def row_count(self, table: str) -> int:
         import numpy as np

@@ -553,7 +553,7 @@ class LocalRunner:
                  task_concurrency: Optional[int] = None,
                  task_prefetch: Optional[int] = None):
         from presto_tpu.exec.programs import (
-            default_registry, maybe_enable_persistent_cache,
+            default_registry, enable_persistent_cache,
             structural_sharing_enabled,
         )
         from presto_tpu.exec.tasks import (
@@ -580,7 +580,7 @@ class LocalRunner:
         self.programs = programs if programs is not None else default_registry()
         self._structural = structural_sharing_enabled()
         self._own_registry = None  # per-node keying when sharing is off
-        maybe_enable_persistent_cache()
+        enable_persistent_cache()
         # env-dependent kernel choices resolve ONCE at construction —
         # not per join build (satellite of the registry PR)
         resolve_direct_join()

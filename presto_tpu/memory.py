@@ -212,27 +212,27 @@ def detected_memory_limit() -> int:
     """Accountable-memory budget for the default pool: 90% of the
     device's reported HBM on an accelerator, half of host RAM on the
     CPU backend.  PRESTO_TPU_MEMORY_LIMIT_BYTES overrides (testing and
-    deployments with reserved headroom)."""
+    deployments with reserved headroom).  An accelerator that reports
+    no limit is an error: sizing its pool from host RAM would admit
+    work the device cannot hold."""
     import os
 
     env = os.environ.get("PRESTO_TPU_MEMORY_LIMIT_BYTES")
     if env:
         return int(env)
-    try:
-        import jax
+    import jax
 
-        stats = jax.devices()[0].memory_stats() or {}
-        limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
-        if limit:
-            return int(limit * 0.9)
-    except Exception:
-        pass
-    try:
-        with open("/proc/meminfo") as f:
-            kb = int(next(ln for ln in f if ln.startswith("MemTotal")).split()[1])
-        return kb * 1024 // 2
-    except Exception:
-        return 16 << 30
+    dev = jax.devices()[0]
+    if dev.platform != "cpu":
+        limit = (dev.memory_stats() or {}).get("bytes_limit")
+        if not limit:
+            raise RuntimeError(
+                f"{dev.platform} device {dev.device_kind!r} reports no "
+                f"bytes_limit; set PRESTO_TPU_MEMORY_LIMIT_BYTES")
+        return int(limit * 0.9)
+    with open("/proc/meminfo") as f:
+        kb = int(next(ln for ln in f if ln.startswith("MemTotal")).split()[1])
+    return kb * 1024 // 2
 
 
 def default_memory_pool() -> MemoryPool:

@@ -30,9 +30,9 @@ from presto_tpu.events import (
 from presto_tpu.obs.trace import Tracer
 
 def _normalize_dir(path: Optional[str]) -> Optional[str]:
-    """Shared disable convention with the sibling config keys
-    (program_cache_dir, query_log_path): empty / ``0`` / ``false``
-    means disabled, not a directory literally named ``0``."""
+    """Shared disable convention with the sibling config key
+    (query_log_path): empty / ``0`` / ``false`` means disabled, not a
+    directory literally named ``0``."""
     if path is None or path.strip() in ("", "0", "false"):
         return None
     return path

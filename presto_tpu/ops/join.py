@@ -49,8 +49,9 @@ DIRECT_DOMAIN_PER_ROW = 64
 # Direct-table / unique-direct selection resolves ONCE per process
 # (first runner construction warms it) instead of re-reading the
 # environment inside every build_join call — the per-build hot path.
-# The explicit override hooks exist for the A/B harness
-# (tools/tpu_ab_direct_join.py) and tests, which flip legs in-process.
+# The explicit override hooks exist for tests, which flip legs
+# in-process (tier-1 runs on XLA:CPU and must still run the leg the
+# chip selects).
 _DIRECT_JOIN_RESOLVED: "Optional[bool]" = None
 _UNIQUE_DIRECT_RESOLVED: "Optional[bool]" = None
 

@@ -39,13 +39,8 @@ _log = logging.getLogger("presto_tpu.dist")
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# shard_map moved from jax.experimental to the jax namespace around
-# 0.6; resolve whichever this build ships so the mesh tier runs on both
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:  # pragma: no cover - depends on the jax build
-    from jax.experimental.shard_map import shard_map
 
 from presto_tpu.catalog import Catalog
 from presto_tpu.exec.local import (

@@ -32,12 +32,16 @@ def year_of(days: "pd.Series") -> "pd.Series":
     return pd.to_datetime(days, unit="D").dt.year
 
 
-def load_frames(conn) -> dict:
+def load_frames(conn, columns=None) -> dict:
     """Decode the generator's columns into DataFrames (strings decoded,
-    decimals scaled to float, dates as int days)."""
+    decimals scaled to float, dates as int days).  ``columns`` maps
+    table -> column names and restricts the frames to those (at scale,
+    decoding every comment column of every table is most of the cost);
+    None decodes everything."""
     frames = {}
-    for table in conn.table_names():
-        schema = conn.schema(table)
+    for table in (conn.table_names() if columns is None else columns):
+        schema = [(name, t) for name, t in conn.schema(table)
+                  if columns is None or name in columns[table]]
         parts = []
         for split in range(conn.num_splits(table)):
             data = conn.generate_split(table, split)

@@ -11,8 +11,8 @@ import pytest
 
 from presto_tpu.catalog import Catalog
 from presto_tpu.exec.programs import (
-    ProgramRegistry, default_registry, disable_persistent_cache,
-    enable_persistent_cache, ir_signature, persistent_cache_stats,
+    ProgramRegistry, default_registry, ir_signature,
+    persistent_cache_stats,
 )
 from presto_tpu.runner import QueryRunner
 from tests.tpch_queries import QUERIES
@@ -84,19 +84,32 @@ def test_persistent_cache_second_registry_hits(tmp_path):
     """A second registry (fresh jit caches, same cache dir) must
     rehydrate serialized XLA binaries: persistent hits recorded and
     the programs recompile from disk, not from scratch."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # the engine never moves the cache once a runner exists, so the
+    # test points jax at its temporary directory itself; jax binds its
+    # cache object to a directory once, hence the resets
+    suite_dir = jax.config.jax_compilation_cache_dir
     cache_dir = str(tmp_path / "xla-cache")
-    enable_persistent_cache(cache_dir)
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    compilation_cache.reset_cache()
     try:
         runner, _ = _fresh_runner()
+        cold = persistent_cache_stats()
+        assert cold["dir"] == cache_dir
         runner.execute("SELECT sum(n_regionkey) FROM nation")
         jax.clear_caches()  # drop in-process executables, keep disk
-        hits0 = persistent_cache_stats()["persistent_hits"]
+        stats0 = persistent_cache_stats()
+        # every cold compile was written to the empty directory
+        assert stats0["persistent_misses"] > cold["persistent_misses"]
         runner2, reg2 = _fresh_runner()
         runner2.execute("SELECT sum(n_regionkey) FROM nation")
-        assert persistent_cache_stats()["persistent_hits"] > hits0
+        assert (persistent_cache_stats()["persistent_hits"]
+                > stats0["persistent_hits"])
         assert reg2.program_count() > 0
     finally:
-        disable_persistent_cache()
+        jax.config.update("jax_compilation_cache_dir", suite_dir)
+        compilation_cache.reset_cache()
 
 
 def test_ir_signature_distinguishes_lossy_reprs():
@@ -169,3 +182,46 @@ def test_registry_lru_eviction_bounds_callables():
     misses = reg.misses
     reg.get("k", ("sig", 9), lambda: (lambda x: x), jit=False)
     assert reg.misses == misses and reg.hits == 1
+
+
+def _child_cache_dir(placed):
+    """``jax_compilation_cache_dir`` in a fresh process after the
+    first QueryRunner exists, with JAX_COMPILATION_CACHE_DIR=placed
+    (None: unset)."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["JAX_PLATFORMS"] = "cpu"
+    if placed is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = placed
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         "import jax, presto_tpu\n"
+         "from presto_tpu.catalog import Catalog\n"
+         "from presto_tpu.runner import QueryRunner\n"
+         "QueryRunner(Catalog())\n"
+         "print('DIR=' + str(jax.config.jax_compilation_cache_dir))"],
+        cwd=root, env=env, capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-500:]
+    return [ln[4:] for ln in proc.stdout.splitlines()
+            if ln.startswith("DIR=")][-1], root
+
+
+def test_cache_dir_placed_from_outside(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: no code path moves the cache."""
+    placed = str(tmp_path / "placed")
+    got, _ = _child_cache_dir(placed)
+    assert got == placed
+
+
+def test_cache_dir_defaults_to_checkout():
+    """Unset: the fixed <checkout>/.jax_cache, not a path built from a
+    tmpdir, a pid or a data root."""
+    import os
+
+    got, root = _child_cache_dir(None)
+    assert got == os.path.join(root, ".jax_cache")

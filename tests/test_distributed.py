@@ -374,3 +374,35 @@ def test_per_shard_limit_bound(env):
     keys = {r[0] for r in runner.execute(
         "select l_orderkey from lineitem where l_quantity > 30").rows}
     assert all(r[0] in keys for r in got.rows)
+
+
+# ---------------------------------------------------------------------------
+# device-resident tables: the connector every benchmark run uses
+# ---------------------------------------------------------------------------
+
+def test_distributed_over_resident_tables_with_ragged_last_split():
+    """Load-time ladder padding leaves a MemoryConnector's last split
+    at a smaller capacity than its siblings; the mesh tier stacks one
+    split per device into a wave page, so every split must come back at
+    the one capacity it asks for (this crashed in ``_stack_pages`` with
+    "all input arrays must have the same shape")."""
+    from presto_tpu.connectors.memory import MemoryConnector
+
+    tpch = Tpch(sf=0.01, split_rows=4096)
+    mem = MemoryConnector()
+    for table in ("lineitem", "orders", "customer"):
+        mem.load_from(tpch, table)
+    caps = {mem.page_for_split("lineitem", s).capacity
+            for s in range(mem.num_splits("lineitem"))}
+    assert len(caps) > 1, "fixture lost its ragged split"
+    catalog = Catalog()
+    catalog.register("mem", mem)
+    runner = QueryRunner(catalog)
+    for qid in (6, 3):
+        expected = runner.execute(QUERIES[qid]).rows
+        runner.execute("SET SESSION distributed = true")
+        res = runner.execute(QUERIES[qid])
+        runner.execute("SET SESSION distributed = false")
+        assert res.dist_fallback is None and res.dist_stages >= 1, (
+            qid, res.dist_fallback, res.dist_stages)
+        assert res.rows == expected

@@ -297,8 +297,9 @@ def test_benchmark_driver_runs_suite():
     proc = subprocess.run(
         [sys.executable, os.path.join(root, "tools", "benchmark_driver.py"),
          "--suite", "tpch", "--queries", "q1,q6", "--sf", "0.001",
-         "--runs", "1", "--cpu", "--json"],
+         "--runs", "1", "--json"],
         cwd=root, stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=300,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
     assert proc.returncode == 0, proc.stderr.decode()[-500:]
     import json as _json
@@ -306,3 +307,49 @@ def test_benchmark_driver_runs_suite():
     rows = [_json.loads(l) for l in proc.stdout.decode().splitlines()]
     assert {r["query"] for r in rows} == {"q1", "q6"}
     assert all("median_s" in r for r in rows)
+
+
+@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
+def test_chip_scripts_refuse_a_cpu(script):
+    """Neither script measures or passes on a device that is not a
+    TPU: non-zero exit, the device found named, no result line."""
+    import subprocess
+    import sys
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, script)], cwd=root,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, timeout=120)
+    assert proc.returncode != 0
+    assert "'platform': 'cpu'" in proc.stderr, proc.stderr[-500:]
+    assert proc.stdout.strip() == ""
+
+
+def test_chip_smoke_last_line_is_the_verdict():
+    """The last line of a passing smoke's standard output is exactly
+    ``{"ok": ..., "device": {"platform", "kind", "count"}}``; the
+    observations are the line before it.  Run as the explicit CPU
+    rehearsal, which says so in ``platform``."""
+    import json as _json
+    import subprocess
+    import sys
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, "chip_smoke.py"),
+         "--cpu-rehearsal", "--sf", "0.01"], cwd=root,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    observations, verdict = map(_json.loads, proc.stdout.splitlines())
+    assert set(verdict) == {"ok", "device"} and verdict["ok"] is True
+    device = verdict["device"]
+    assert set(device) == {"platform", "kind", "count"}
+    assert device["platform"] == "cpu" and isinstance(device["kind"], str)
+    assert type(device["count"]) is int and device["count"] >= 1
+    assert observations["device"] == device
+    assert observations["reduced"] and set(observations["one_chip"]["queries"]) \
+        == {"q6", "q14", "q1", "q3"}
