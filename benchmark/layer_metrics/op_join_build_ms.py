@@ -1,0 +1,14 @@
+"""Kernels: per traced pass, the time chip 0 ran operations under the
+scope ``op:JoinBuild`` (``exec/local._materialize_build``'s
+``join_build`` program); median over the traced passes.  Cells with a
+join."""
+
+from benchmark import scopes
+
+NAME = "op_join_build_ms"
+UNIT = "ms"
+WORKLOADS = ["tpch_sf10.join", "tpch_sf1.join_agg"]
+
+
+def read(run):
+    return scopes.ms_per_pass(run, "op:JoinBuild")

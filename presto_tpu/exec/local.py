@@ -23,6 +23,7 @@ LookupJoinPageBuilder).
 from __future__ import annotations
 
 import dataclasses
+import threading
 from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
@@ -375,6 +376,63 @@ def _split_pruned(constraints, stats) -> bool:
     return td.is_none or not td.overlaps_split_stats(stats)
 
 
+def _named(f, name: str):
+    """``f`` under ``name``.  XLA calls a program ``jit_<the function's
+    name>``, which is what a device trace shows and part of the
+    persistent compile cache's key (scopes alone are not: jax strips
+    metadata before it hashes).  So the name must be a function of the
+    program's kind and structure only — nothing from ``ir_signature``,
+    ``id`` or ``hash``, which differ between processes and would make
+    every start a cold one."""
+    f.__name__ = f.__qualname__ = name
+    return f
+
+
+def _chain_name(sig) -> str:
+    """A chain program's name from its ``_stage_signature``: the stage
+    tags leaf first, an aggregation tagged with its counts of keys and
+    aggregates (``chain_leaf_filter_agg_k2a8`` is TPC-H q1's)."""
+    tags = []
+    while True:
+        tag = sig[0]
+        if tag == "agg_partial":
+            tag = f"agg_k{len(sig[1])}a{len(sig[2])}"
+        tags.append(tag)
+        if tag == "leaf":
+            return "chain_" + "_".join(reversed(tags))
+        sig = sig[-1]
+
+
+_HOST_READS = threading.local()  # .n: this thread's reads, never reset
+
+
+def host_reads() -> int:
+    """Blocking host reads this thread has made so far.  A query's
+    count is the difference across it: the reads are made on the
+    query's consumer thread (like ``_task_stats``), never on a
+    scheduler worker, and nothing is kept on the shared runner."""
+    return getattr(_HOST_READS, "n", 0)
+
+
+def host_read(x, why: str):
+    """``x``, a device array or a pytree of them, as NumPy on the
+    host.  Every read on the execution path that makes the host wait
+    for the device goes through here, so that each is a
+    ``host_read:<why>`` span when the query traces (what a device-idle
+    gap is booked to), one of ``device.get_calls`` / ``device.get_bytes``
+    and one of the query's ``hostReads``.  Not routed here: the
+    ``collect_stats`` row count, the range sanitizer, ``parallel/``."""
+    from presto_tpu.obs import METRICS, span
+
+    with span("host_read:" + why, cat="device"):
+        out = jax.device_get(x)
+    _HOST_READS.n = getattr(_HOST_READS, "n", 0) + 1
+    METRICS.counter("device.get_calls").inc()
+    METRICS.counter("device.get_bytes").inc(sum(
+        getattr(a, "nbytes", 0) for a in jax.tree_util.tree_leaves(out)))
+    return out
+
+
 @jax.jit
 def _extent_live(mask):
     """(highest live index + 1, live count) of a row mask, as one
@@ -437,16 +495,20 @@ class _AggFoldTower:
         fns = runner._fold_cache.get(cache_key)
         if fns is None:
             def fold(pages, out_cap):
-                return merge_aggregate(
-                    concat_pages_device(list(pages)), num_keys, list(aggs),
-                    out_cap, key_domains=kd, mode="partial",
-                    return_count=True)
+                with jax.named_scope("op:Aggregation"):
+                    return merge_aggregate(
+                        concat_pages_device(list(pages)), num_keys,
+                        list(aggs), out_cap, key_domains=kd, mode="partial",
+                        return_count=True)
 
             def final(pages, out_cap):
-                return merge_aggregate(
-                    concat_pages_device(list(pages)), num_keys, list(aggs),
-                    out_cap, key_domains=kd, mode="single")
+                with jax.named_scope("op:Aggregation"):
+                    return merge_aggregate(
+                        concat_pages_device(list(pages)), num_keys,
+                        list(aggs), out_cap, key_domains=kd, mode="single")
 
+            _named(fold, "agg_tower_fold")
+            _named(final, "agg_tower_final")
             sig = (num_keys, tuple(aggs), tuple(kd or ()))
             fold_p = runner._program(
                 "agg_tower_fold", sig,
@@ -479,7 +541,7 @@ class _AggFoldTower:
             f"agg_accumulator@{id(self.node)}", page_bytes(page))
 
     def add(self, page: Page) -> None:
-        el = np.asarray(_extent_live(page.row_mask))
+        el = host_read(_extent_live(page.row_mask), "extent")
         extent, live = int(el[0]), int(el[1])
         self.live_total += live
         if live >= self.mg:
@@ -498,7 +560,7 @@ class _AggFoldTower:
             # between cap and 2*cap, compiling two programs per level
             out_cap = 2 * cap
             page, cnt = self.fold([o_page, page], out_cap=out_cap)
-            live = min(int(np.asarray(cnt)), out_cap)
+            live = min(int(host_read(cnt, "fold_count")), out_cap)
             if mem is not None:
                 mem.free(tag)
                 mem.free(o_tag)
@@ -526,7 +588,7 @@ def _probe_with_retry(probe_fn, build, page):
     compiled probe program instead of one per observed match count."""
     cap = max(int(page.capacity), 1024)
     res = probe_fn(build, page, cap)
-    total = int(np.asarray(res[1]))
+    total = int(host_read(res[1], "probe_total"))
     if total > cap:
         res = probe_fn(build, page, bucket_capacity(total))
     return res
@@ -636,23 +698,14 @@ class LocalRunner:
 
     # ------------------------------------------------------------------
     def run(self, plan: PlanNode, query_id: Optional[str] = None) -> MaterializedResult:
-        from presto_tpu.obs import METRICS, record_point, span
+        from presto_tpu.obs import span
 
         page = self.run_to_page(plan, query_id=query_id)
-        # the result transfer is THE device sync of a local query — a
-        # span + counters so host-transfer time/bytes are attributable
-        # (the device_get tax EXPLAIN could not see before)
+        # the result transfer is the last device sync of a local query:
+        # the read itself is host_read:result, the span around it also
+        # times compaction and the conversion to Python rows
         with span("device_get", cat="device"):
-            out = page.compact_host()
-            rows = out.to_pylist()
-        METRICS.counter("device.get_calls").inc()
-        record_point("device.get_calls", 1.0)
-        try:
-            from presto_tpu.memory import page_bytes
-
-            METRICS.counter("device.get_bytes").inc(page_bytes(out))
-        except Exception:
-            pass  # byte accounting is best-effort on exotic pages
+            rows = host_read(page, "result").compact_host().to_pylist()
         return MaterializedResult(
             names=plan.output_names,
             types=plan.output_types,
@@ -1153,7 +1206,7 @@ class LocalRunner:
                 if remaining <= 0:
                     return
                 p = limit_page(p, remaining)
-                remaining -= int(np.asarray(p.num_rows()))
+                remaining -= int(host_read(p.num_rows(), "limit_rows"))
                 yield p
             return
 
@@ -1165,8 +1218,10 @@ class LocalRunner:
             if fn is None:
 
                 def do_sort(p):
-                    return sort_page(p, sort_exprs, ascending, nulls_first)
+                    with jax.named_scope("op:Sort"):
+                        return sort_page(p, sort_exprs, ascending, nulls_first)
 
+                _named(do_sort, "sort")
                 fn = self._program(
                     "sort", (sort_exprs, ascending, nulls_first),
                     lambda: jax.jit(do_sort) if self.jit else do_sort,
@@ -1340,8 +1395,10 @@ class LocalRunner:
         if node in self._chain_cache:
             fn = self._chain_cache[node]
         else:
+            sig = self._stage_signature(node)
+            _named(stage, _chain_name(sig))
             fn = self._program(
-                "chain", self._stage_signature(node),
+                "chain", sig,
                 lambda: jax.jit(stage) if self.jit else stage, node=node)
             self._chain_cache[node] = fn
         mem = self._mem
@@ -1452,12 +1509,24 @@ class LocalRunner:
         if isinstance(node, FilterNode):
             inner = self._build_stage(node.source, joins)
             pred = node.predicate
-            return lambda p, c: filter_page(inner(p, c), pred)
+
+            def filter_stage(p, c):
+                p = inner(p, c)
+                with jax.named_scope("op:Filter"):
+                    return filter_page(p, pred)
+
+            return filter_stage
 
         if isinstance(node, ProjectNode):
             inner = self._build_stage(node.source, joins)
             projections = list(node.projections)
-            return lambda p, c: project_page(inner(p, c), projections)
+
+            def project_stage(p, c):
+                p = inner(p, c)
+                with jax.named_scope("op:Project"):
+                    return project_page(p, projections)
+
+            return project_stage
 
         if isinstance(node, AggregationNode) and node.step == "partial":
             inner = self._build_stage(node.source, joins)
@@ -1468,10 +1537,12 @@ class LocalRunner:
             presorted = node.presorted
 
             def agg_stage(p, c):
-                return grouped_aggregate(
-                    inner(p, c), group_exprs, aggs, mg, key_domains=kd,
-                    mode="partial", presorted=presorted,
-                )
+                p = inner(p, c)
+                with jax.named_scope("op:Aggregation"):
+                    return grouped_aggregate(
+                        p, group_exprs, aggs, mg, key_domains=kd,
+                        mode="partial", presorted=presorted,
+                    )
 
             return agg_stage
 
@@ -1487,11 +1558,13 @@ class LocalRunner:
             na = getattr(node, "null_aware", False)
 
             def probe_stage(p, c):
-                return probe_join(
-                    c[key], inner(p, c), left_keys, key_domains=kd,
-                    kind=kind, build_output=build_output, null_safe=ns,
-                    null_aware=na,
-                )
+                p = inner(p, c)
+                with jax.named_scope("op:Join"):
+                    return probe_join(
+                        c[key], p, left_keys, key_domains=kd,
+                        kind=kind, build_output=build_output, null_safe=ns,
+                        null_aware=na,
+                    )
 
             return probe_stage
 
@@ -1501,7 +1574,9 @@ class LocalRunner:
             joins.append(node)
 
             def cross_stage(p, c):
-                return cross_append_single(inner(p, c), c[key])
+                p = inner(p, c)
+                with jax.named_scope("op:CrossSingle"):
+                    return cross_append_single(p, c[key])
 
             return cross_stage
 
@@ -1608,9 +1683,8 @@ class LocalRunner:
                         int(sample[1] * 100))
                     page = Page(page.blocks, page.row_mask & keep)
                 if node.limit is not None:
-                    import numpy as _np
-
-                    produced += int(_np.asarray(page.row_mask).sum())
+                    produced += int(
+                        host_read(page.row_mask, "limit_rows").sum())
                 raw = Page(tuple(page.blocks[i] for i in idx), page.row_mask)
                 if uniform and 0 < raw.capacity <= cap_hi \
                         and raw.capacity * 3 >= cap_hi:
@@ -1630,7 +1704,8 @@ class LocalRunner:
         if node not in self._builds:
             if isinstance(node, CrossSingleNode):
                 build_page = self._execute_to_page(node.right)
-                self._builds[node] = slice_page(build_page.compact_host(), 1)
+                self._builds[node] = slice_page(
+                    host_read(build_page, "cross_single").compact_host(), 1)
             else:
                 pages = tuple(self._pages(node.right))
                 if not pages:
@@ -1647,12 +1722,15 @@ class LocalRunner:
                             # producers' caps — a data-dependent shape
                             # every downstream probe program would bake
                             # in; padding dead rows restores the ladder)
-                            return build_join(
-                                pad_page_pow2(concat_pages_device(list(ps))),
-                                right_keys,
-                                key_domains=kd, null_safe=ns, unique=_u,
-                            )
+                            with jax.named_scope("op:JoinBuild"):
+                                return build_join(
+                                    pad_page_pow2(
+                                        concat_pages_device(list(ps))),
+                                    right_keys,
+                                    key_domains=kd, null_safe=ns, unique=_u,
+                                )
 
+                        _named(make_build, "join_build")
                         fn = self._program(
                             "join_build",
                             (right_keys, tuple(kd or ()), ns, uniq),
@@ -1664,7 +1742,8 @@ class LocalRunner:
 
                 uniq = bool(getattr(node, "unique_build", False))
                 build = build_fn(uniq)(pages)
-                if build.unique_ok is not None and not bool(build.unique_ok):
+                if build.unique_ok is not None and not bool(
+                        host_read(build.unique_ok, "unique_ok")):
                     # the planner's uniqueness promise failed at runtime
                     # (PagesHash would have chained): rebuild sorted
                     build = build_fn(False)(pages)
@@ -1697,17 +1776,20 @@ class LocalRunner:
         ns = node.null_safe_keys
 
         def probe(b, p, out_capacity):
-            return probe_expand(
-                b, p, left_keys, out_capacity, key_domains=kd,
-                kind=kind, build_output=build_output, return_matched=is_full,
-                null_safe=ns,
-            )
+            with jax.named_scope("op:Join"):
+                return probe_expand(
+                    b, p, left_keys, out_capacity, key_domains=kd,
+                    kind=kind, build_output=build_output,
+                    return_matched=is_full, null_safe=ns,
+                )
+
+        _named(probe, "join_probe")
 
         if node in self._chain_cache:
             fn = self._chain_cache[node]
         else:
             fn = self._program(
-                "probe_expand",
+                "join_probe",
                 (left_keys, tuple(kd or ()), kind, tuple(build_output),
                  is_full, ns),
                 lambda: jax.jit(probe, static_argnames=("out_capacity",))
@@ -1960,10 +2042,13 @@ class LocalRunner:
         nulls_first = node.nulls_first
 
         def fold(acc: Optional[Page], p: Page) -> Page:
-            cand = p if acc is None else concat_pages_device([acc, p])
-            s = sort_page(cand, sort_exprs, ascending, nulls_first)
-            keep = jnp.arange(s.capacity) < n
-            return slice_page(Page(s.blocks, s.row_mask & keep), n)
+            with jax.named_scope("op:TopN"):
+                cand = p if acc is None else concat_pages_device([acc, p])
+                s = sort_page(cand, sort_exprs, ascending, nulls_first)
+                keep = jnp.arange(s.capacity) < n
+                return slice_page(Page(s.blocks, s.row_mask & keep), n)
+
+        _named(fold, "topn")
 
         fold_fn = self._fold_cache.get(node)
         if fold_fn is None:
@@ -2277,10 +2362,15 @@ class LocalRunner:
             def fold_pk(acc: Optional[Page], p: Page) -> Page:
                 if acc is None:
                     return p
-                return combine_packed_states(acc, p, num_keys, aggs)
+                with jax.named_scope("op:Aggregation"):
+                    return combine_packed_states(acc, p, num_keys, aggs)
 
             def final_pk(acc: Page) -> Page:
-                return finalize_packed(acc, num_keys, aggs)
+                with jax.named_scope("op:Aggregation"):
+                    return finalize_packed(acc, num_keys, aggs)
+
+            _named(fold_pk, "agg_packed_fold")
+            _named(final_pk, "agg_packed_final")
 
             fold_fn, final_fn = self._fold_cache.get(node, (None, None))
             if fold_fn is None:
@@ -2311,11 +2401,18 @@ class LocalRunner:
         # fixed-capacity running fold — pages are already as tight as the
         # key domain allows, so compaction buys nothing
         def fold(acc: Optional[Page], p: Page) -> Page:
-            cand = p if acc is None else concat_pages_device([acc, p])
-            return merge_aggregate(cand, num_keys, aggs, mg, key_domains=kd, mode="partial")
+            with jax.named_scope("op:Aggregation"):
+                cand = p if acc is None else concat_pages_device([acc, p])
+                return merge_aggregate(cand, num_keys, aggs, mg,
+                                       key_domains=kd, mode="partial")
 
         def final(acc: Page) -> Page:
-            return merge_aggregate(acc, num_keys, aggs, mg, key_domains=kd, mode="single")
+            with jax.named_scope("op:Aggregation"):
+                return merge_aggregate(acc, num_keys, aggs, mg,
+                                       key_domains=kd, mode="single")
+
+        _named(fold, "agg_fold")
+        _named(final, "agg_final")
 
         fold_fn, final_fn = self._fold_cache.get(node, (None, None))
         if fold_fn is None:
@@ -2368,7 +2465,7 @@ class LocalRunner:
         empty_gids = [gid for gid, m in enumerate(src.set_masks) if not any(m)]
         if not empty_gids:
             return out
-        if int(np.asarray(jnp.sum(out.row_mask.astype(jnp.int32)))) > 0:
+        if int(host_read(out.num_rows(), "live_count")) > 0:
             return out
         nkeys = len(node.group_exprs) - 1  # last group expr is $group_id
         types = node.output_types
@@ -2420,7 +2517,7 @@ class LocalRunner:
     def _check_overflow(self, node: AggregationNode, out: Page, mg: int) -> None:
         if not node.group_exprs or self._exact_capacity(node, mg):
             return
-        live = int(np.asarray(jnp.sum(out.row_mask.astype(jnp.int32))))
+        live = int(host_read(out.num_rows(), "live_count"))
         if live >= mg and mg < MAX_AGG_GROUPS:
             self._agg_overrides[node] = mg * 2
             self._invalidate_agg_caches(node)

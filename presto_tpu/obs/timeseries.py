@@ -26,7 +26,7 @@ telemetry without limit):
 2. :class:`QueryTimeline` — one bounded per-query buffer of
    ``(ts_ms, metric, value)`` points appended by the runner/exec/
    parallel hot paths (memory reservation, exchange buffered bytes,
-   splits done per stage, device dispatches, admission queue depth),
+   splits done per stage, admission queue depth),
    plus an ``annotations`` dict of per-query scalars the doctor
    consumes (queued/memory-blocked ms, spill bytes, producer stall,
    per-partition row counts, per-worker fragment durations, findings).

@@ -386,6 +386,7 @@ def _seg_assoc(op, identity, vals, gid, n):
     return jnp.where(present, scanned[ends], identity)
 
 
+@jax.named_scope("agg:reduce")
 def _partial_states(page: Page, aggs: Sequence[AggCall], gid: jax.Array, n: int,
                     ctx: "Optional[_SortCtx]" = None):
     """Compute per-group state columns for each aggregate.
@@ -1050,6 +1051,7 @@ def _topn_halves(ctx, egid, keys, vals, sel, n, cap_e, storage,
     return halves, gcnt
 
 
+@jax.named_scope("agg:reduce")
 def _merge_states(state_cols: List[List[jax.Array]], aggs, gid, n,
                   ctx: "Optional[_SortCtx]" = None):
     """Merge partial-state rows (one row per upstream group) into final
@@ -1773,6 +1775,7 @@ class _SortCtx:
         return jnp.where(glive, seg, jnp.zeros_like(seg))
 
 
+@jax.named_scope("agg:sort")
 def _sorted_group_ids(key: jax.Array, live: jax.Array, max_groups: int,
                       want_ctx: bool = False):
     """Shared sort-path grouping: returns per-row group ids (dead rows
@@ -1814,6 +1817,7 @@ def _sorted_group_ids(key: jax.Array, live: jax.Array, max_groups: int,
     return gid, num_groups, rep_rows, ctx
 
 
+@jax.named_scope("agg:sort")
 def _presorted_group_ids(key: jax.Array, live: jax.Array, max_groups: int):
     """Streaming-aggregation grouping (StreamingAggregationOperator.java:38
     analog): input rows arrive grouped (equal keys contiguous), so run

@@ -249,6 +249,7 @@ def build_null_flags(page: Page, key_exprs: Sequence[Expr]):
             jnp.any(page.row_mask))
 
 
+@jax.named_scope("join:lookup")
 def _lookup_first(build: JoinBuild, key: jax.Array):
     """(candidate sorted position, key-match mask) per probe row."""
     if build.starts is not None:
@@ -263,6 +264,7 @@ def _lookup_first(build: JoinBuild, key: jax.Array):
     return pos_c, build.sorted_keys[pos_c] == key
 
 
+@jax.named_scope("join:lookup")
 def _lookup_range(build: JoinBuild, key: jax.Array):
     """[lo, hi) sorted-position match range per probe row."""
     if build.starts is not None:

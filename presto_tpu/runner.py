@@ -13,7 +13,9 @@ from __future__ import annotations
 from typing import Optional
 
 from presto_tpu.catalog import Catalog
-from presto_tpu.exec.local import LocalRunner, MaterializedResult, QueryStats
+from presto_tpu.exec.local import (
+    LocalRunner, MaterializedResult, QueryStats, host_reads,
+)
 from presto_tpu.session import Session
 from presto_tpu.sql import ast
 from presto_tpu.sql.binder import Binder
@@ -240,6 +242,7 @@ class QueryRunner:
                     # opt-in (one device sync per page)
                     qstats = (QueryStats()
                               if self.session.get("collect_stats") else None)
+                    reads0 = host_reads()
                     with obs.span("execute", cat="lifecycle"):
                         res = None
                         if prepared is not None:
@@ -296,6 +299,9 @@ class QueryRunner:
             res.planning_ms = self._ms(planning_s)
             res.compile_ms = compile_ms
             res.execution_ms = self._ms(execution_s)
+            # blocking device reads this query made on this thread
+            # (exec/local.host_read); 0 for a result-cache hit
+            res.host_reads = host_reads() - reads0
             # serving-tier surfaces: whether this result came from the
             # structural cache, and the executor's observed peak bytes
             # (the admission controller's projection source for the

@@ -199,6 +199,7 @@ class _QueryState:
         self.planning_ms: Optional[float] = None
         self.compile_ms: Optional[float] = None
         self.execution_ms: Optional[float] = None
+        self.host_reads: Optional[int] = None
         # client-supplied request correlation (X-Presto-Trace-Token)
         self.trace_token: Optional[str] = None
         # deadline bookkeeping: the effective limit (None = none) and
@@ -811,6 +812,7 @@ class CoordinatorServer:
                 q.planning_ms = getattr(res, "planning_ms", None)
                 q.compile_ms = getattr(res, "compile_ms", None)
                 q.execution_ms = getattr(res, "execution_ms", None)
+                q.host_reads = getattr(res, "host_reads", None)
                 q.cache_hit = getattr(res, "cache_hit", None)
                 q.queued_ms = getattr(res, "queued_ms", None)
                 q.memory_blocked_ms = getattr(res, "memory_blocked_ms",
@@ -902,6 +904,8 @@ class CoordinatorServer:
             out["stats"]["compileMs"] = q.compile_ms
         if q.execution_ms is not None:
             out["stats"]["executionMs"] = q.execution_ms
+        if q.host_reads is not None:
+            out["stats"]["hostReads"] = q.host_reads
         # serving tier: result provenance (structural result cache)
         if q.cache_hit is not None:
             out["stats"]["cacheHit"] = q.cache_hit

@@ -420,3 +420,223 @@ def test_coordinator_trace_endpoint_and_stage_stats():
                 f"{srv.uri}/v1/query/nope/trace", timeout=10)
     finally:
         srv.stop()
+
+
+# ---------------------------------------------------------------------------
+# names inside the compiled programs, and host reads
+# ---------------------------------------------------------------------------
+
+# q6 -> q14 -> q1 -> q3 at SF0.01: every program each query adds, as
+# XLA names its module, with the scopes its lowered text holds.  The
+# names enter the persistent compile cache's key: a change here makes
+# every deployment's next start a cold one, so it is pinned letter for
+# letter.  (chain_leaf and the first chain_leaf_project move columns
+# only and hold no operation to scope.)
+_FILTER_AGG = {"op:Filter", "op:Aggregation", "agg:reduce"}
+_PROBE_AGG = _FILTER_AGG | {"op:Join", "join:lookup"}
+PROGRAMS = {
+    6: {"jit_chain_leaf_filter_agg_k0a1": _FILTER_AGG,
+        "jit_agg_fold": {"op:Aggregation", "agg:reduce"},
+        "jit_agg_final": {"op:Aggregation", "agg:reduce"},
+        "jit_chain_leaf_project": set()},
+    14: {"jit_chain_leaf": set(),
+         "jit_join_build": {"op:JoinBuild"},
+         "jit_chain_leaf_filter_probe_agg_k0a2": _PROBE_AGG,
+         "jit_agg_fold": {"op:Aggregation", "agg:reduce"},
+         "jit_agg_final": {"op:Aggregation", "agg:reduce"},
+         "jit_chain_leaf_project": {"op:Project"}},
+    1: {"jit_chain_leaf_filter_agg_k2a8": _FILTER_AGG,
+        "jit_agg_packed_final": {"op:Aggregation"},
+        "jit_chain_leaf_project": set(),
+        "jit_sort": {"op:Sort"}},
+    3: {"jit_chain_leaf_filter": {"op:Filter"},
+        "jit_join_build": {"op:JoinBuild"},
+        "jit_chain_leaf_filter_probe": {"op:Filter", "op:Join",
+                                        "join:lookup"},
+        "jit_chain_leaf_filter_probe_agg_k3a1": _PROBE_AGG | {"agg:sort"},
+        "jit_agg_tower_final": {"op:Aggregation", "agg:sort", "agg:reduce"},
+        "jit_topn": {"op:TopN"},
+        "jit_chain_leaf_project": set()},
+}
+# ProgramRegistry.program_count() after each query of that sequence,
+# as measured on the commit before the programs had names or scopes
+PROGRAM_COUNTS = [4, 10, 14, 22]
+
+
+def _run_four(monkeypatch):
+    """q6, q14, q1, q3 on a fresh registry: per query its rows, the
+    registry's program count after it and {module name: scopes} of the
+    programs it added, read from their lowered text."""
+    import re
+
+    from presto_tpu.exec import programs
+
+    first = {}
+    real_call = programs.Program.__call__
+
+    def call(self, *args, **kwargs):
+        first.setdefault(id(self), (self, args, kwargs))
+        return real_call(self, *args, **kwargs)
+
+    monkeypatch.setattr(programs.Program, "__call__", call)
+    catalog = Catalog()
+    catalog.register("tpch", Tpch(sf=0.01))
+    registry = programs.ProgramRegistry()
+    runner = QueryRunner(catalog, programs=registry)
+    out = {}
+    for q in (6, 14, 1, 3):
+        seen = len(first)
+        rows = runner.execute(QUERIES[q]).rows
+        added = {}
+        for prog, args, kwargs in list(first.values())[seen:]:
+            text = prog.fn.lower(*args, **kwargs).as_text(debug_info=True)
+            module = re.search(r"module @(\S+)", text).group(1)
+            added.setdefault(module, set()).update(
+                re.findall(r"\b((?:op|agg|join):[A-Za-z]+)", text))
+        out[q] = (rows, registry.program_count(), added)
+    return out
+
+
+@pytest.fixture(scope="module")
+def four_queries():
+    with pytest.MonkeyPatch.context() as mp:
+        return _run_four(mp)
+
+
+@pytest.mark.parametrize("q", [6, 14, 1, 3])
+def test_program_names_and_scopes(four_queries, q):
+    assert four_queries[q][2] == PROGRAMS[q]
+
+
+def test_scopes_change_neither_answers_nor_program_count(
+        four_queries, monkeypatch):
+    import contextlib
+
+    import jax
+
+    from presto_tpu.exec import local
+    from presto_tpu.ops import aggregate, join
+
+    assert [four_queries[q][1] for q in (6, 14, 1, 3)] == PROGRAM_COUNTS
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    monkeypatch.setattr(local, "_named", lambda f, name: f)
+    for module, names in (
+            (aggregate, ("_sorted_group_ids", "_presorted_group_ids",
+                         "_partial_states", "_merge_states")),
+            (join, ("_lookup_first", "_lookup_range"))):
+        for name in names:
+            monkeypatch.setattr(module, name,
+                                getattr(module, name).__wrapped__)
+    bare = _run_four(monkeypatch)
+    for q in (6, 14, 1, 3):
+        rows, count, added = bare[q]
+        assert not any(added.values()), added  # the scopes are gone
+        assert "jit_agg_stage" in added  # named after the closure
+        assert (rows, count) == four_queries[q][:2]
+
+
+@pytest.fixture(scope="module")
+def statement_server():
+    from presto_tpu.client import StatementClient
+    from presto_tpu.server.coordinator import CoordinatorServer
+
+    catalog = Catalog()
+    catalog.register("tpch", Tpch(sf=0.01))
+    srv = CoordinatorServer(QueryRunner(catalog))
+    srv.start()
+    try:
+        yield StatementClient(srv.uri)
+    finally:
+        srv.stop()
+
+
+@pytest.mark.parametrize("q", [6, 14, 1, 3])
+def test_host_reads_in_stats_and_spans(statement_server, q):
+    """``stats.hostReads`` is in the final page tracing on or off, and
+    with it on equals the number of ``host_read:*`` spans."""
+    client = statement_server
+    counts = {}
+    for trace in ("false", "true"):
+        client.execute(f"SET SESSION trace = {trace}")
+        pages = []
+        client.execute(QUERIES[q], on_progress=pages.append)
+        counts[trace] = pages[-1]["hostReads"]
+        tracer = obs.lookup(client.last_query_id)
+        if trace == "false":
+            assert tracer is None
+            continue
+        reads = [s.name for s in tracer.spans
+                 if s.name.startswith("host_read:")]
+        assert len(reads) == counts[trace]
+        assert reads[-1] == "host_read:result"
+        if q in (14, 3):  # one uniqueness check per primary-key build
+            assert "host_read:unique_ok" in reads
+    assert counts["false"] == counts["true"] >= 1
+
+
+def test_host_read_opens_no_span_without_tracer(monkeypatch):
+    import jax.numpy as jnp
+    import numpy as np
+
+    from presto_tpu.exec import local
+
+    opened = []
+    real_span = obs.span
+
+    def spy(*args, **kwargs):
+        opened.append(real_span(*args, **kwargs))
+        return opened[-1]
+
+    monkeypatch.setattr(obs, "span", spy)
+    calls = obs.METRICS.counter("device.get_calls").value
+    nbytes = obs.METRICS.counter("device.get_bytes").value
+    before = local.host_reads()
+    assert obs.current_tracer() is None
+    out = local.host_read(jnp.arange(3, dtype=jnp.int32), "unit")
+    assert isinstance(out, np.ndarray) and out.tolist() == [0, 1, 2]
+    assert opened == [obs.NULL_SPAN]
+    tr = obs.Tracer("q_host_read")
+    with obs.tracing(tr):
+        local.host_read((jnp.zeros(2, jnp.int32), jnp.ones(1, jnp.bool_)),
+                        "unit")
+    assert [(s.name, s.cat) for s in tr.spans] == [
+        ("host_read:unit", "device")]
+    assert local.host_reads() == before + 2
+    assert obs.METRICS.counter("device.get_calls").value == calls + 2
+    assert obs.METRICS.counter("device.get_bytes").value == nbytes + 12 + 9
+
+
+def test_spans_are_profiler_annotations_during_a_session(tmp_path):
+    """While ``jax.profiler`` runs, a traced query's live spans are
+    ``presto:<name>`` events of the profile's host plane; the tracer's
+    own spans are the same with or without a session."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    runner, _ = make_runner()
+    runner.session.set("trace", "true")
+    before = runner.execute(QUERIES[6])  # compiles outside the session
+    plain = {s.name for s in obs.lookup(before.query_id).spans}
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        res = runner.execute(QUERIES[6] + " ")
+    finally:
+        jax.profiler.stop_trace()
+    assert res.rows == before.rows
+    spans = {s.name for s in obs.lookup(res.query_id).spans}
+    assert spans - {"xla_compile"} == plain - {"xla_compile"}
+    [path] = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    events = {e.name for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events
+              if e.name.startswith("presto:")}
+    # retroactive spans (parse, xla_compile, the root) are not events
+    assert events == {"presto:" + n for n in spans
+                      - {"parse", "xla_compile", "query"}}
+    assert {"presto:plan", "presto:op:Aggregation",
+            "presto:host_read:result"} <= events
