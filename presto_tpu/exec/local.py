@@ -32,7 +32,9 @@ import numpy as np
 
 from presto_tpu.catalog import Catalog
 from presto_tpu.ops.aggregate import grouped_aggregate, merge_aggregate
-from presto_tpu.ops.filter_project import filter_page, project_page
+from presto_tpu.ops.filter_project import (
+    compact_page, filter_page, project_page,
+)
 from presto_tpu.ops.join import JoinBuild, build_join, probe_expand, probe_join
 from presto_tpu.ops.sort import limit_page, sort_page, sort_perm, topn_page
 from presto_tpu.page import Block, Page
@@ -391,7 +393,9 @@ def _named(f, name: str):
 def _chain_name(sig) -> str:
     """A chain program's name from its ``_stage_signature``: the stage
     tags leaf first, an aggregation tagged with its counts of keys and
-    aggregates (``chain_leaf_filter_agg_k2a8`` is TPC-H q1's)."""
+    aggregates (``chain_leaf_filter_agg_k2a8`` is TPC-H q1's), a
+    compaction as ``compact`` where it happens
+    (``chain_leaf_filter_compact_probe_agg_k0a2`` is q14's)."""
     tags = []
     while True:
         tag = sig[0]
@@ -403,7 +407,27 @@ def _chain_name(sig) -> str:
         sig = sig[-1]
 
 
-_HOST_READS = threading.local()  # .n: this thread's reads, never reset
+# this thread's counts, never reset: .n host reads; .compacted and
+# .fallback pages of compacting chains (``compact_counts``)
+_HOST_READS = threading.local()
+
+
+# A compacting chain holds its partial pages, of max_groups rows each,
+# until its last split is dispatched (``_chain_pages``): with at most
+# this many groups all of them together stay under one page of the scan
+# (the capacity ladder's step, ``bucket_capacity``).
+COMPACT_MAX_GROUPS = 1 << 16
+
+
+def _compact_k(share: float) -> int:
+    """The k of ``_compact_point`` for an estimated live share: the
+    largest with ``2 * share <= 2**-k``, or 0 (no compaction) below 3.
+    The factor of two is room for the estimate; a chain one of whose
+    pages still holds more runs again, uncompacted."""
+    k = 0
+    while k < 30 and 2.0 * share * (2 << k) <= 1.0:
+        k += 1
+    return k if k >= 3 else 0
 
 
 def host_reads() -> int:
@@ -412,6 +436,15 @@ def host_reads() -> int:
     query's consumer thread (like ``_task_stats``), never on a
     scheduler worker, and nothing is kept on the shared runner."""
     return getattr(_HOST_READS, "n", 0)
+
+
+def compact_counts() -> Tuple[int, int]:
+    """(pages of chains that ran compacted, pages of chains that had
+    to run again whole) on this thread so far (``_chain_pages``); a
+    query's counts are the differences across it, like
+    ``host_reads``."""
+    return (getattr(_HOST_READS, "compacted", 0),
+            getattr(_HOST_READS, "fallback", 0))
 
 
 def host_read(x, why: str):
@@ -994,36 +1027,102 @@ class LocalRunner:
         return self._own_registry.get(kind, ("ab", self._ab_seq), factory,
                                       jit=self.jit)
 
-    def _stage_signature(self, node: PlanNode):
+    def _stage_signature(self, node: PlanNode,
+                         compact_k: Optional[int] = None):
         """Structural signature of the fused streaming chain rooted at
         ``node``.  Mirrors ``_build_stage`` member-for-member: every
         parameter a stage closure bakes in (expression IR, resolved
-        capacities, key domains, join kind/flags, build arity) is part
-        of the signature, so equal signatures guarantee the cached
-        callable computes the same function.  Input-page schemas are
-        NOT included — they ride as jit-static pytree aux data
-        (types + dictionaries) and key jit's own trace cache."""
+        capacities, key domains, join kind/flags, build arity, where
+        the chain compacts and how far) is part of the signature, so
+        equal signatures guarantee the cached callable computes the
+        same function.  Input-page schemas are NOT included — they ride
+        as jit-static pytree aux data (types + dictionaries) and key
+        jit's own trace cache."""
+        return self._signature(node, self._compact_point(node, compact_k))
+
+    def _signature(self, node: PlanNode, at):
         if isinstance(node, FilterNode):
             return ("filter", node.predicate,
-                    self._stage_signature(node.source))
+                    self._signature(node.source, at))
         if isinstance(node, ProjectNode):
             return ("project", tuple(node.projections),
-                    self._stage_signature(node.source))
+                    self._signature(node.source, at))
         if isinstance(node, AggregationNode) and node.step == "partial":
             return ("agg_partial", tuple(node.group_exprs),
                     tuple(node.aggs), self._max_groups(node),
                     tuple(node.key_domains),
                     bool(getattr(node, "presorted", False)),
-                    self._stage_signature(node.source))
+                    self._signature(node.source, at))
         if isinstance(node, JoinNode) and self._streaming(node):
+            inner = self._signature(node.left, at)
+            if at is not None and at[0] is node:
+                inner = ("compact", at[1], inner)
             return ("probe", tuple(node.left_keys),
                     tuple(node.key_domains or ()), node.kind,
                     node.null_safe_keys, getattr(node, "null_aware", False),
-                    len(node.right.channels),
-                    self._stage_signature(node.left))
+                    len(node.right.channels), inner)
         if isinstance(node, CrossSingleNode):
-            return ("cross1", self._stage_signature(node.left))
+            return ("cross1", self._signature(node.left, at))
         return ("leaf",)
+
+    def _compact_point(self, node: PlanNode,
+                       compact_k: Optional[int] = None):
+        """Where the chain rooted at ``node`` compacts its page and how
+        far: ``(probe, k)``, the live rows of ``probe``'s input moved to
+        a page of ``capacity >> k`` before it is probed, or None.
+
+        The probe is the one nearest the leaf with a FilterNode in
+        front of it since the leaf or the probe before; k the largest
+        for which twice the filters' estimated share of their source's
+        rows fits ``2**-k``, and at least 3 (``_compact_k``).  Only a
+        chain over a table scan that ends in a partial aggregation of
+        at most ``COMPACT_MAX_GROUPS`` groups compacts: its pages are
+        held until the last has said whether it fitted, and after a
+        miss the scan is read again (``_chain_pages``).  The answer is
+        a function of the plan and the catalog's column metadata alone,
+        never of what a run observed: a served statement must find its
+        program compiled.  ``compact_k`` sets k instead of the
+        estimate's (0: never), for tests."""
+        if compact_k == 0 or not (
+                isinstance(node, AggregationNode) and node.step == "partial"
+                and self._max_groups(node) <= COMPACT_MAX_GROUPS
+                and isinstance(self._chain_leaf(node), TableScanNode)):
+            return None
+        found = None
+        while self._is_chain_member(node):
+            if isinstance(node, JoinNode):
+                share = self._filtered_share(node.left)
+                if share is not None:
+                    k = _compact_k(share) if compact_k is None else compact_k
+                    if k:
+                        found = (node, k)
+            node = (node.left if isinstance(node, (JoinNode, CrossSingleNode))
+                    else node.source)
+        return found
+
+    @staticmethod
+    def _filtered_share(node: PlanNode) -> Optional[float]:
+        """The textbook estimate (``StatsCalculator`` without history)
+        of the share of their source's rows that the filters at the
+        top of ``node`` keep; None without a filter there, or where the
+        estimate would have to read a materialized page."""
+        from presto_tpu.planner.stats import StatsCalculator
+
+        source, filtered = node, False
+        while isinstance(source, (FilterNode, ProjectNode)):
+            filtered = filtered or isinstance(source, FilterNode)
+            source = source.source
+        if not filtered:
+            return None
+        below = [source]
+        while below:
+            n = below.pop()
+            if isinstance(n, PrecomputedNode):
+                return None
+            below.extend(n.sources)
+        calc = StatsCalculator()
+        rows = calc.rows(source)
+        return calc.rows(node) / rows if rows > 0 else None
 
     def _is_chain_member(self, n: PlanNode) -> bool:
         return (
@@ -1084,10 +1183,16 @@ class LocalRunner:
         if not pages:
             return
 
+        # a prefix that holds the chain's compacting probe compacts as
+        # the chain does, so a member's time is its time in the program
+        # that runs (the compaction's is booked to the probe)
+        at = self._compact_point(root)
         prev = 0.0
-        for prefix_root in reversed(seq):
+        for pos, prefix_root in reversed(list(enumerate(seq))):
             joins: List[JoinNode] = []
-            stage = self._build_stage(prefix_root, joins)
+            compacts = at is not None and any(n is at[0] for n in seq[pos:])
+            stage = self._build_chain(prefix_root, joins,
+                                      at if compacts else None)
             consts = {f"build_{i}": self._materialize_build(j)
                       for i, j in enumerate(joins)}
             fn = jax.jit(stage) if self.jit else stage
@@ -1378,7 +1483,8 @@ class LocalRunner:
         unordered = self._take_unordered()
         leaf = self._chain_leaf(node)
         joins: List[JoinNode] = []
-        stage = self._build_stage(node, joins)
+        at = self._compact_point(node)
+        stage = self._build_chain(node, joins, at)
         try:
             consts = {f"build_{i}": self._materialize_build(j) for i, j in enumerate(joins)}
         except ExceededMemoryLimitError as e:
@@ -1395,12 +1501,46 @@ class LocalRunner:
         if node in self._chain_cache:
             fn = self._chain_cache[node]
         else:
-            sig = self._stage_signature(node)
-            _named(stage, _chain_name(sig))
-            fn = self._program(
-                "chain", sig,
-                lambda: jax.jit(stage) if self.jit else stage, node=node)
+            fn = self._chain_program(node, stage, at)
             self._chain_cache[node] = fn
+        if at is None:
+            yield from self._chain_outputs(leaf, fn, consts, unordered)
+            return
+        # A compacting program answers for the rows that fitted its
+        # small page and says whether all did.  Its (small, partial)
+        # pages are held until the last split is dispatched; then ONE
+        # read says whether any page held more, and if so the chain
+        # runs again under the program that does not compact.  So no
+        # row is ever dropped, whatever the estimate was worth.
+        from presto_tpu.obs import METRICS
+
+        outs = list(self._chain_outputs(leaf, fn, consts, unordered))
+        fitted = not any(host_read([over for _, over in outs],
+                                   "compact_taken"))
+        which = "compacted" if fitted else "fallback"
+        setattr(_HOST_READS, which,
+                getattr(_HOST_READS, which, 0) + len(outs))
+        METRICS.counter("chain.compact_pages" if fitted
+                        else "chain.compact_fallback_pages").inc(len(outs))
+        if fitted:
+            yield from (page for page, _ in outs)
+            return
+        del outs
+        whole = self._chain_program(node, self._build_stage(node, []), None)
+        yield from self._chain_outputs(leaf, whole, consts, unordered)
+
+    def _chain_program(self, node: PlanNode, stage, at):
+        """``stage`` named after its chain and compiled, or the
+        registry's program of the same signature."""
+        sig = self._signature(node, at)
+        _named(stage, _chain_name(sig))
+        return self._program(
+            "chain", sig,
+            lambda: jax.jit(stage) if self.jit else stage, node=node)
+
+    def _chain_outputs(self, leaf: PlanNode, fn, consts,
+                       unordered: bool) -> Iterator:
+        """``fn(page, consts)`` of every page of ``leaf``."""
         mem = self._mem
         # the scheduler takes SCAN pipelines (independent connector
         # splits — the morsel shape); breaker-leaf chains keep the
@@ -1497,17 +1637,46 @@ class LocalRunner:
             return self._chain_leaf(node.left)
         return node
 
-    def _build_stage(self, node: PlanNode, joins: List[JoinNode]):
+    def _build_chain(self, node: PlanNode, joins: List[JoinNode], at):
+        """``_build_stage``'s fn where the chain does not compact
+        (``at``, its ``_compact_point``, is None).  Where it does:
+        ``fn(page, consts) -> (page, over)``.  The stages below the
+        probe run as ever; the first ``capacity >> k`` live rows of
+        their page are compacted, and the probe and everything above
+        it run over that small page.  ``over``, a device scalar, says
+        the page held more live rows than that: the answer is then of
+        the rows that fitted only, and the caller must not use it
+        (``_chain_pages`` runs the chain again, uncompacted)."""
+        if at is None:
+            return self._build_stage(node, joins)
+        probe, k = at
+        head = self._build_stage(probe.left, joins)
+        rest = self._build_stage(node, joins, stop=probe.left)
+
+        def compact_stage(p, c):
+            p = head(p, c)
+            cap_out = max(p.capacity >> k, 1)
+            with jax.named_scope("op:Filter"):
+                small, live = compact_page(p, cap_out)
+            return rest(small, c), live > cap_out
+
+        return compact_stage
+
+    def _build_stage(self, node: PlanNode, joins: List[JoinNode],
+                     stop: Optional[PlanNode] = None):
         """Recursively build fn(page, consts)->page for the streaming
-        prefix of ``node``; below the chain leaf, the identity.
+        prefix of ``node``; below the chain leaf, and at ``stop``, the
+        identity.
 
         KEEP IN SYNC with ``_stage_signature``: every parameter a stage
         closure bakes in here must appear in the signature, or two
         different chains will share one compiled program (silent wrong
         results, not a crash).  test_cold_compile pins the current
         parameters' signature-sensitivity."""
+        if node is stop:
+            return lambda p, c: p
         if isinstance(node, FilterNode):
-            inner = self._build_stage(node.source, joins)
+            inner = self._build_stage(node.source, joins, stop)
             pred = node.predicate
 
             def filter_stage(p, c):
@@ -1518,7 +1687,7 @@ class LocalRunner:
             return filter_stage
 
         if isinstance(node, ProjectNode):
-            inner = self._build_stage(node.source, joins)
+            inner = self._build_stage(node.source, joins, stop)
             projections = list(node.projections)
 
             def project_stage(p, c):
@@ -1529,7 +1698,7 @@ class LocalRunner:
             return project_stage
 
         if isinstance(node, AggregationNode) and node.step == "partial":
-            inner = self._build_stage(node.source, joins)
+            inner = self._build_stage(node.source, joins, stop)
             group_exprs = list(node.group_exprs)
             aggs = list(node.aggs)
             mg = self._max_groups(node)
@@ -1547,7 +1716,7 @@ class LocalRunner:
             return agg_stage
 
         if isinstance(node, JoinNode) and self._streaming(node):
-            inner = self._build_stage(node.left, joins)
+            inner = self._build_stage(node.left, joins, stop)
             key = f"build_{len(joins)}"
             joins.append(node)
             build_output = list(range(len(node.right.channels)))
@@ -1569,7 +1738,7 @@ class LocalRunner:
             return probe_stage
 
         if isinstance(node, CrossSingleNode):
-            inner = self._build_stage(node.left, joins)
+            inner = self._build_stage(node.left, joins, stop)
             key = f"build_{len(joins)}"
             joins.append(node)
 

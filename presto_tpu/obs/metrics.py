@@ -239,6 +239,9 @@ def _preregister(reg: MetricsRegistry) -> None:
         "xla.registry_hits", "xla.registry_misses",
         # device <-> host transfers (the TPU tax EXPLAIN can't see)
         "device.get_calls", "device.get_bytes",
+        # pages of chains that ran compacted in front of a probe, and
+        # of chains that had to run again whole (exec/local)
+        "chain.compact_pages", "chain.compact_fallback_pages",
         # spill + exchange volume
         "spill.bytes", "exchange.pages_serialized",
         "exchange.bytes_serialized", "exchange.pages_deserialized",

@@ -14,7 +14,7 @@ from typing import Optional
 
 from presto_tpu.catalog import Catalog
 from presto_tpu.exec.local import (
-    LocalRunner, MaterializedResult, QueryStats, host_reads,
+    LocalRunner, MaterializedResult, QueryStats, compact_counts, host_reads,
 )
 from presto_tpu.session import Session
 from presto_tpu.sql import ast
@@ -243,6 +243,7 @@ class QueryRunner:
                     qstats = (QueryStats()
                               if self.session.get("collect_stats") else None)
                     reads0 = host_reads()
+                    compact0 = compact_counts()
                     with obs.span("execute", cat="lifecycle"):
                         res = None
                         if prepared is not None:
@@ -302,6 +303,11 @@ class QueryRunner:
             # blocking device reads this query made on this thread
             # (exec/local.host_read); 0 for a result-cache hit
             res.host_reads = host_reads() - reads0
+            # pages of chains that ran compacted, and of chains that
+            # held more live rows than the plan's estimate left room
+            # for and ran again whole (_chain_pages)
+            res.compacted_pages, res.compact_fallback_pages = (
+                n - n0 for n, n0 in zip(compact_counts(), compact0))
             # serving-tier surfaces: whether this result came from the
             # structural cache, and the executor's observed peak bytes
             # (the admission controller's projection source for the

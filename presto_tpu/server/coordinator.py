@@ -200,6 +200,8 @@ class _QueryState:
         self.compile_ms: Optional[float] = None
         self.execution_ms: Optional[float] = None
         self.host_reads: Optional[int] = None
+        self.compacted_pages: Optional[int] = None
+        self.compact_fallback_pages: Optional[int] = None
         # client-supplied request correlation (X-Presto-Trace-Token)
         self.trace_token: Optional[str] = None
         # deadline bookkeeping: the effective limit (None = none) and
@@ -813,6 +815,9 @@ class CoordinatorServer:
                 q.compile_ms = getattr(res, "compile_ms", None)
                 q.execution_ms = getattr(res, "execution_ms", None)
                 q.host_reads = getattr(res, "host_reads", None)
+                q.compacted_pages = getattr(res, "compacted_pages", None)
+                q.compact_fallback_pages = getattr(
+                    res, "compact_fallback_pages", None)
                 q.cache_hit = getattr(res, "cache_hit", None)
                 q.queued_ms = getattr(res, "queued_ms", None)
                 q.memory_blocked_ms = getattr(res, "memory_blocked_ms",
@@ -906,6 +911,9 @@ class CoordinatorServer:
             out["stats"]["executionMs"] = q.execution_ms
         if q.host_reads is not None:
             out["stats"]["hostReads"] = q.host_reads
+        if q.compacted_pages is not None:
+            out["stats"]["compactedPages"] = q.compacted_pages
+            out["stats"]["compactFallbackPages"] = q.compact_fallback_pages
         # serving tier: result provenance (structural result cache)
         if q.cache_hit is not None:
             out["stats"]["cacheHit"] = q.cache_hit

@@ -169,6 +169,29 @@ def test_stage_signature_sensitivity():
     assert sig(agg) != sig(agg.replace("sum", "max"))  # agg fn
 
 
+def test_stage_signature_holds_the_compaction():
+    """Where a chain compacts, and how far, is baked into its program:
+    q14's chain signs differently for every k, and for none."""
+    import dataclasses
+
+    from presto_tpu.exec.local import _chain_name
+    from presto_tpu.planner.plan import AggregationNode
+
+    runner, _ = _fresh_runner()
+    ex = runner.executor
+    node = runner.binder.plan(QUERIES[14])
+    while not isinstance(node, AggregationNode):
+        node = node.sources[0]
+    root = dataclasses.replace(node, step="partial")
+    ex._agg_overrides[root] = ex._max_groups(node)
+    sigs = {k: ex._stage_signature(root, compact_k=k) for k in (0, 4, 5)}
+    assert len(set(sigs.values())) == 3
+    assert ex._stage_signature(root) == sigs[5]  # the plan's own k
+    assert _chain_name(sigs[0]) == "chain_leaf_filter_probe_agg_k0a2"
+    assert _chain_name(sigs[4]) == _chain_name(sigs[5]) == \
+        "chain_leaf_filter_compact_probe_agg_k0a2"
+
+
 def test_registry_lru_eviction_bounds_callables():
     """The registry must bound the live-executable arena (XLA:CPU
     segfaults past a few thousand live programs — r5 TPC-DS finding):

@@ -441,7 +441,8 @@ PROGRAMS = {
         "jit_chain_leaf_project": set()},
     14: {"jit_chain_leaf": set(),
          "jit_join_build": {"op:JoinBuild"},
-         "jit_chain_leaf_filter_probe_agg_k0a2": _PROBE_AGG,
+         "jit_chain_leaf_filter_compact_probe_agg_k0a2":
+             _PROBE_AGG | {"filter:compact"},
          "jit_agg_fold": {"op:Aggregation", "agg:reduce"},
          "jit_agg_final": {"op:Aggregation", "agg:reduce"},
          "jit_chain_leaf_project": {"op:Project"}},
@@ -492,7 +493,7 @@ def _run_four(monkeypatch):
             text = prog.fn.lower(*args, **kwargs).as_text(debug_info=True)
             module = re.search(r"module @(\S+)", text).group(1)
             added.setdefault(module, set()).update(
-                re.findall(r"\b((?:op|agg|join):[A-Za-z]+)", text))
+                re.findall(r"\b((?:op|agg|join|filter):[A-Za-z]+)", text))
         out[q] = (rows, registry.program_count(), added)
     return out
 
@@ -532,7 +533,8 @@ def test_scopes_change_neither_answers_nor_program_count(
     for q in (6, 14, 1, 3):
         rows, count, added = bare[q]
         assert not any(added.values()), added  # the scopes are gone
-        assert "jit_agg_stage" in added  # named after the closure
+        # named after the closure
+        assert ("jit_compact_stage" if q == 14 else "jit_agg_stage") in added
         assert (rows, count) == four_queries[q][:2]
 
 
@@ -572,6 +574,11 @@ def test_host_reads_in_stats_and_spans(statement_server, q):
         assert reads[-1] == "host_read:result"
         if q in (14, 3):  # one uniqueness check per primary-key build
             assert "host_read:unique_ok" in reads
+        # q14's chain compacts in front of its probe: one read says
+        # whether every page fitted, and the stats count the pages
+        assert reads.count("host_read:compact_taken") == (q == 14)
+        assert (pages[-1]["compactedPages"] > 0) == (q == 14)
+        assert pages[-1]["compactFallbackPages"] == 0
     assert counts["false"] == counts["true"] >= 1
 
 
