@@ -408,7 +408,9 @@ def _chain_name(sig) -> str:
 
 
 # this thread's counts, never reset: .n host reads; .compacted and
-# .fallback pages of compacting chains (``compact_counts``)
+# .fallback pages of compacting chains (``compact_counts``);
+# .expand_retries and .expanded_rows of expanding probes
+# (``expand_counts``)
 _HOST_READS = threading.local()
 
 
@@ -445,6 +447,15 @@ def compact_counts() -> Tuple[int, int]:
     ``host_reads``."""
     return (getattr(_HOST_READS, "compacted", 0),
             getattr(_HOST_READS, "fallback", 0))
+
+
+def expand_counts() -> Tuple[int, int]:
+    """(expanding probes that ran a second time at a larger capacity,
+    rows expanding probes emitted: the ``total`` each read) on this
+    thread so far (``_probe_with_retry``); a query's counts are the
+    differences across it, like ``host_reads``."""
+    return (getattr(_HOST_READS, "expand_retries", 0),
+            getattr(_HOST_READS, "expanded_rows", 0))
 
 
 def host_read(x, why: str):
@@ -622,7 +633,11 @@ def _probe_with_retry(probe_fn, build, page):
     cap = max(int(page.capacity), 1024)
     res = probe_fn(build, page, cap)
     total = int(host_read(res[1], "probe_total"))
+    _HOST_READS.expanded_rows = getattr(
+        _HOST_READS, "expanded_rows", 0) + total
     if total > cap:
+        _HOST_READS.expand_retries = getattr(
+            _HOST_READS, "expand_retries", 0) + 1
         res = probe_fn(build, page, bucket_capacity(total))
     return res
 

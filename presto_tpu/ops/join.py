@@ -219,18 +219,20 @@ def build_join(
                          unique_ok=jnp.logical_not(collision),
                          has_null_key=has_null, nonempty=nonempty)
 
-    order = jnp.argsort(key)
-    sorted_keys = key[order]
+    with jax.named_scope("join:index"):
+        order = jnp.argsort(key)
+        sorted_keys = key[order]
 
-    starts = None
-    prod = (packed_domain_size(key_domains)
-            if exact and _direct_table_profitable() else None)
-    if prod is not None and prod <= _direct_budget(page):
-        # one fused sort at build time buys O(1)-gather probes forever:
-        # dead/sentinel rows sort past prod-1 so they never enter a range
-        queries = jnp.arange(prod + 1, dtype=sorted_keys.dtype)
-        starts = jnp.searchsorted(
-            sorted_keys, queries, method="sort").astype(jnp.int32)
+        starts = None
+        prod = (packed_domain_size(key_domains)
+                if exact and _direct_table_profitable() else None)
+        if prod is not None and prod <= _direct_budget(page):
+            # one fused sort at build time buys O(1)-gather probes
+            # forever: dead/sentinel rows sort past prod-1 so they
+            # never enter a range
+            queries = jnp.arange(prod + 1, dtype=sorted_keys.dtype)
+            starts = jnp.searchsorted(
+                sorted_keys, queries, method="sort").astype(jnp.int32)
     return JoinBuild(sorted_keys, order.astype(jnp.int32), page, starts,
                      has_null_key=has_null, nonempty=nonempty)
 
@@ -402,40 +404,44 @@ def probe_expand(
     positions after all probes finish)."""
     key, _ = _probe_keys(probe, probe_key_exprs, key_domains, null_safe)
     lo, hi = _lookup_range(build, key)
-    counts = jnp.where(probe.row_mask, hi - lo, 0)
-    if kind == "left":
-        counts = jnp.where(probe.row_mask & (counts == 0), 1, counts)
-    offsets = jnp.cumsum(counts) - counts
-    total = jnp.sum(counts)
+    with jax.named_scope("join:expand"):
+        counts = jnp.where(probe.row_mask, hi - lo, 0)
+        if kind == "left":
+            counts = jnp.where(probe.row_mask & (counts == 0), 1, counts)
+        offsets = jnp.cumsum(counts) - counts
+        total = jnp.sum(counts)
 
-    out_idx = jnp.arange(out_capacity)
-    # probe row for each output slot
-    p_row = jnp.searchsorted(offsets, out_idx, side="right") - 1
-    p_row = jnp.clip(p_row, 0, probe.capacity - 1).astype(jnp.int32)
-    j = out_idx - offsets[p_row]
-    live_out = out_idx < total
-    b_pos = jnp.clip(lo[p_row] + j, 0, build.capacity - 1)
-    matched = j < (hi[p_row] - lo[p_row])  # false only for left-join null rows
-    b_row = build.perm[b_pos]
+        out_idx = jnp.arange(out_capacity)
+        # probe row for each output slot
+        p_row = jnp.searchsorted(offsets, out_idx, side="right") - 1
+        p_row = jnp.clip(p_row, 0, probe.capacity - 1).astype(jnp.int32)
+        j = out_idx - offsets[p_row]
+        live_out = out_idx < total
+        b_pos = jnp.clip(lo[p_row] + j, 0, build.capacity - 1)
+        # false only for left-join null rows
+        matched = j < (hi[p_row] - lo[p_row])
+        b_row = build.perm[b_pos]
 
-    out_blocks: List[Block] = []
-    for b in probe.blocks:
-        out_blocks.append(
-            Block(b.data[p_row], b.valid[p_row] & live_out, b.type, b.dictionary)
-        )
-    if build_output is None:
-        build_output = range(len(build.page.blocks))
-    for i in build_output:
-        b = build.page.blocks[i]
-        out_blocks.append(
-            Block(b.data[b_row], b.valid[b_row] & matched & live_out, b.type, b.dictionary)
-        )
-    out_page = Page(tuple(out_blocks), live_out)
-    if return_matched:
-        b_matched = jnp.zeros((build.page.capacity,), dtype=jnp.bool_)
-        b_matched = b_matched.at[b_row].max(matched & live_out, mode="drop")
-        return out_page, total, b_matched
-    return out_page, total
+        out_blocks: List[Block] = []
+        for b in probe.blocks:
+            out_blocks.append(
+                Block(b.data[p_row], b.valid[p_row] & live_out, b.type,
+                      b.dictionary)
+            )
+        if build_output is None:
+            build_output = range(len(build.page.blocks))
+        for i in build_output:
+            b = build.page.blocks[i]
+            out_blocks.append(
+                Block(b.data[b_row], b.valid[b_row] & matched & live_out,
+                      b.type, b.dictionary)
+            )
+        out_page = Page(tuple(out_blocks), live_out)
+        if return_matched:
+            b_matched = jnp.zeros((build.page.capacity,), dtype=jnp.bool_)
+            b_matched = b_matched.at[b_row].max(matched & live_out, mode="drop")
+            return out_page, total, b_matched
+        return out_page, total
 
 
 def outer_build_tail(

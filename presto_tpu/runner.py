@@ -14,7 +14,8 @@ from typing import Optional
 
 from presto_tpu.catalog import Catalog
 from presto_tpu.exec.local import (
-    LocalRunner, MaterializedResult, QueryStats, compact_counts, host_reads,
+    LocalRunner, MaterializedResult, QueryStats, compact_counts, expand_counts,
+    host_reads,
 )
 from presto_tpu.session import Session
 from presto_tpu.sql import ast
@@ -244,6 +245,7 @@ class QueryRunner:
                               if self.session.get("collect_stats") else None)
                     reads0 = host_reads()
                     compact0 = compact_counts()
+                    expand0 = expand_counts()
                     with obs.span("execute", cat="lifecycle"):
                         res = None
                         if prepared is not None:
@@ -308,6 +310,10 @@ class QueryRunner:
             # for and ran again whole (_chain_pages)
             res.compacted_pages, res.compact_fallback_pages = (
                 n - n0 for n, n0 in zip(compact_counts(), compact0))
+            # expanding probes that ran again at a larger capacity, and
+            # the rows expanding probes emitted (_probe_with_retry)
+            res.expand_retries, res.expanded_rows = (
+                n - n0 for n, n0 in zip(expand_counts(), expand0))
             # serving-tier surfaces: whether this result came from the
             # structural cache, and the executor's observed peak bytes
             # (the admission controller's projection source for the

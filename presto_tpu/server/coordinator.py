@@ -202,6 +202,8 @@ class _QueryState:
         self.host_reads: Optional[int] = None
         self.compacted_pages: Optional[int] = None
         self.compact_fallback_pages: Optional[int] = None
+        self.expand_retries: Optional[int] = None
+        self.expanded_rows: Optional[int] = None
         # client-supplied request correlation (X-Presto-Trace-Token)
         self.trace_token: Optional[str] = None
         # deadline bookkeeping: the effective limit (None = none) and
@@ -818,6 +820,8 @@ class CoordinatorServer:
                 q.compacted_pages = getattr(res, "compacted_pages", None)
                 q.compact_fallback_pages = getattr(
                     res, "compact_fallback_pages", None)
+                q.expand_retries = getattr(res, "expand_retries", None)
+                q.expanded_rows = getattr(res, "expanded_rows", None)
                 q.cache_hit = getattr(res, "cache_hit", None)
                 q.queued_ms = getattr(res, "queued_ms", None)
                 q.memory_blocked_ms = getattr(res, "memory_blocked_ms",
@@ -914,6 +918,9 @@ class CoordinatorServer:
         if q.compacted_pages is not None:
             out["stats"]["compactedPages"] = q.compacted_pages
             out["stats"]["compactFallbackPages"] = q.compact_fallback_pages
+        if q.expand_retries is not None:
+            out["stats"]["expandRetries"] = q.expand_retries
+            out["stats"]["expandedRows"] = q.expanded_rows
         # serving tier: result provenance (structural result cache)
         if q.cache_hit is not None:
             out["stats"]["cacheHit"] = q.cache_hit

@@ -464,7 +464,7 @@ PROGRAMS = {
 PROGRAM_COUNTS = [4, 10, 14, 22]
 
 
-def _run_four(monkeypatch):
+def _run_four(monkeypatch, qids=(6, 14, 1, 3)):
     """q6, q14, q1, q3 on a fresh registry: per query its rows, the
     registry's program count after it and {module name: scopes} of the
     programs it added, read from their lowered text."""
@@ -485,7 +485,7 @@ def _run_four(monkeypatch):
     registry = programs.ProgramRegistry()
     runner = QueryRunner(catalog, programs=registry)
     out = {}
-    for q in (6, 14, 1, 3):
+    for q in qids:
         seen = len(first)
         rows = runner.execute(QUERIES[q]).rows
         added = {}
@@ -536,6 +536,29 @@ def test_scopes_change_neither_answers_nor_program_count(
         # named after the closure
         assert ("jit_compact_stage" if q == 14 else "jit_agg_stage") in added
         assert (rows, count) == four_queries[q][:2]
+
+
+def test_q13_build_and_probe_carry_the_join_scopes(monkeypatch):
+    """The legs no primary-key build reaches (PR 27): the sorted index
+    with its CSR ``starts`` table is ``join:index`` inside the build,
+    the expansion ``join:expand`` beside the range lookup inside the
+    probe.  Under the leg a TPU takes."""
+    from presto_tpu.ops import join
+
+    join.set_direct_join_override(True)
+    try:
+        (rows, _, added), = _run_four(monkeypatch, qids=(13,)).values()
+    finally:
+        join.set_direct_join_override(None)
+    assert rows and rows[0][1] > 0
+    assert added["jit_join_build"] == {"op:JoinBuild", "join:index"}
+    assert added["jit_join_probe"] == {"op:Join", "join:lookup",
+                                       "join:expand"}
+    # no program of q6, q14, q1, q3 holds either (PROGRAMS above): their
+    # builds leave by the unique-direct leg, their probes do not expand
+    assert not any({"join:index", "join:expand"} & scopes
+                   for programs in PROGRAMS.values()
+                   for scopes in programs.values())
 
 
 @pytest.fixture(scope="module")
