@@ -16,9 +16,15 @@ reserved 0 code which is excluded, or sort to the +inf sentinel.
 
 Shapes: probe_join aligned outputs (unique build keys, or first-match)
 keep the probe page's capacity. probe_expand emits up to out_capacity
-rows for many-to-many joins, with an overflow flag the driver checks
-(it re-probes in smaller chunks on overflow — the analog of the
-reference's yielding LookupJoinPageBuilder)."""
+rows for many-to-many joins and returns the true match count beside
+them; the driver checks it and probes again at a capacity that fits
+(the analog of the reference's yielding LookupJoinPageBuilder). The map
+from output slot to probe row there is one scatter and one running
+maximum, not a search: the slots are an ``arange`` and the rows'
+offsets a prefix sum, so each emitting row marks its first slot and the
+scan carries the mark over the rest (a binary search did the same in
+18 gather rounds, 380 times slower on a v5e at Q13's size; PERF.md,
+PR 28)."""
 
 from __future__ import annotations
 
@@ -391,8 +397,14 @@ def probe_expand(
 ) -> Tuple[Page, jax.Array]:
     """Many-to-many join: each probe row emits one output row per
     matching build row. Returns (page, total_matches); if
-    total_matches > out_capacity the page is truncated and the driver
-    must re-probe in chunks.
+    total_matches > out_capacity the page is truncated (its first
+    out_capacity rows) and the driver probes again at a capacity that
+    holds total_matches. Slots at or past total_matches are dead:
+    ``row_mask`` and every ``valid`` false, the data unspecified.
+
+    No control flow: output slot -> probe row is a scatter of the
+    emitting rows' numbers to their first slots and a ``cummax`` over
+    the slots, in int32.
 
     kind: inner | left (left emits one null-extended row for probes
     with no match).
@@ -411,11 +423,20 @@ def probe_expand(
         offsets = jnp.cumsum(counts) - counts
         total = jnp.sum(counts)
 
-        out_idx = jnp.arange(out_capacity)
-        # probe row for each output slot
-        p_row = jnp.searchsorted(offsets, out_idx, side="right") - 1
-        p_row = jnp.clip(p_row, 0, probe.capacity - 1).astype(jnp.int32)
-        j = out_idx - offsets[p_row]
+        # probe row of each output slot, in one pass: a row that emits
+        # anything owns the slots from its offset on, and those offsets
+        # are distinct, so each such row writes its number (+1: 0 says
+        # "no row starts here") into its first slot and a running
+        # maximum carries it over the rest. Rows that emit nothing, and
+        # rows past a truncated page's end, aim past the end and drop.
+        rows = jnp.arange(probe.capacity, dtype=jnp.int32)
+        out_idx = jnp.arange(out_capacity, dtype=jnp.int32)
+        first = jnp.where(counts > 0, offsets, out_capacity + rows)
+        marks = jnp.zeros((out_capacity,), jnp.int32).at[first].set(
+            rows + 1, mode="drop", unique_indices=True)
+        p_row = jnp.maximum(jax.lax.cummax(marks) - 1, 0)
+        # ... and the slot the row starts at, by the same scan
+        j = out_idx - jax.lax.cummax(jnp.where(marks > 0, out_idx, 0))
         live_out = out_idx < total
         b_pos = jnp.clip(lo[p_row] + j, 0, build.capacity - 1)
         # false only for left-join null rows
