@@ -249,20 +249,6 @@ class _MemoryTx:
         self.ops: List[tuple] = []
 
 
-# PRESTO_TPU_PAD_LOAD=0 disables write-time ladder padding; resolved
-# once per process (engine_lint env-read rule: _to_device runs per
-# stored page), set_pad_load overrides for tests.
-from presto_tpu.envflag import EnvFlag
-
-_PAD_LOAD = EnvFlag("PRESTO_TPU_PAD_LOAD", default=True)
-_pad_load_enabled = _PAD_LOAD
-
-
-def set_pad_load(value) -> None:
-    """Override hook (None re-resolves from the environment)."""
-    _PAD_LOAD.set(value)
-
-
 def _to_device(page: Page):
     """Pin a page's arrays in HBM once at write time — compacted result
     pages arrive numpy-backed (page.compact_host), and storing them
@@ -279,7 +265,7 @@ def _to_device(page: Page):
 
     cap = page.capacity
     tgt = bucket_capacity(cap)
-    if tgt > cap and _pad_load_enabled():
+    if tgt > cap:
         def padded(a):
             a = np.asarray(a)
             out = np.zeros((tgt,) + a.shape[1:], dtype=a.dtype)

@@ -3,15 +3,16 @@
 The engine_lint ``env-read`` contract: an ``os.environ`` read belongs
 at import/construction time or behind a resolve-once helper — never in
 a per-page/per-query path (a dict lookup per page, and program choice
-that flips mid-process with the environment).  Every A/B escape hatch
-(``PRESTO_TPU_PAD_SCAN``, ``PRESTO_TPU_AGG_TOWER``, ...) shares this
-one implementation instead of hand-rolling the getter/setter pair.
+that flips mid-process with the environment).  Every boolean switch
+(``PRESTO_TPU_EXCHANGE_STREAMING``, ``PRESTO_TPU_VALIDATE_PLANS``, ...)
+shares this one implementation instead of hand-rolling the
+getter/setter pair.
 
 Usage::
 
-    _PAD_SCAN = EnvFlag("PRESTO_TPU_PAD_SCAN", default=True)
-    if _PAD_SCAN(): ...
-    _PAD_SCAN.set(False)   # test override; .set(None) re-resolves
+    _VALIDATION = EnvFlag("PRESTO_TPU_VALIDATE_PLANS", default=False)
+    if _VALIDATION(): ...
+    _VALIDATION.set(True)   # test override; .set(None) re-resolves
 """
 
 from __future__ import annotations

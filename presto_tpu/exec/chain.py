@@ -1,0 +1,418 @@
+"""A streaming chain, lowered once.
+
+The executor fuses every streaming chain of a plan (scan -> filter ->
+project -> join probe -> partial aggregation) into one program
+``fn(page, consts)`` per split (``exec/local.py``).  What a chain is,
+what its program computes, what the registry keys it by and what XLA
+calls it are all read from ONE description, the :class:`Chain` that
+:func:`lower_chain` makes in the only walk over the chain grammar
+(:func:`_member`):
+
+- a chain is linear: a leaf (a scan, or a breaker whose pages stream
+  in) and the ordered :class:`Stage` s over it, leaf first;
+- a stage is ``(kind, params, node)``.  ``params`` is a NamedTuple of
+  everything the stage's program depends on and ``apply`` its program
+  over one page.  ``apply`` receives the params and nothing else, so a
+  value cannot be baked into a program without being in its signature:
+  two chains that sign equal compute the same function, by
+  construction;
+- ``node`` is kept for timing (``LocalRunner._time_chain``) and for the
+  build sides (:attr:`Chain.joins`); it is never part of the signature.
+
+Input-page schemas are not part of a signature either: they ride as
+jit-static pytree aux data (types + dictionaries) and key jit's own
+trace cache.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Callable, List, NamedTuple, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from presto_tpu.expr.ir import AggCall, Expr
+from presto_tpu.ops.aggregate import grouped_aggregate
+from presto_tpu.ops.filter_project import (
+    compact_page, filter_page, project_page,
+)
+from presto_tpu.ops.join import probe_join
+from presto_tpu.page import Block, Page
+from presto_tpu.planner.plan import (
+    AggregationNode,
+    CrossSingleNode,
+    FilterNode,
+    JoinNode,
+    PlanNode,
+    PrecomputedNode,
+    ProjectNode,
+    TableScanNode,
+)
+
+
+def is_streaming_join(node: JoinNode) -> bool:
+    """True when the probe is row-aligned (jittable in a chain):
+    semi/anti (presence tests) or unique-key builds. FULL joins always
+    take the materializing path — the unmatched-build tail needs
+    cross-page match state."""
+    if node.kind == "full":
+        return False
+    return node.kind in ("semi", "anti", "mark") or node.unique_build
+
+
+def streams(node: JoinNode) -> bool:
+    """``is_streaming_join`` for a join that is no index join: an index
+    join must not fuse into a chain, whose builder would materialize
+    the full build scan instead of point lookups.  What ``lower_chain``
+    asks where no runner has demoted a join (``LocalRunner._streaming``
+    adds that)."""
+    return is_streaming_join(node) and not node.use_index
+
+
+def cross_append_single(q: Page, r: Page) -> Page:
+    """Append a single-row page's columns to every row of ``q`` (the
+    cross-join-with-scalar-subquery kernel, EnforceSingleRow +
+    NestedLoopJoin's one-row case)."""
+    blocks = list(q.blocks)
+    for b in r.blocks:
+        blocks.append(
+            Block(
+                jnp.broadcast_to(b.data[0], (q.capacity,) + b.data.shape[1:]),
+                jnp.broadcast_to(b.valid[0] & r.row_mask[0], (q.capacity,)),
+                b.type,
+                b.dictionary,
+            )
+        )
+    return Page(tuple(blocks), q.row_mask)
+
+
+# ---------------------------------------------------------------------------
+# the stage kinds: params, how a plan node gives them, the program
+# ---------------------------------------------------------------------------
+# ``of(node, max_groups)`` reads a member node; ``apply(page, consts,
+# build_key)`` is the stage over one page, under the operator's scope
+# (the host spans' names: what a device trace books the time to).
+
+
+class Filter(NamedTuple):
+    predicate: Expr
+
+    @classmethod
+    def of(cls, node, max_groups):
+        return cls(node.predicate)
+
+    def apply(self, page, consts, build_key):
+        with jax.named_scope("op:Filter"):
+            return filter_page(page, self.predicate)
+
+
+class Project(NamedTuple):
+    projections: Tuple[Expr, ...]
+
+    @classmethod
+    def of(cls, node, max_groups):
+        return cls(tuple(node.projections))
+
+    def apply(self, page, consts, build_key):
+        with jax.named_scope("op:Project"):
+            return project_page(page, list(self.projections))
+
+
+class AggPartial(NamedTuple):
+    group_exprs: Tuple[Expr, ...]
+    aggs: Tuple[AggCall, ...]
+    max_groups: int  # resolved: key domains and capacity retries applied
+    key_domains: tuple
+    presorted: bool
+
+    @classmethod
+    def of(cls, node, max_groups):
+        return cls(tuple(node.group_exprs), tuple(node.aggs),
+                   max_groups(node), tuple(node.key_domains),
+                   bool(node.presorted))
+
+    def apply(self, page, consts, build_key):
+        with jax.named_scope("op:Aggregation"):
+            return grouped_aggregate(
+                page, list(self.group_exprs), list(self.aggs),
+                self.max_groups, key_domains=list(self.key_domains),
+                mode="partial", presorted=self.presorted,
+            )
+
+
+class Probe(NamedTuple):
+    left_keys: Tuple[Expr, ...]
+    key_domains: tuple
+    kind: str
+    null_safe: bool
+    null_aware: bool
+    build_arity: int
+
+    @classmethod
+    def of(cls, node, max_groups):
+        return cls(tuple(node.left_keys), tuple(node.key_domains or ()),
+                   node.kind, node.null_safe_keys,
+                   getattr(node, "null_aware", False),
+                   len(node.right.channels))
+
+    def apply(self, page, consts, build_key):
+        with jax.named_scope("op:Join"):
+            return probe_join(
+                consts[build_key], page, list(self.left_keys),
+                key_domains=list(self.key_domains), kind=self.kind,
+                build_output=list(range(self.build_arity)),
+                null_safe=self.null_safe, null_aware=self.null_aware,
+            )
+
+
+class Cross1(NamedTuple):
+    @classmethod
+    def of(cls, node, max_groups):
+        return cls()
+
+    def apply(self, page, consts, build_key):
+        with jax.named_scope("op:CrossSingle"):
+            return cross_append_single(page, consts[build_key])
+
+
+class Compact(NamedTuple):
+    """Not a plan node's: ``lower_chain`` places it (``_compact_at``).
+    The first ``capacity >> k`` live rows of the page move to a page of
+    that capacity, and every stage after it runs over the small page.
+    Its ``apply`` also returns the page's live count: a chain that
+    compacts is ``fn(page, consts) -> (page, over)``, ``over`` a device
+    scalar saying the page held more live rows than fitted.  The answer
+    is then of the rows that fitted only, and the caller must not use
+    it (``LocalRunner._chain_pages`` runs the chain again,
+    uncompacted)."""
+
+    k: int
+
+    def apply(self, page, consts, build_key):
+        with jax.named_scope("op:Filter"):
+            return compact_page(page, max(page.capacity >> self.k, 1))
+
+
+#: stage kind -> its params class: the fields are the signature, the
+#: ``apply`` the program
+KINDS = {"filter": Filter, "project": Project, "agg_partial": AggPartial,
+         "probe": Probe, "cross1": Cross1, "compact": Compact}
+
+#: the kinds whose stage reads a build side, ``consts["build_<i>"]``
+_BUILDS = ("probe", "cross1")
+
+# what XLA would call a program that nothing names (``local._named``
+# names every chain the registry holds): after its outermost stage
+_UNNAMED = {"filter": "filter_stage", "project": "project_stage",
+            "agg_partial": "agg_stage", "probe": "probe_stage",
+            "cross1": "cross_stage"}
+
+
+@dataclasses.dataclass(frozen=True)
+class Stage:
+    kind: str
+    params: tuple
+    node: Optional[PlanNode] = dataclasses.field(default=None, compare=False)
+
+
+def _member(node: PlanNode, streaming) -> Optional[Tuple[str, PlanNode]]:
+    """THE chain grammar: (stage kind, the source that streams into it)
+    of a chain member, None of anything else: a chain's leaf."""
+    if isinstance(node, FilterNode):
+        return "filter", node.source
+    if isinstance(node, ProjectNode):
+        return "project", node.source
+    if isinstance(node, AggregationNode) and node.step == "partial":
+        return "agg_partial", node.source
+    if isinstance(node, JoinNode) and streaming(node):
+        return "probe", node.left  # probe side streams
+    if isinstance(node, CrossSingleNode):
+        return "cross1", node.left
+    return None
+
+
+def chain_leaf(node: PlanNode, streaming=streams) -> PlanNode:
+    """Where the chain rooted at ``node`` reads its pages: a scan, or a
+    breaker (``node`` itself where it is no chain member)."""
+    while True:
+        member = _member(node, streaming)
+        if member is None:
+            return node
+        node = member[1]
+
+
+@dataclasses.dataclass(frozen=True)
+class Chain:
+    leaf: PlanNode
+    stages: Tuple[Stage, ...]  # leaf first
+
+    @property
+    def joins(self) -> List[PlanNode]:
+        """The nodes whose build side a stage reads, in ``build_<i>``
+        order."""
+        return [s.node for s in self.stages if s.kind in _BUILDS]
+
+    @property
+    def compacts(self) -> bool:
+        return any(s.kind == "compact" for s in self.stages)
+
+    def uncompacted(self) -> "Chain":
+        """The same chain with no compaction: what runs after a miss."""
+        return Chain(self.leaf, tuple(
+            s for s in self.stages if s.kind != "compact"))
+
+    def signature(self, upto: Optional[int] = None) -> tuple:
+        """What the program of the first ``upto`` stages (all of them:
+        None) depends on, leaf first."""
+        return (("leaf",),) + tuple(
+            (s.kind,) + tuple(s.params) for s in self.stages[:upto])
+
+    def name(self, upto: Optional[int] = None) -> str:
+        """The program's name: the stage kinds leaf first, an
+        aggregation tagged with its counts of keys and aggregates
+        (``chain_leaf_filter_agg_k2a8`` is TPC-H q1's), a compaction as
+        ``compact`` where it happens
+        (``chain_leaf_filter_compact_probe_agg_k0a2`` is q14's).  Of
+        the structure only: the name is part of the persistent compile
+        cache's key (``local._named``)."""
+        tags = ["leaf"]
+        for s in self.stages[:upto]:
+            if s.kind == "agg_partial":
+                tags.append(f"agg_k{len(s.params.group_exprs)}"
+                            f"a{len(s.params.aggs)}")
+            else:
+                tags.append(s.kind)
+        return "chain_" + "_".join(tags)
+
+    def fn(self, upto: Optional[int] = None) -> Callable:
+        """``fn(page, consts) -> page`` of the first ``upto`` stages
+        (all of them: None), the identity over a bare leaf;
+        ``-> (page, over)`` where one of them compacts
+        (:class:`Compact`)."""
+        # (params, build key) only: the registry keeps this closure for
+        # the life of the process, and a Stage would pin its plan node
+        # (and through it the whole plan) behind it
+        stages = self.stages[:upto]
+        steps, builds = [], 0
+        for s in stages:
+            steps.append((s.params, f"build_{builds}"))
+            builds += s.kind in _BUILDS
+
+        def run(page, consts):
+            live = None
+            for params, key in steps:
+                out = params.apply(page, consts, key)
+                if isinstance(params, Compact):
+                    page, live = out
+                    cap_out = page.capacity
+                else:
+                    page = out
+            return page if live is None else (page, live > cap_out)
+
+        if stages:
+            run.__name__ = run.__qualname__ = (
+                "compact_stage" if any(s.kind == "compact" for s in stages)
+                else _UNNAMED[stages[-1].kind])
+        return run
+
+
+def lower_chain(root: PlanNode, *, max_groups: Callable[[AggregationNode], int],
+                streaming: Callable[[JoinNode], bool] = streams,
+                compact_k: Optional[int] = None) -> Chain:
+    """The chain rooted at ``root``.  ``streaming`` says which joins
+    probe inside a chain and ``max_groups`` resolves a partial
+    aggregation's capacity: what ``LocalRunner._streaming`` (a join
+    whose build spilled is demoted) and ``LocalRunner._max_groups``
+    (a capacity retry raises it) answer.  ``compact_k`` sets the
+    compaction's k instead of the estimate's (0: never compact), for
+    tests and for callers that take pages only."""
+    stages: List[Stage] = []
+    node = root
+    while True:
+        member = _member(node, streaming)
+        if member is None:
+            break
+        kind, source = member
+        stages.append(Stage(kind, KINDS[kind].of(node, max_groups), node))
+        node = source
+    stages.reverse()
+    at = _compact_at(node, stages, compact_k)
+    if at is not None:
+        stages.insert(at[0], Stage("compact", Compact(at[1])))
+    return Chain(node, tuple(stages))
+
+
+# A compacting chain holds its partial pages, of max_groups rows each,
+# until its last split is dispatched (``LocalRunner._chain_pages``):
+# with at most this many groups all of them together stay under one
+# page of the scan (the capacity ladder's step, ``bucket_capacity``).
+COMPACT_MAX_GROUPS = 1 << 16
+
+
+def _compact_k(share: float) -> int:
+    """The k of ``_compact_at`` for an estimated live share: the
+    largest with ``2 * share <= 2**-k``, or 0 (no compaction) below 3.
+    The factor of two is room for the estimate; a chain one of whose
+    pages still holds more runs again, uncompacted."""
+    k = 0
+    while k < 30 and 2.0 * share * (2 << k) <= 1.0:
+        k += 1
+    return k if k >= 3 else 0
+
+
+def _compact_at(leaf: PlanNode, stages: List[Stage],
+                compact_k: Optional[int]) -> Optional[Tuple[int, int]]:
+    """Where the chain compacts its page and how far: ``(i, k)``, the
+    live rows of the input of the probe ``stages[i]`` moved to a page
+    of ``capacity >> k`` before it is probed, or None.
+
+    The probe is the one nearest the leaf with a FilterNode in
+    front of it since the leaf or the probe before; k the largest
+    for which twice the filters' estimated share of their source's
+    rows fits ``2**-k``, and at least 3 (``_compact_k``).  Only a
+    chain over a table scan that ends in a partial aggregation of
+    at most ``COMPACT_MAX_GROUPS`` groups compacts: its pages are
+    held until the last has said whether it fitted, and after a
+    miss the scan is read again (``LocalRunner._chain_pages``).  The
+    answer is a function of the plan and the catalog's column metadata
+    alone, never of what a run observed: a served statement must find
+    its program compiled."""
+    if compact_k == 0 or not (
+            stages and stages[-1].kind == "agg_partial"
+            and stages[-1].params.max_groups <= COMPACT_MAX_GROUPS
+            and isinstance(leaf, TableScanNode)):
+        return None
+    for i, s in enumerate(stages):
+        if s.kind != "probe":
+            continue
+        share = _filtered_share(s.node.left)
+        if share is not None:
+            k = _compact_k(share) if compact_k is None else compact_k
+            if k:
+                return i, k
+    return None
+
+
+def _filtered_share(node: PlanNode) -> Optional[float]:
+    """The textbook estimate (``StatsCalculator`` without history)
+    of the share of their source's rows that the filters at the
+    top of ``node`` keep; None without a filter there, or where the
+    estimate would have to read a materialized page."""
+    from presto_tpu.planner.stats import StatsCalculator
+
+    source, filtered = node, False
+    while isinstance(source, (FilterNode, ProjectNode)):
+        filtered = filtered or isinstance(source, FilterNode)
+        source = source.source
+    if not filtered:
+        return None
+    below = [source]
+    while below:
+        n = below.pop()
+        if isinstance(n, PrecomputedNode):
+            return None
+        below.extend(n.sources)
+    calc = StatsCalculator()
+    rows = calc.rows(source)
+    return calc.rows(node) / rows if rows > 0 else None

@@ -24,31 +24,27 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from presto_tpu.catalog import Catalog
+from presto_tpu.exec.chain import Chain, is_streaming_join, lower_chain
 from presto_tpu.ops.aggregate import grouped_aggregate, merge_aggregate
-from presto_tpu.ops.filter_project import (
-    compact_page, filter_page, project_page,
-)
 from presto_tpu.ops.join import JoinBuild, build_join, probe_expand, probe_join
 from presto_tpu.ops.sort import limit_page, sort_page, sort_perm, topn_page
 from presto_tpu.page import Block, Page
 from presto_tpu.planner.plan import (
     AggregationNode,
     CrossSingleNode,
-    FilterNode,
     GroupIdNode,
     JoinNode,
     LimitNode,
     OutputNode,
     PlanNode,
     PrecomputedNode,
-    ProjectNode,
     RemoteSourceNode,
     SortNode,
     TableScanNode,
@@ -136,36 +132,6 @@ def pad_page_to(page: Page, tgt: int) -> Page:
     return Page(blocks, pm)
 
 
-# A/B escape hatches, resolved ONCE per process (engine_lint env-read
-# rule: pad_page_pow2 runs per page, _run_aggregation_impl per query —
-# neither is a place for an environment lookup); the set_* hooks
-# override for tests/tools without touching the environment.
-from presto_tpu.envflag import EnvFlag
-
-#: ``PRESTO_TPU_PAD_SCAN=0`` disables scan-page ladder padding
-#: (uniform-capacity pass included) for A/B runs.
-_PAD_SCAN = EnvFlag("PRESTO_TPU_PAD_SCAN", default=True)
-#: ``PRESTO_TPU_AGG_TOWER=0`` reverts to the running-fold aggregation
-#: path for A/B runs.
-_AGG_TOWER = EnvFlag("PRESTO_TPU_AGG_TOWER", default=True)
-
-
-def pad_scan_enabled() -> bool:
-    return _PAD_SCAN()
-
-
-def set_pad_scan(value: Optional[bool]) -> None:
-    _PAD_SCAN.set(value)
-
-
-def agg_tower_enabled() -> bool:
-    return _AGG_TOWER()
-
-
-def set_agg_tower(value: Optional[bool]) -> None:
-    _AGG_TOWER.set(value)
-
-
 def pad_page_pow2(page: Page) -> Page:
     """Pad a page with dead rows up to its bucketed capacity
     (bucket_capacity).  Scan splits otherwise carry data-dependent
@@ -173,8 +139,6 @@ def pad_page_pow2(page: Page) -> Page:
     distinct capacity costs a full XLA compile of the whole chain
     program — the dominant cold-start cost (19 of q3's 32 warmup
     compiles were one agg program re-traced per shape)."""
-    if not pad_scan_enabled():
-        return page
     return pad_page_to(page, bucket_capacity(page.capacity))
 
 
@@ -197,23 +161,6 @@ def slice_page(page: Page, n: int) -> Page:
         Block(b.data[:n], b.valid[:n], b.type, b.dictionary) for b in page.blocks
     )
     return Page(blocks, page.row_mask[:n])
-
-
-def cross_append_single(q: Page, r: Page) -> Page:
-    """Append a single-row page's columns to every row of ``q`` (the
-    cross-join-with-scalar-subquery kernel, EnforceSingleRow +
-    NestedLoopJoin's one-row case)."""
-    blocks = list(q.blocks)
-    for b in r.blocks:
-        blocks.append(
-            Block(
-                jnp.broadcast_to(b.data[0], (q.capacity,) + b.data.shape[1:]),
-                jnp.broadcast_to(b.valid[0] & r.row_mask[0], (q.capacity,)),
-                b.type,
-                b.dictionary,
-            )
-        )
-    return Page(tuple(blocks), q.row_mask)
 
 
 class QueryStats:
@@ -390,46 +337,11 @@ def _named(f, name: str):
     return f
 
 
-def _chain_name(sig) -> str:
-    """A chain program's name from its ``_stage_signature``: the stage
-    tags leaf first, an aggregation tagged with its counts of keys and
-    aggregates (``chain_leaf_filter_agg_k2a8`` is TPC-H q1's), a
-    compaction as ``compact`` where it happens
-    (``chain_leaf_filter_compact_probe_agg_k0a2`` is q14's)."""
-    tags = []
-    while True:
-        tag = sig[0]
-        if tag == "agg_partial":
-            tag = f"agg_k{len(sig[1])}a{len(sig[2])}"
-        tags.append(tag)
-        if tag == "leaf":
-            return "chain_" + "_".join(reversed(tags))
-        sig = sig[-1]
-
-
 # this thread's counts, never reset: .n host reads; .compacted and
 # .fallback pages of compacting chains (``compact_counts``);
 # .expand_retries and .expanded_rows of expanding probes
 # (``expand_counts``)
 _HOST_READS = threading.local()
-
-
-# A compacting chain holds its partial pages, of max_groups rows each,
-# until its last split is dispatched (``_chain_pages``): with at most
-# this many groups all of them together stay under one page of the scan
-# (the capacity ladder's step, ``bucket_capacity``).
-COMPACT_MAX_GROUPS = 1 << 16
-
-
-def _compact_k(share: float) -> int:
-    """The k of ``_compact_point`` for an estimated live share: the
-    largest with ``2 * share <= 2**-k``, or 0 (no compaction) below 3.
-    The factor of two is room for the estimate; a chain one of whose
-    pages still holds more runs again, uncompacted."""
-    k = 0
-    while k < 30 and 2.0 * share * (2 << k) <= 1.0:
-        k += 1
-    return k if k >= 3 else 0
 
 
 def host_reads() -> int:
@@ -525,7 +437,7 @@ class _AggFoldTower:
     # of one more; merging <=4096 rows is noise on the VPU either way
     MIN_CAP = 1 << 12
 
-    def __init__(self, runner, node, num_keys, aggs, kd, mg, account=True):
+    def __init__(self, runner, node, fns, mg, account=True):
         self.runner = runner
         self.node = node
         self.mg = mg
@@ -535,38 +447,36 @@ class _AggFoldTower:
         # may have dropped groups; the total live count sizes the retry
         self.suspect_truncation = False
         self.live_total = 0
-        cache_key = (node, "tower")
-        fns = runner._fold_cache.get(cache_key)
-        if fns is None:
-            def fold(pages, out_cap):
-                with jax.named_scope("op:Aggregation"):
-                    return merge_aggregate(
-                        concat_pages_device(list(pages)), num_keys,
-                        list(aggs), out_cap, key_domains=kd, mode="partial",
-                        return_count=True)
-
-            def final(pages, out_cap):
-                with jax.named_scope("op:Aggregation"):
-                    return merge_aggregate(
-                        concat_pages_device(list(pages)), num_keys,
-                        list(aggs), out_cap, key_domains=kd, mode="single")
-
-            _named(fold, "agg_tower_fold")
-            _named(final, "agg_tower_final")
-            sig = (num_keys, tuple(aggs), tuple(kd or ()))
-            fold_p = runner._program(
-                "agg_tower_fold", sig,
-                lambda f=fold: jax.jit(f, static_argnames=("out_cap",))
-                if runner.jit else f,
-                node=node)
-            final_p = runner._program(
-                "agg_tower_final", sig,
-                lambda f=final: jax.jit(f, static_argnames=("out_cap",))
-                if runner.jit else f,
-                node=node)
-            runner._fold_cache[cache_key] = (fold_p, final_p)
-            fns = (fold_p, final_p)
         self.fold, self.final = fns
+
+    @staticmethod
+    def programs(runner, num_keys, aggs, kd):
+        """The (fold, final) programs every tower of one aggregation
+        shares: fetched once per operator, not once per tower (the
+        spilled path builds one per bucket and retry)."""
+        def fold(pages, out_cap):
+            with jax.named_scope("op:Aggregation"):
+                return merge_aggregate(
+                    concat_pages_device(list(pages)), num_keys,
+                    list(aggs), out_cap, key_domains=kd, mode="partial",
+                    return_count=True)
+
+        def final(pages, out_cap):
+            with jax.named_scope("op:Aggregation"):
+                return merge_aggregate(
+                    concat_pages_device(list(pages)), num_keys,
+                    list(aggs), out_cap, key_domains=kd, mode="single")
+
+        _named(fold, "agg_tower_fold")
+        _named(final, "agg_tower_final")
+        sig = (num_keys, tuple(aggs), tuple(kd or ()))
+        return tuple(
+            runner._program(
+                kind, sig,
+                lambda f=f: jax.jit(f, static_argnames=("out_cap",))
+                if runner.jit else f)
+            for kind, f in (("agg_tower_fold", fold),
+                            ("agg_tower_final", final)))
 
     def _cap(self, n: int) -> int:
         """Pow2 capacity bound — never clamped to max_groups: tower
@@ -642,16 +552,6 @@ def _probe_with_retry(probe_fn, build, page):
     return res
 
 
-def _is_streaming_join(node: JoinNode) -> bool:
-    """True when the probe is row-aligned (jittable in a chain):
-    semi/anti (presence tests) or unique-key builds. FULL joins always
-    take the materializing path — the unmatched-build tail needs
-    cross-page match state."""
-    if node.kind == "full":
-        return False
-    return node.kind in ("semi", "anti", "mark") or node.unique_build
-
-
 class LocalRunner:
     """Executes a plan tree against registered connectors.
 
@@ -664,7 +564,6 @@ class LocalRunner:
                  task_prefetch: Optional[int] = None):
         from presto_tpu.exec.programs import (
             default_registry, enable_persistent_cache,
-            structural_sharing_enabled,
         )
         from presto_tpu.exec.tasks import (
             task_concurrency_default, task_prefetch_default,
@@ -688,8 +587,6 @@ class LocalRunner:
         # compiled callables keyed by kernel family + canonical IR +
         # baked-in parameters, shared process-wide unless injected
         self.programs = programs if programs is not None else default_registry()
-        self._structural = structural_sharing_enabled()
-        self._own_registry = None  # per-node keying when sharing is off
         enable_persistent_cache()
         # env-dependent kernel choices resolve ONCE at construction —
         # not per join build (satellite of the registry PR)
@@ -722,8 +619,6 @@ class LocalRunner:
         import threading as _threading
 
         self._mem_tls = _threading.local()
-        self._chain_cache: Dict[PlanNode, Callable] = {}
-        self._fold_cache: Dict[PlanNode, Callable] = {}
         self._agg_overrides: Dict[PlanNode, int] = {}
         self._partial_nodes: Dict[PlanNode, AggregationNode] = {}
         # per-THREAD materialized join builds: device-resident state
@@ -975,10 +870,8 @@ class LocalRunner:
         peak = getattr(self, "last_peak_bytes", 0)
         if peak:
             text = f"peak reserved memory: {peak / 1e6:.1f}MB\n" + text
-        progs = self.compiled_program_count()
-        if progs is not None:
-            text = f"compiled XLA programs: {progs}\n" + text
-        reg = (self._own_registry or self.programs).stats()
+        reg = self.programs.stats()
+        text = f"compiled XLA programs: {reg['programs']}\n" + text
         line = (f"program registry: {reg['callables']} callables, "
                 f"{reg['programs']} compiled programs, "
                 f"{reg['hits']} hits / {reg['misses']} misses, "
@@ -997,163 +890,32 @@ class LocalRunner:
             text = report.summary() + "\n" + text
         return text
 
-    def compiled_program_count(self) -> Optional[int]:
-        """Distinct compiled XLA programs behind this runner's cached
-        jitted callables (each shape signature of each callable is one
-        program — the TPU cold-start cost driver; VERDICT r4 #9)."""
-        total = 0
-        seen = set()
-        entries = list(self._chain_cache.values())
-        for v in self._fold_cache.values():
-            if isinstance(v, (tuple, list)):
-                entries.extend(x for x in v if x is not None)
-            else:
-                entries.append(v)
-        for fn in entries:
-            if id(fn) in seen:
-                continue
-            seen.add(id(fn))
-            try:
-                total += fn._cache_size()
-            except Exception:
-                total += 1  # non-jitted (debug mode) counts as one
-        return total
-
-    def _program(self, kind: str, sig, factory, node=None):
+    def _program(self, kind: str, sig, factory):
         """Compiled callable for (kind, structural signature) from the
-        shared registry — identical operator shapes in other plans,
-        queries, and runners resolve to the same callable.  With
-        structural sharing disabled (PRESTO_TPU_PROGRAM_REGISTRY=0,
-        the A/B baseline) the key degrades to per-PlanNode identity in
-        a runner-private registry, i.e. the pre-registry behavior."""
-        if self._structural or node is None:
-            return self.programs.get(kind, sig, factory, jit=self.jit)
-        from presto_tpu.exec.programs import ProgramRegistry
+        shared registry, the only program cache there is: identical
+        operator shapes in other plans, queries and runners resolve to
+        the same callable.  ``sig`` holds every value ``factory``'s
+        callable bakes in, and a capacity retry or a demoted join
+        changes it (``_max_groups``, ``_streaming``), so nothing is
+        ever invalidated.  Ask once per operator per statement, not
+        once per page."""
+        return self.programs.get(kind, sig, factory, jit=self.jit)
 
-        # A/B baseline: NO dedup — every request compiles fresh and the
-        # per-runner memo dicts are the only cache (seed behavior), so
-        # capacity-retry invalidation (memo deletion) fully retires a
-        # stale program; a keyed per-node registry would hand the retry
-        # the old max-groups capacity back.  The private registry holds
-        # the programs solely for metrics (unique monotonic keys).
-        if self._own_registry is None:
-            self._own_registry = ProgramRegistry()
-        self._ab_seq = getattr(self, "_ab_seq", 0) + 1
-        return self._own_registry.get(kind, ("ab", self._ab_seq), factory,
-                                      jit=self.jit)
-
-    def _stage_signature(self, node: PlanNode,
-                         compact_k: Optional[int] = None):
-        """Structural signature of the fused streaming chain rooted at
-        ``node``.  Mirrors ``_build_stage`` member-for-member: every
-        parameter a stage closure bakes in (expression IR, resolved
-        capacities, key domains, join kind/flags, build arity, where
-        the chain compacts and how far) is part of the signature, so
-        equal signatures guarantee the cached callable computes the
-        same function.  Input-page schemas are NOT included — they ride
-        as jit-static pytree aux data (types + dictionaries) and key
-        jit's own trace cache."""
-        return self._signature(node, self._compact_point(node, compact_k))
-
-    def _signature(self, node: PlanNode, at):
-        if isinstance(node, FilterNode):
-            return ("filter", node.predicate,
-                    self._signature(node.source, at))
-        if isinstance(node, ProjectNode):
-            return ("project", tuple(node.projections),
-                    self._signature(node.source, at))
-        if isinstance(node, AggregationNode) and node.step == "partial":
-            return ("agg_partial", tuple(node.group_exprs),
-                    tuple(node.aggs), self._max_groups(node),
-                    tuple(node.key_domains),
-                    bool(getattr(node, "presorted", False)),
-                    self._signature(node.source, at))
-        if isinstance(node, JoinNode) and self._streaming(node):
-            inner = self._signature(node.left, at)
-            if at is not None and at[0] is node:
-                inner = ("compact", at[1], inner)
-            return ("probe", tuple(node.left_keys),
-                    tuple(node.key_domains or ()), node.kind,
-                    node.null_safe_keys, getattr(node, "null_aware", False),
-                    len(node.right.channels), inner)
-        if isinstance(node, CrossSingleNode):
-            return ("cross1", self._signature(node.left, at))
-        return ("leaf",)
-
-    def _compact_point(self, node: PlanNode,
-                       compact_k: Optional[int] = None):
-        """Where the chain rooted at ``node`` compacts its page and how
-        far: ``(probe, k)``, the live rows of ``probe``'s input moved to
-        a page of ``capacity >> k`` before it is probed, or None.
-
-        The probe is the one nearest the leaf with a FilterNode in
-        front of it since the leaf or the probe before; k the largest
-        for which twice the filters' estimated share of their source's
-        rows fits ``2**-k``, and at least 3 (``_compact_k``).  Only a
-        chain over a table scan that ends in a partial aggregation of
-        at most ``COMPACT_MAX_GROUPS`` groups compacts: its pages are
-        held until the last has said whether it fitted, and after a
-        miss the scan is read again (``_chain_pages``).  The answer is
-        a function of the plan and the catalog's column metadata alone,
-        never of what a run observed: a served statement must find its
-        program compiled.  ``compact_k`` sets k instead of the
-        estimate's (0: never), for tests."""
-        if compact_k == 0 or not (
-                isinstance(node, AggregationNode) and node.step == "partial"
-                and self._max_groups(node) <= COMPACT_MAX_GROUPS
-                and isinstance(self._chain_leaf(node), TableScanNode)):
-            return None
-        found = None
-        while self._is_chain_member(node):
-            if isinstance(node, JoinNode):
-                share = self._filtered_share(node.left)
-                if share is not None:
-                    k = _compact_k(share) if compact_k is None else compact_k
-                    if k:
-                        found = (node, k)
-            node = (node.left if isinstance(node, (JoinNode, CrossSingleNode))
-                    else node.source)
-        return found
-
-    @staticmethod
-    def _filtered_share(node: PlanNode) -> Optional[float]:
-        """The textbook estimate (``StatsCalculator`` without history)
-        of the share of their source's rows that the filters at the
-        top of ``node`` keep; None without a filter there, or where the
-        estimate would have to read a materialized page."""
-        from presto_tpu.planner.stats import StatsCalculator
-
-        source, filtered = node, False
-        while isinstance(source, (FilterNode, ProjectNode)):
-            filtered = filtered or isinstance(source, FilterNode)
-            source = source.source
-        if not filtered:
-            return None
-        below = [source]
-        while below:
-            n = below.pop()
-            if isinstance(n, PrecomputedNode):
-                return None
-            below.extend(n.sources)
-        calc = StatsCalculator()
-        rows = calc.rows(source)
-        return calc.rows(node) / rows if rows > 0 else None
-
-    def _is_chain_member(self, n: PlanNode) -> bool:
-        return (
-            isinstance(n, (FilterNode, ProjectNode, CrossSingleNode))
-            or (isinstance(n, AggregationNode) and n.step == "partial")
-            or (isinstance(n, JoinNode) and not n.use_index and self._streaming(n))
-        )
+    def _lower(self, node: PlanNode,
+               compact_k: Optional[int] = None) -> Chain:
+        """The streaming chain rooted at ``node``, as this runner
+        stands: its demoted joins, its capacity retries."""
+        return lower_chain(node, streaming=self._streaming,
+                           max_groups=self._max_groups, compact_k=compact_k)
 
     def _exclusive_times(self, plan: PlanNode) -> Dict[PlanNode, float]:
         out: Dict[PlanNode, float] = {}
 
-        def walk(n: PlanNode, parent_in_chain: bool) -> None:
-            member = self._is_chain_member(n)
-            if member and not parent_in_chain:
+        def walk(n: PlanNode) -> None:
+            chain = self._lower(n)
+            if chain.stages:
                 try:
-                    self._time_chain(n, out)
+                    self._time_chain(chain, out)
                 except Exception as e:
                     # attribution is best-effort diagnostics, but a
                     # failure must not be invisible (VERDICT r3): the
@@ -1166,28 +928,20 @@ class LocalRunner:
                         "%s chain: %s: %s", type(n).__name__,
                         type(e).__name__, e)
                     out.setdefault(n, float("nan"))
-            if isinstance(n, (JoinNode, CrossSingleNode)):
-                walk(n.sources[0], member)  # probe side continues chain
-                walk(n.sources[1], False)  # build side is its own tree
-            else:
-                for s in n.sources:
-                    walk(s, member)
+            for j in chain.joins:
+                walk(j.sources[1])  # a build side is its own tree
+            for s in chain.leaf.sources:
+                walk(s)
 
-        walk(plan, False)
+        walk(plan)
         return out
 
-    def _time_chain(self, root: PlanNode, out: Dict[PlanNode, float]) -> None:
-        """Time prefix programs of the chain rooted at ``root`` and
-        record per-member deltas (and the leaf's own source time)."""
+    def _time_chain(self, chain: Chain, out: Dict[PlanNode, float]) -> None:
+        """Time prefix programs of ``chain`` and record per-member
+        deltas (and the leaf's own source time)."""
         import time
 
-        seq: List[PlanNode] = []
-        n = root
-        while self._is_chain_member(n):
-            seq.append(n)
-            n = n.sources[0] if isinstance(n, (JoinNode, CrossSingleNode)) else n.source
-        leaf = n
-
+        leaf = chain.leaf
         t0 = time.perf_counter()
         pages = list(self._source_pages(leaf))
         jax.block_until_ready(pages)
@@ -1201,21 +955,18 @@ class LocalRunner:
         # a prefix that holds the chain's compacting probe compacts as
         # the chain does, so a member's time is its time in the program
         # that runs (the compaction's is booked to the probe)
-        at = self._compact_point(root)
+        consts = {f"build_{i}": self._materialize_build(j)
+                  for i, j in enumerate(chain.joins)}
         prev = 0.0
-        for pos, prefix_root in reversed(list(enumerate(seq))):
-            joins: List[JoinNode] = []
-            compacts = at is not None and any(n is at[0] for n in seq[pos:])
-            stage = self._build_chain(prefix_root, joins,
-                                      at if compacts else None)
-            consts = {f"build_{i}": self._materialize_build(j)
-                      for i, j in enumerate(joins)}
-            fn = jax.jit(stage) if self.jit else stage
+        for upto, stage in enumerate(chain.stages, 1):
+            if stage.node is None:
+                continue
+            fn = jax.jit(chain.fn(upto)) if self.jit else chain.fn(upto)
             jax.block_until_ready([fn(p, consts) for p in pages])  # compile
             t0 = time.perf_counter()
             jax.block_until_ready([fn(p, consts) for p in pages])
             t = time.perf_counter() - t0
-            out[prefix_root] = max(t - prev, 0.0)
+            out[stage.node] = max(t - prev, 0.0)
             prev = t
 
     # ------------------------------------------------------------------
@@ -1334,19 +1085,15 @@ class LocalRunner:
             sort_exprs = list(node.sort_exprs)
             ascending = list(node.ascending)
             nulls_first = node.nulls_first
-            fn = self._fold_cache.get(node)
-            if fn is None:
 
-                def do_sort(p):
-                    with jax.named_scope("op:Sort"):
-                        return sort_page(p, sort_exprs, ascending, nulls_first)
+            def do_sort(p):
+                with jax.named_scope("op:Sort"):
+                    return sort_page(p, sort_exprs, ascending, nulls_first)
 
-                _named(do_sort, "sort")
-                fn = self._program(
-                    "sort", (sort_exprs, ascending, nulls_first),
-                    lambda: jax.jit(do_sort) if self.jit else do_sort,
-                    node=node)
-                self._fold_cache[node] = fn
+            _named(do_sort, "sort")
+            fn = self._program(
+                "sort", (sort_exprs, ascending, nulls_first),
+                lambda: jax.jit(do_sort) if self.jit else do_sort)
             pages = list(self._pages(node.source))
             if len(pages) > 1 and self.merge_sort:
                 # distributed-sort shape: sort each producer page, then
@@ -1417,29 +1164,25 @@ class LocalRunner:
             return
 
         if isinstance(node, WindowNode):
+            from presto_tpu.ops.window import window_page
+
             src = self._execute_to_page(node.source)
-            fn = self._fold_cache.get(node)
-            if fn is None:
-                from presto_tpu.ops.window import window_page
+            partition_exprs = list(node.partition_exprs)
+            order_exprs = list(node.order_exprs)
+            ascending = list(node.ascending)
+            funcs = list(node.funcs)
+            pd = node.partition_domains
 
-                partition_exprs = list(node.partition_exprs)
-                order_exprs = list(node.order_exprs)
-                ascending = list(node.ascending)
-                funcs = list(node.funcs)
-                pd = node.partition_domains
+            def do_window(p):
+                return window_page(
+                    p, partition_exprs, order_exprs, ascending, funcs,
+                    partition_domains=pd,
+                )
 
-                def do_window(p):
-                    return window_page(
-                        p, partition_exprs, order_exprs, ascending, funcs,
-                        partition_domains=pd,
-                    )
-
-                fn = self._program(
-                    "window",
-                    (partition_exprs, order_exprs, ascending, funcs, pd),
-                    lambda: jax.jit(do_window) if self.jit else do_window,
-                    node=node)
-                self._fold_cache[node] = fn
+            fn = self._program(
+                "window",
+                (partition_exprs, order_exprs, ascending, funcs, pd),
+                lambda: jax.jit(do_window) if self.jit else do_window)
             yield fn(src)
             return
 
@@ -1448,24 +1191,20 @@ class LocalRunner:
             return
 
         if isinstance(node, UnnestNode):
-            fn = self._fold_cache.get(node)
-            if fn is None:
-                from presto_tpu.ops.container import unnest_expand
+            from presto_tpu.ops.container import unnest_expand
 
-                exprs = list(node.unnest_exprs)
-                ordinality = node.ordinality
-                chans = node.channels
+            exprs = list(node.unnest_exprs)
+            ordinality = node.ordinality
+            chans = node.channels
 
-                def do_unnest(p: Page) -> Page:
-                    return unnest_expand(p, exprs, ordinality, chans)
+            def do_unnest(p: Page) -> Page:
+                return unnest_expand(p, exprs, ordinality, chans)
 
-                fn = self._program(
-                    "unnest",
-                    (exprs, ordinality,
-                     [(c.type, c.dictionary) for c in chans]),
-                    lambda: jax.jit(do_unnest) if self.jit else do_unnest,
-                    node=node)
-                self._fold_cache[node] = fn
+            fn = self._program(
+                "unnest",
+                (exprs, ordinality,
+                 [(c.type, c.dictionary) for c in chans]),
+                lambda: jax.jit(do_unnest) if self.jit else do_unnest)
             for p in self._pages(node.source):
                 yield fn(p)
             return
@@ -1484,7 +1223,7 @@ class LocalRunner:
     def _streaming(self, node: JoinNode) -> bool:
         # index joins must not fuse into chains: the chain builder would
         # materialize the full build scan instead of point lookups
-        return (_is_streaming_join(node) and node not in self._force_expanding
+        return (is_streaming_join(node) and node not in self._force_expanding
                 and not node.use_index)
 
     # ------------------------------------------------------------------
@@ -1496,10 +1235,8 @@ class LocalRunner:
         # pop the unordered grant FIRST: it applies to this chain only,
         # never to nested chains pulled while materializing builds
         unordered = self._take_unordered()
-        leaf = self._chain_leaf(node)
-        joins: List[JoinNode] = []
-        at = self._compact_point(node)
-        stage = self._build_chain(node, joins, at)
+        chain = self._lower(node)
+        joins = chain.joins
         try:
             consts = {f"build_{i}": self._materialize_build(j) for i, j in enumerate(joins)}
         except ExceededMemoryLimitError as e:
@@ -1509,17 +1246,11 @@ class LocalRunner:
             # demote the oversized build's join out of the fused chain;
             # it re-plans through the partitioned (spilled) join path
             self._force_expanding.add(victim)
-            self._chain_cache.clear()
-            self._fold_cache.clear()
             yield from self._pages_impl(node)
             return
-        if node in self._chain_cache:
-            fn = self._chain_cache[node]
-        else:
-            fn = self._chain_program(node, stage, at)
-            self._chain_cache[node] = fn
-        if at is None:
-            yield from self._chain_outputs(leaf, fn, consts, unordered)
+        fn = self._chain_program(chain)
+        if not chain.compacts:
+            yield from self._chain_outputs(chain.leaf, fn, consts, unordered)
             return
         # A compacting program answers for the rows that fitted its
         # small page and says whether all did.  Its (small, partial)
@@ -1529,7 +1260,7 @@ class LocalRunner:
         # row is ever dropped, whatever the estimate was worth.
         from presto_tpu.obs import METRICS
 
-        outs = list(self._chain_outputs(leaf, fn, consts, unordered))
+        outs = list(self._chain_outputs(chain.leaf, fn, consts, unordered))
         fitted = not any(host_read([over for _, over in outs],
                                    "compact_taken"))
         which = "compacted" if fitted else "fallback"
@@ -1541,17 +1272,17 @@ class LocalRunner:
             yield from (page for page, _ in outs)
             return
         del outs
-        whole = self._chain_program(node, self._build_stage(node, []), None)
-        yield from self._chain_outputs(leaf, whole, consts, unordered)
+        whole = self._chain_program(chain.uncompacted())
+        yield from self._chain_outputs(chain.leaf, whole, consts, unordered)
 
-    def _chain_program(self, node: PlanNode, stage, at):
-        """``stage`` named after its chain and compiled, or the
-        registry's program of the same signature."""
-        sig = self._signature(node, at)
-        _named(stage, _chain_name(sig))
-        return self._program(
-            "chain", sig,
-            lambda: jax.jit(stage) if self.jit else stage, node=node)
+    def _chain_program(self, chain: Chain):
+        """``chain``'s program, named after its stages and compiled, or
+        the registry's program of the same signature."""
+        def make():
+            stage = _named(chain.fn(), chain.name())
+            return jax.jit(stage) if self.jit else stage
+
+        return self._program("chain", chain.signature(), make)
 
     def _chain_outputs(self, leaf: PlanNode, fn, consts,
                        unordered: bool) -> Iterator:
@@ -1641,132 +1372,6 @@ class LocalRunner:
             drop=drop_split if mem is not None else None)
         yield from sched.map(produced(), run_split)
 
-    def _chain_leaf(self, node: PlanNode) -> PlanNode:
-        if isinstance(node, (FilterNode, ProjectNode)):
-            return self._chain_leaf(node.source)
-        if isinstance(node, AggregationNode) and node.step == "partial":
-            return self._chain_leaf(node.source)
-        if isinstance(node, JoinNode) and self._streaming(node):
-            return self._chain_leaf(node.left)  # probe side streams
-        if isinstance(node, CrossSingleNode):
-            return self._chain_leaf(node.left)
-        return node
-
-    def _build_chain(self, node: PlanNode, joins: List[JoinNode], at):
-        """``_build_stage``'s fn where the chain does not compact
-        (``at``, its ``_compact_point``, is None).  Where it does:
-        ``fn(page, consts) -> (page, over)``.  The stages below the
-        probe run as ever; the first ``capacity >> k`` live rows of
-        their page are compacted, and the probe and everything above
-        it run over that small page.  ``over``, a device scalar, says
-        the page held more live rows than that: the answer is then of
-        the rows that fitted only, and the caller must not use it
-        (``_chain_pages`` runs the chain again, uncompacted)."""
-        if at is None:
-            return self._build_stage(node, joins)
-        probe, k = at
-        head = self._build_stage(probe.left, joins)
-        rest = self._build_stage(node, joins, stop=probe.left)
-
-        def compact_stage(p, c):
-            p = head(p, c)
-            cap_out = max(p.capacity >> k, 1)
-            with jax.named_scope("op:Filter"):
-                small, live = compact_page(p, cap_out)
-            return rest(small, c), live > cap_out
-
-        return compact_stage
-
-    def _build_stage(self, node: PlanNode, joins: List[JoinNode],
-                     stop: Optional[PlanNode] = None):
-        """Recursively build fn(page, consts)->page for the streaming
-        prefix of ``node``; below the chain leaf, and at ``stop``, the
-        identity.
-
-        KEEP IN SYNC with ``_stage_signature``: every parameter a stage
-        closure bakes in here must appear in the signature, or two
-        different chains will share one compiled program (silent wrong
-        results, not a crash).  test_cold_compile pins the current
-        parameters' signature-sensitivity."""
-        if node is stop:
-            return lambda p, c: p
-        if isinstance(node, FilterNode):
-            inner = self._build_stage(node.source, joins, stop)
-            pred = node.predicate
-
-            def filter_stage(p, c):
-                p = inner(p, c)
-                with jax.named_scope("op:Filter"):
-                    return filter_page(p, pred)
-
-            return filter_stage
-
-        if isinstance(node, ProjectNode):
-            inner = self._build_stage(node.source, joins, stop)
-            projections = list(node.projections)
-
-            def project_stage(p, c):
-                p = inner(p, c)
-                with jax.named_scope("op:Project"):
-                    return project_page(p, projections)
-
-            return project_stage
-
-        if isinstance(node, AggregationNode) and node.step == "partial":
-            inner = self._build_stage(node.source, joins, stop)
-            group_exprs = list(node.group_exprs)
-            aggs = list(node.aggs)
-            mg = self._max_groups(node)
-            kd = node.key_domains
-            presorted = node.presorted
-
-            def agg_stage(p, c):
-                p = inner(p, c)
-                with jax.named_scope("op:Aggregation"):
-                    return grouped_aggregate(
-                        p, group_exprs, aggs, mg, key_domains=kd,
-                        mode="partial", presorted=presorted,
-                    )
-
-            return agg_stage
-
-        if isinstance(node, JoinNode) and self._streaming(node):
-            inner = self._build_stage(node.left, joins, stop)
-            key = f"build_{len(joins)}"
-            joins.append(node)
-            build_output = list(range(len(node.right.channels)))
-            kd = node.key_domains
-            left_keys = list(node.left_keys)
-            kind = node.kind
-            ns = node.null_safe_keys
-            na = getattr(node, "null_aware", False)
-
-            def probe_stage(p, c):
-                p = inner(p, c)
-                with jax.named_scope("op:Join"):
-                    return probe_join(
-                        c[key], p, left_keys, key_domains=kd,
-                        kind=kind, build_output=build_output, null_safe=ns,
-                        null_aware=na,
-                    )
-
-            return probe_stage
-
-        if isinstance(node, CrossSingleNode):
-            inner = self._build_stage(node.left, joins, stop)
-            key = f"build_{len(joins)}"
-            joins.append(node)
-
-            def cross_stage(p, c):
-                p = inner(p, c)
-                with jax.named_scope("op:CrossSingle"):
-                    return cross_append_single(p, c[key])
-
-            return cross_stage
-
-        # chain leaf (scan / breaker / expanding join): identity
-        return lambda p, c: p
-
     def _source_pages(self, node: PlanNode) -> Iterator[Page]:
         if isinstance(node, TableScanNode):
             conn = self.catalog.connector(node.handle.connector_name)
@@ -1833,9 +1438,7 @@ class LocalRunner:
             # generator's just-short tail must NOT overshoot past the
             # full splits' bucket).  Much smaller splits keep their own
             # bucket: padding a sliver to full capacity would multiply
-            # its compute, not add +6%.  PRESTO_TPU_PAD_SCAN=0 disables
-            # all scan padding, uniform included.
-            uniform = pad_scan_enabled()
+            # its compute, not add +6%.
             cap_hi = 0
             for split in splits:
                 if node.limit is not None and produced >= node.limit:
@@ -1870,7 +1473,7 @@ class LocalRunner:
                     produced += int(
                         host_read(page.row_mask, "limit_rows").sum())
                 raw = Page(tuple(page.blocks[i] for i in idx), page.row_mask)
-                if uniform and 0 < raw.capacity <= cap_hi \
+                if 0 < raw.capacity <= cap_hi \
                         and raw.capacity * 3 >= cap_hi:
                     out = pad_page_to(raw, cap_hi)
                 else:
@@ -1894,35 +1497,31 @@ class LocalRunner:
                 pages = tuple(self._pages(node.right))
                 if not pages:
                     pages = (Page.empty(node.right.output_types, 1),)
+
                 def build_fn(uniq: bool):
-                    fn = self._fold_cache.get((node, uniq))
-                    if fn is None:
-                        right_keys = list(node.right_keys)
-                        kd = node.key_domains
-                        ns = getattr(node, "null_safe_keys", False)
+                    right_keys = list(node.right_keys)
+                    kd = node.key_domains
+                    ns = getattr(node, "null_safe_keys", False)
 
-                        def make_build(ps, _u=uniq):
-                            # bucket the build capacity (concat sums the
-                            # producers' caps — a data-dependent shape
-                            # every downstream probe program would bake
-                            # in; padding dead rows restores the ladder)
-                            with jax.named_scope("op:JoinBuild"):
-                                return build_join(
-                                    pad_page_pow2(
-                                        concat_pages_device(list(ps))),
-                                    right_keys,
-                                    key_domains=kd, null_safe=ns, unique=_u,
-                                )
+                    def make_build(ps):
+                        # bucket the build capacity (concat sums the
+                        # producers' caps — a data-dependent shape
+                        # every downstream probe program would bake
+                        # in; padding dead rows restores the ladder)
+                        with jax.named_scope("op:JoinBuild"):
+                            return build_join(
+                                pad_page_pow2(
+                                    concat_pages_device(list(ps))),
+                                right_keys,
+                                key_domains=kd, null_safe=ns, unique=uniq,
+                            )
 
-                        _named(make_build, "join_build")
-                        fn = self._program(
-                            "join_build",
-                            (right_keys, tuple(kd or ()), ns, uniq),
-                            lambda: jax.jit(make_build) if self.jit
-                            else make_build,
-                            node=node)
-                        self._fold_cache[(node, uniq)] = fn
-                    return fn
+                    _named(make_build, "join_build")
+                    return self._program(
+                        "join_build",
+                        (right_keys, tuple(kd or ()), ns, uniq),
+                        lambda: jax.jit(make_build) if self.jit
+                        else make_build)
 
                 uniq = bool(getattr(node, "unique_build", False))
                 build = build_fn(uniq)(pages)
@@ -1969,17 +1568,12 @@ class LocalRunner:
 
         _named(probe, "join_probe")
 
-        if node in self._chain_cache:
-            fn = self._chain_cache[node]
-        else:
-            fn = self._program(
-                "join_probe",
-                (left_keys, tuple(kd or ()), kind, tuple(build_output),
-                 is_full, ns),
-                lambda: jax.jit(probe, static_argnames=("out_capacity",))
-                if self.jit else probe,
-                node=node)
-            self._chain_cache[node] = fn
+        fn = self._program(
+            "join_probe",
+            (left_keys, tuple(kd or ()), kind, tuple(build_output),
+             is_full, ns),
+            lambda: jax.jit(probe, static_argnames=("out_capacity",))
+            if self.jit else probe)
 
         matched_acc = None
         for p in self._pages(node.left):
@@ -2002,45 +1596,41 @@ class LocalRunner:
         """Emit each source page once per grouping set: source blocks +
         key blocks (inactive keys NULL-masked) + constant $group_id
         (GroupIdOperator.java analog; replication stays on device)."""
-        fns = self._fold_cache.get(node)
-        if fns is None:
-            from presto_tpu.expr.compile import ExprCompiler
+        from presto_tpu.expr.compile import ExprCompiler
 
-            key_exprs = list(node.key_exprs)
-            nsrc = len(node.source.channels)
-            key_chans = node.channels[nsrc:nsrc + len(key_exprs)]
+        key_exprs = list(node.key_exprs)
+        nsrc = len(node.source.channels)
+        key_chans = node.channels[nsrc:nsrc + len(key_exprs)]
+        gid_type = node.channels[-1].type
 
-            def make(mask, gid):
-                def run(p: Page) -> Page:
-                    comp = ExprCompiler.for_page(p)
-                    blocks = list(p.blocks)
-                    for e, live, ch in zip(key_exprs, mask, key_chans):
-                        d, v = comp.compile(e)(p)
-                        if not live:
-                            v = jnp.zeros_like(v)
-                        blocks.append(Block(d, v, e.type, ch.dictionary))
-                    gid_data = jnp.full((p.capacity,), gid, dtype=jnp.int64)
-                    blocks.append(
-                        Block(gid_data, jnp.ones(p.capacity, dtype=jnp.bool_),
-                              node.channels[-1].type)
-                    )
-                    return Page(tuple(blocks), p.row_mask)
+        def make(mask, gid):
+            def run(p: Page) -> Page:
+                comp = ExprCompiler.for_page(p)
+                blocks = list(p.blocks)
+                for e, live, ch in zip(key_exprs, mask, key_chans):
+                    d, v = comp.compile(e)(p)
+                    if not live:
+                        v = jnp.zeros_like(v)
+                    blocks.append(Block(d, v, e.type, ch.dictionary))
+                gid_data = jnp.full((p.capacity,), gid, dtype=jnp.int64)
+                blocks.append(
+                    Block(gid_data, jnp.ones(p.capacity, dtype=jnp.bool_),
+                          gid_type)
+                )
+                return Page(tuple(blocks), p.row_mask)
 
-                return run
+            return run
 
-            fns = [
-                self._program(
-                    "groupid",
-                    (tuple(key_exprs),
-                     [(c.type, c.dictionary) for c in key_chans],
-                     tuple(bool(b) for b in mask), gid,
-                     node.channels[-1].type),
-                    lambda m=mask, g=gid: jax.jit(make(m, g)) if self.jit
-                    else make(m, g),
-                    node=node)
-                for gid, mask in enumerate(node.set_masks)
-            ]
-            self._fold_cache[node] = fns
+        fns = [
+            self._program(
+                "groupid",
+                (tuple(key_exprs),
+                 [(c.type, c.dictionary) for c in key_chans],
+                 tuple(bool(b) for b in mask), gid, gid_type),
+                lambda m=mask, g=gid: jax.jit(make(m, g)) if self.jit
+                else make(m, g))
+            for gid, mask in enumerate(node.set_masks)
+        ]
         for p in self._pages(node.source):
             for fn in fns:
                 yield fn(p)
@@ -2123,12 +1713,10 @@ class LocalRunner:
 
         bfn_r = self._program(
             "spill_bucket", (tuple(right_keys), tuple(kd or ()), K),
-            lambda: make_bucket_fn(right_keys, kd, K, jit=self.jit),
-            node=node)
+            lambda: make_bucket_fn(right_keys, kd, K, jit=self.jit))
         bfn_l = self._program(
             "spill_bucket", (tuple(left_keys), tuple(kd or ()), K),
-            lambda: make_bucket_fn(left_keys, kd, K, jit=self.jit),
-            node=node)
+            lambda: make_bucket_fn(left_keys, kd, K, jit=self.jit))
 
         bbuckets: List[List[HostPage]] = [[] for _ in range(K)]
         for p in self._pages(node.right):
@@ -2234,12 +1822,9 @@ class LocalRunner:
 
         _named(fold, "topn")
 
-        fold_fn = self._fold_cache.get(node)
-        if fold_fn is None:
-            fold_fn = self._program(
-                "topn", (n, sort_exprs, ascending, nulls_first),
-                lambda: jax.jit(fold) if self.jit else fold, node=node)
-            self._fold_cache[node] = fold_fn
+        fold_fn = self._program(
+            "topn", (n, sort_exprs, ascending, nulls_first),
+            lambda: jax.jit(fold) if self.jit else fold)
 
         acc: Optional[Page] = None
         for p in self._pages(node.source):
@@ -2400,8 +1985,7 @@ class LocalRunner:
             bucket_exprs = group_exprs
         bucket_fn = self._program(
             "spill_bucket", (tuple(bucket_exprs), tuple(kd or ()), K),
-            lambda: make_bucket_fn(bucket_exprs, kd, K, jit=self.jit),
-            node=node)
+            lambda: make_bucket_fn(bucket_exprs, kd, K, jit=self.jit))
 
         buckets: List[List[HostPage]] = [[] for _ in range(K)]
         for p in self._pages(node.source):
@@ -2413,12 +1997,13 @@ class LocalRunner:
         # doubling below recovers skewed buckets
         cap0 = max(1 << 10, min(self._max_groups(node), SPILL_GROUP_THRESHOLD) // K)
 
+        tower_fns = _AggFoldTower.programs(self, num_keys, aggs, kd)
+
         def fold_bucket(pages: List[HostPage], cap: int) -> "_AggFoldTower":
             # tower fold with live-extent compaction (same machinery as
             # the in-memory path; account=False — spill state must not
             # re-trip the pool it is relieving)
-            tower = _AggFoldTower(self, node, num_keys, aggs, kd, cap,
-                                  account=False)
+            tower = _AggFoldTower(self, node, tower_fns, cap, account=False)
             for hp in pages:
                 p = hp.rehydrate()
                 if partial_input:
@@ -2489,8 +2074,7 @@ class LocalRunner:
             self._agg_overrides[partial] = mg
             source = partial
 
-        if agg_tower_enabled() and node.group_exprs \
-                and not self._packed_direct(node, mg):
+        if node.group_exprs and not self._packed_direct(node, mg):
             # sort-path partials: live-extent compaction + tower merge.
             # Tower capacities are unclamped, so the merge itself never
             # truncates; the one remaining hazard is the chain's
@@ -2498,7 +2082,9 @@ class LocalRunner:
             # injected it, i.e. step single) — a full partial page
             # triggers ONE retry with the capacity jumped to the
             # observed live total instead of a doubling ladder.
-            tower = _AggFoldTower(self, node, num_keys, aggs, kd, mg)
+            tower = _AggFoldTower(
+                self, node, _AggFoldTower.programs(self, num_keys, aggs, kd),
+                mg)
             # exact commutative folds (count/min/max, integer sums) may
             # take chain pages in COMPLETION order: the tower's merged
             # values are order-independent in exact arithmetic, so the
@@ -2519,7 +2105,6 @@ class LocalRunner:
                     max(mg * 2,
                         1 << max(1, 2 * tower.live_total - 1).bit_length()))
                 self._agg_overrides[node] = needed
-                self._invalidate_agg_caches(node)
                 raise GroupCapacityExceeded(needed, node)
             out = tower.finish_single()
             if out is None:
@@ -2556,18 +2141,13 @@ class LocalRunner:
             _named(fold_pk, "agg_packed_fold")
             _named(final_pk, "agg_packed_final")
 
-            fold_fn, final_fn = self._fold_cache.get(node, (None, None))
-            if fold_fn is None:
-                sig = (num_keys, tuple(aggs))
-                fold_fn = self._program(
-                    "agg_packed_fold", sig,
-                    lambda: jax.jit(fold_pk) if self.jit else fold_pk,
-                    node=node)
-                final_fn = self._program(
-                    "agg_packed_final", sig,
-                    lambda: jax.jit(final_pk) if self.jit else final_pk,
-                    node=node)
-                self._fold_cache[node] = (fold_fn, final_fn)
+            sig = (num_keys, tuple(aggs))
+            fold_fn = self._program(
+                "agg_packed_fold", sig,
+                lambda: jax.jit(fold_pk) if self.jit else fold_pk)
+            final_fn = self._program(
+                "agg_packed_final", sig,
+                lambda: jax.jit(final_pk) if self.jit else final_pk)
             acc = None
             for p in self._pages(source):
                 if acc is None:
@@ -2598,16 +2178,11 @@ class LocalRunner:
         _named(fold, "agg_fold")
         _named(final, "agg_final")
 
-        fold_fn, final_fn = self._fold_cache.get(node, (None, None))
-        if fold_fn is None:
-            sig = (num_keys, tuple(aggs), mg, tuple(kd or ()))
-            fold_fn = self._program(
-                "agg_fold", sig,
-                lambda: jax.jit(fold) if self.jit else fold, node=node)
-            final_fn = self._program(
-                "agg_final", sig,
-                lambda: jax.jit(final) if self.jit else final, node=node)
-            self._fold_cache[node] = (fold_fn, final_fn)
+        sig = (num_keys, tuple(aggs), mg, tuple(kd or ()))
+        fold_fn = self._program(
+            "agg_fold", sig, lambda: jax.jit(fold) if self.jit else fold)
+        final_fn = self._program(
+            "agg_final", sig, lambda: jax.jit(final) if self.jit else final)
 
         # seed the first fold with a dead-rows accumulator so EVERY
         # call has the steady-state (acc, page) shape — a bare first
@@ -2671,38 +2246,10 @@ class LocalRunner:
         dicts = [c.dictionary for c in node.channels]
         return Page.from_arrays(cols, types, valids=valids, dictionaries=dicts)
 
-    def _invalidate_agg_caches(self, node: AggregationNode) -> None:
-        """Drop only the compiled programs the retried aggregation's
-        capacity is baked into — the rest of the query's chains, builds
-        and folds stay compiled across the retry (a full clear re-paid
-        every compile per capacity step)."""
-        targets = {id(node)}
-        partial = self._partial_nodes.get(node)
-        if partial is not None:
-            targets.add(id(partial))
-
-        def contains(root) -> bool:
-            stack = [root]
-            while stack:
-                n = stack.pop()
-                if id(n) in targets:
-                    return True
-                stack.extend(getattr(n, "sources", []) or [])
-            return False
-
-        for key in list(self._chain_cache):
-            if isinstance(key, PlanNode) and contains(key):
-                del self._chain_cache[key]
-        for key in list(self._fold_cache):
-            base = key[0] if isinstance(key, tuple) else key
-            if isinstance(base, PlanNode) and id(base) in targets:
-                del self._fold_cache[key]
-
     def _check_overflow(self, node: AggregationNode, out: Page, mg: int) -> None:
         if not node.group_exprs or self._exact_capacity(node, mg):
             return
         live = int(host_read(out.num_rows(), "live_count"))
         if live >= mg and mg < MAX_AGG_GROUPS:
             self._agg_overrides[node] = mg * 2
-            self._invalidate_agg_caches(node)
             raise GroupCapacityExceeded(mg * 2, node)

@@ -418,11 +418,3 @@ def default_registry() -> ProgramRegistry:
         if _DEFAULT is None:
             _DEFAULT = ProgramRegistry()
         return _DEFAULT
-
-
-def structural_sharing_enabled() -> bool:
-    """A/B escape hatch: ``PRESTO_TPU_PROGRAM_REGISTRY=0`` reverts to
-    per-PlanNode program identity (the pre-registry behavior) so the
-    cold-compile win is measurable in one process."""
-    return os.environ.get("PRESTO_TPU_PROGRAM_REGISTRY", "1") \
-        not in ("0", "false")

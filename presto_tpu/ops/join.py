@@ -52,14 +52,12 @@ DIRECT_DOMAIN_MAX = 1 << 26
 DIRECT_DOMAIN_PER_ROW = 64
 
 
-# Direct-table / unique-direct selection resolves ONCE per process
-# (first runner construction warms it) instead of re-reading the
-# environment inside every build_join call — the per-build hot path.
-# The explicit override hooks exist for tests, which flip legs
-# in-process (tier-1 runs on XLA:CPU and must still run the leg the
-# chip selects).
+# Direct-table selection resolves ONCE per process (first runner
+# construction warms it) instead of re-reading the environment inside
+# every build_join call — the per-build hot path.  The explicit
+# override hook exists for tests, which flip legs in-process (tier-1
+# runs on XLA:CPU and must still run the leg the chip selects).
 _DIRECT_JOIN_RESOLVED: "Optional[bool]" = None
-_UNIQUE_DIRECT_RESOLVED: "Optional[bool]" = None
 
 
 def set_direct_join_override(value: "Optional[bool]") -> None:
@@ -67,13 +65,6 @@ def set_direct_join_override(value: "Optional[bool]") -> None:
     from the environment/backend on next use)."""
     global _DIRECT_JOIN_RESOLVED
     _DIRECT_JOIN_RESOLVED = None if value is None else bool(value)
-
-
-def set_unique_direct_override(value: "Optional[bool]") -> None:
-    """Force the sort-free unique-build path on/off (None re-resolves
-    from the environment on next use)."""
-    global _UNIQUE_DIRECT_RESOLVED
-    _UNIQUE_DIRECT_RESOLVED = None if value is None else bool(value)
 
 
 def resolve_direct_join() -> bool:
@@ -100,16 +91,6 @@ def resolve_direct_join() -> bool:
 
 def _direct_table_profitable() -> bool:
     return resolve_direct_join()
-
-
-def _unique_direct_enabled() -> bool:
-    global _UNIQUE_DIRECT_RESOLVED
-    if _UNIQUE_DIRECT_RESOLVED is None:
-        import os
-
-        _UNIQUE_DIRECT_RESOLVED = os.environ.get(
-            "PRESTO_TPU_UNIQUE_DIRECT", "1") not in ("0", "false", "")
-    return _UNIQUE_DIRECT_RESOLVED
 
 
 def _direct_budget(page: Page) -> int:
@@ -204,7 +185,7 @@ def build_join(
     has_null = jnp.any(page.row_mask & jnp.logical_not(all_valid))
 
     prod_u = (packed_domain_size(key_domains)
-              if unique and exact and _unique_direct_enabled() else None)
+              if unique and exact else None)
     if prod_u is not None and prod_u <= _direct_budget(page):
         cap = page.capacity
         key_c = jnp.clip(key, 0, prod_u - 1)

@@ -43,6 +43,7 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from presto_tpu.catalog import Catalog
+from presto_tpu.exec.chain import chain_leaf, lower_chain
 from presto_tpu.exec.local import (
     MAX_AGG_GROUPS,
     GroupCapacityExceeded,
@@ -971,8 +972,9 @@ class DistributedRunner:
 
     def _build_dist_stage(self, node: PlanNode, ctx: "_ChainCtx"):
         """fn(page, consts) -> (page, checks): the distributed analog of
-        LocalRunner._build_stage.  ``checks`` maps check names to scalar
-        counts (exchange fills, expand totals) the host verifies."""
+        ``exec/chain.py``'s stages (ROADMAP D9).  ``checks`` maps check
+        names to scalar counts (exchange fills, expand totals) the host
+        verifies."""
         from presto_tpu.ops.filter_project import filter_page, project_page
         from presto_tpu.planner.plan import CrossSingleNode, JoinNode
 
@@ -1015,7 +1017,7 @@ class DistributedRunner:
             return f_pagg
 
         if isinstance(node, CrossSingleNode):
-            from presto_tpu.exec.local import cross_append_single
+            from presto_tpu.exec.chain import cross_append_single
 
             inner = self._build_dist_stage(node.left, ctx)
             key = ctx.add_broadcast(node)
@@ -1027,7 +1029,7 @@ class DistributedRunner:
             return f_cross
 
         if isinstance(node, JoinNode):
-            from presto_tpu.exec.local import _is_streaming_join
+            from presto_tpu.exec.chain import is_streaming_join
 
             if node.kind == "full":
                 # the unmatched-build tail needs cross-page (and
@@ -1044,7 +1046,7 @@ class DistributedRunner:
             ns = node.null_safe_keys
             na = getattr(node, "null_aware", False)
             build_output = list(range(len(node.right.channels)))
-            streaming = _is_streaming_join(node)
+            streaming = is_streaming_join(node)
             cfg = self._join_cfg_for(node, ctx.cap)
             n, axis = self.n, self.axis
 
@@ -1318,13 +1320,14 @@ class DistributedRunner:
             return cached
         n, mesh, axis = self.n, self.mesh, self.axis
         runner = self._stage_runner
-        leaf_r = runner._chain_leaf(jnode.right)
+        chain_r = lower_chain(jnode.right, max_groups=runner._max_groups,
+                              compact_k=0)
+        leaf_r, stage_r = chain_r.leaf, chain_r.fn()
         conn_r = self.catalog.connector(leaf_r.handle.connector_name)
         cap_r = self._split_capacity(conn_r, leaf_r.handle.table)
-        joins_r: List[PlanNode] = []
-        stage_r = runner._build_stage(jnode.right, joins_r)
         consts_r = {
-            f"build_{i}": runner._materialize_build(j) for i, j in enumerate(joins_r)
+            f"build_{i}": runner._materialize_build(j)
+            for i, j in enumerate(chain_r.joins)
         }
         right_keys = list(jnode.right_keys)
         kd = jnode.key_domains
@@ -1383,8 +1386,7 @@ class DistributedRunner:
         Device p ends up holding exactly the build rows with
         hash(key) % n == p — the PartitionedLookupSourceFactory analog
         with the shuffle collapsed into ``all_to_all``."""
-        runner = self._stage_runner
-        leaf_r = runner._chain_leaf(jnode.right)
+        leaf_r = chain_leaf(jnode.right)
         conn_r = self.catalog.connector(leaf_r.handle.connector_name)
         cap_r = self._split_capacity(conn_r, leaf_r.handle.table)
         cfg = self._join_cfg.setdefault(jnode, {})
@@ -1415,10 +1417,12 @@ class DistributedRunner:
     ) -> JoinBuild:
         n, mesh, axis = self.n, self.mesh, self.axis
         runner = self._stage_runner
-        joins_r: List[PlanNode] = []
-        stage_r = runner._build_stage(jnode.right, joins_r)
+        chain_r = lower_chain(jnode.right, max_groups=runner._max_groups,
+                              compact_k=0)
+        stage_r = chain_r.fn()
         consts_r = {
-            f"build_{i}": runner._materialize_build(j) for i, j in enumerate(joins_r)
+            f"build_{i}": runner._materialize_build(j)
+            for i, j in enumerate(chain_r.joins)
         }
         right_keys = list(jnode.right_keys)
         kd = jnode.key_domains

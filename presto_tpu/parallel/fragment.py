@@ -158,7 +158,9 @@ def estimate_rows(node: PlanNode, calc=None) -> Optional[int]:
 def build_side_chainable(node: PlanNode) -> bool:
     """True when the build side can wave-scan on the mesh: a streaming
     chain (filter/project/partial-agg/streaming-join probes) rooted at
-    a table scan.  Mirrors LocalRunner._chain_leaf's descent."""
+    a table scan.  ``exec/chain.chain_leaf``'s descent, but for two
+    joins: a FULL join with a unique build and an index join stop
+    ``chain_leaf`` and not this walk (ROADMAP D9)."""
     if isinstance(node, (FilterNode, ProjectNode)):
         return build_side_chainable(node.source)
     if isinstance(node, AggregationNode) and node.step == "partial":

@@ -33,6 +33,7 @@ from typing import Dict, List, Optional, Sequence
 
 from presto_tpu.analysis.protocols import RECORDER
 from presto_tpu.catalog import Catalog
+from presto_tpu.exec.chain import chain_leaf
 from presto_tpu.exec.local import LocalRunner, MaterializedResult, concat_pages_device
 from presto_tpu.planner.plan import (
     AggregationNode,
@@ -550,7 +551,7 @@ class MultiHostRunner:
                 "evaluate_classifier_predictions is local-only")
         from presto_tpu.obs import span
 
-        leaf = self.local._chain_leaf(agg.source)
+        leaf = chain_leaf(agg.source)
         with span("mh_stage:aggregation", cat="exchange"):
             if isinstance(leaf, TableScanNode):
                 return self._run_agg_with_retry(agg, leaf)
@@ -568,7 +569,7 @@ class MultiHostRunner:
         the coordinator's own bound node still does the global pick."""
         from presto_tpu.page import concat_pages_host
 
-        leaf = self.local._chain_leaf(chain_root)
+        leaf = chain_leaf(chain_root)
         frag: PlanNode = chain_root
         if isinstance(bound, TopNNode):
             frag = TopNNode(source=chain_root,
@@ -608,7 +609,7 @@ class MultiHostRunner:
         survive or the shuffle dies mid-flight."""
         from presto_tpu.obs import span
 
-        leaf = self.local._chain_leaf(wnode.source)
+        leaf = chain_leaf(wnode.source)
         with span("mh_stage:window", cat="exchange"):
             alive = self._live_workers()
             if len(alive) >= 2 and isinstance(leaf, TableScanNode):
@@ -682,7 +683,7 @@ class MultiHostRunner:
         from presto_tpu.ops.merge import merge_sorted_pages
         from presto_tpu.page import Page
 
-        leaf = self.local._chain_leaf(snode.source)
+        leaf = chain_leaf(snode.source)
         with span("mh_stage:sort", cat="exchange"):
             if isinstance(leaf, TableScanNode):
                 pages = self._run_fragments(snode, leaf)
@@ -942,9 +943,9 @@ class MultiHostRunner:
                     mode == "partitioned"
                     and all(isinstance(e, ColumnRef) for e in node.left_keys)
                     and all(isinstance(e, ColumnRef) for e in node.right_keys)
-                    and isinstance(self.local._chain_leaf(node.left),
+                    and isinstance(chain_leaf(node.left),
                                    TableScanNode)
-                    and isinstance(self.local._chain_leaf(node.right),
+                    and isinstance(chain_leaf(node.right),
                                    TableScanNode)
                 )
                 if ok:
@@ -1078,8 +1079,8 @@ class MultiHostRunner:
         kd = join.key_domains
         lidx = [e.index for e in join.left_keys]
         ridx = [e.index for e in join.right_keys]
-        probe_scan = self.local._chain_leaf(join.left)
-        build_scan = self.local._chain_leaf(join.right)
+        probe_scan = chain_leaf(join.left)
+        build_scan = chain_leaf(join.right)
         mg = self.local._max_groups(agg)
 
         while True:
@@ -1357,7 +1358,7 @@ class MultiHostRunner:
                     merge_mg = grow(merge_mg)
 
     def _leaf_scan(self, node: PlanNode) -> TableScanNode:
-        n = self.local._chain_leaf(node)
+        n = chain_leaf(node)
         if not isinstance(n, TableScanNode):
             raise MultiHostUnsupported("chain leaf is not a table scan")
         return n

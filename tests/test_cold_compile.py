@@ -130,25 +130,15 @@ def test_ir_signature_dictionary_identity():
     assert ir_signature(d1) != ir_signature(d2)  # identity, not content
 
 
-def test_registry_disabled_mode_still_executes(monkeypatch):
-    monkeypatch.setenv("PRESTO_TPU_PROGRAM_REGISTRY", "0")
-    runner, registry = _fresh_runner()
-    res = runner.execute("SELECT count(*) FROM region")
-    assert res.rows == [(5,)]
-    # programs landed in the executor's private per-node registry
-    assert registry.program_count() == 0
-    own = runner.executor._own_registry
-    assert own is not None and own.program_count() > 0
-
-
 def test_default_registry_is_shared():
     assert default_registry() is default_registry()
 
 
 def test_stage_signature_sensitivity():
-    """Every parameter _build_stage bakes into a chain closure must
-    flip the signature (the registry's correctness guarantee); equal
-    structure must sign equal across separately planned queries."""
+    """What a query's text changes in a chain's program flips the
+    chain's signature (the registry's correctness guarantee; field by
+    field in tests/test_chain_lowering.py); equal structure must sign
+    equal across separately planned queries."""
     runner, _ = _fresh_runner()
     ex = runner.executor
 
@@ -156,9 +146,9 @@ def test_stage_signature_sensitivity():
         plan = runner.binder.plan(sql)
         # walk to the streaming chain root (under the Output node)
         node = plan
-        while not ex._is_chain_member(node) and node.sources:
+        while not ex._lower(node).stages and node.sources:
             node = node.sources[0]
-        return ex._stage_signature(node)
+        return ir_signature(ex._lower(node).signature())
 
     base = "SELECT l_quantity FROM lineitem WHERE l_discount < 0.05"
     assert sig(base) == sig(base.replace("0.05", "0.05"))
@@ -174,7 +164,6 @@ def test_stage_signature_holds_the_compaction():
     q14's chain signs differently for every k, and for none."""
     import dataclasses
 
-    from presto_tpu.exec.local import _chain_name
     from presto_tpu.planner.plan import AggregationNode
 
     runner, _ = _fresh_runner()
@@ -184,11 +173,13 @@ def test_stage_signature_holds_the_compaction():
         node = node.sources[0]
     root = dataclasses.replace(node, step="partial")
     ex._agg_overrides[root] = ex._max_groups(node)
-    sigs = {k: ex._stage_signature(root, compact_k=k) for k in (0, 4, 5)}
+    chains = {k: ex._lower(root, compact_k=k) for k in (0, 4, 5)}
+    sigs = {k: ir_signature(c.signature()) for k, c in chains.items()}
     assert len(set(sigs.values())) == 3
-    assert ex._stage_signature(root) == sigs[5]  # the plan's own k
-    assert _chain_name(sigs[0]) == "chain_leaf_filter_probe_agg_k0a2"
-    assert _chain_name(sigs[4]) == _chain_name(sigs[5]) == \
+    # the plan's own k
+    assert ir_signature(ex._lower(root).signature()) == sigs[5]
+    assert chains[0].name() == "chain_leaf_filter_probe_agg_k0a2"
+    assert chains[4].name() == chains[5].name() == \
         "chain_leaf_filter_compact_probe_agg_k0a2"
 
 

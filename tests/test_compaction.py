@@ -1,9 +1,9 @@
 """Selection compaction in front of a probe (ops/filter_project
-``compact_page``, exec/local ``_compact_point`` / ``_build_chain`` /
-``_chain_pages``): the primitive keeps order, validity and count; a
-chain that compacts answers as the chain that does not, whether its
-pages fit or the chain has to run again whole; chains the gate leaves
-alone build the program they always built."""
+``compact_page``, exec/chain ``lower_chain``'s ``compact`` stage,
+exec/local ``_chain_pages``): the primitive keeps order, validity and
+count; a chain that compacts answers as the chain that does not,
+whether its pages fit or the chain has to run again whole; chains the
+gate leaves alone build the program they always built."""
 
 import jax
 import jax.numpy as jnp
@@ -12,10 +12,11 @@ import pytest
 
 from presto_tpu.catalog import Catalog
 from presto_tpu.connectors.tpch import Tpch
+from presto_tpu.exec import chain as chain_mod
 from presto_tpu.exec import local
 from presto_tpu.ops.filter_project import compact_page
 from presto_tpu.page import Block, Dictionary, Page
-from presto_tpu.planner.plan import AggregationNode, JoinNode
+from presto_tpu.planner.plan import AggregationNode, JoinNode, LimitNode
 from presto_tpu.runner import QueryRunner
 from presto_tpu.types import BIGINT, DATE, VARCHAR
 
@@ -97,6 +98,13 @@ def _chain_root(runner, sql):
     return root
 
 
+def _force_k(monkeypatch, ex, k):
+    """Every chain ``ex`` lowers from here on compacts at ``k``."""
+    lower = ex._lower
+    monkeypatch.setattr(ex, "_lower",
+                        lambda node, compact_k=None: lower(node, k))
+
+
 def _counts(runner, sql):
     before = local.compact_counts()
     rows = runner.execute(sql).rows
@@ -116,9 +124,7 @@ def test_q14_falls_back_and_matches_the_oracle(env, monkeypatch):
     page: the chain runs again under the program that does not
     compact, and the answer is the same."""
     runner, frames = env
-    point = runner.executor._compact_point
-    monkeypatch.setattr(runner.executor, "_compact_point",
-                        lambda node, compact_k=None: point(node, 12))
+    _force_k(monkeypatch, runner.executor, 12)
     rows, (compacted, fallback) = _counts(runner, QUERIES[14] + " ")
     assert_rows_match(rows, PANDAS_QUERIES[14](frames), ordered=False)
     assert compacted == 0 and fallback == 4
@@ -134,18 +140,27 @@ def test_other_queries_compact_nothing(env, q):
 
 def _programs(runner, root, k):
     """(today's program, the compacting one at ``k``, their consts,
-    the chain's pages) through the stage builders."""
+    the chain's pages) through the lowered chain."""
     ex = runner.executor
-    plain_joins, joins = [], []
-    plain = jax.jit(ex._build_stage(root, plain_joins))
-    chain = jax.jit(ex._build_chain(root, joins,
-                                    ex._compact_point(root, k)))
-    assert [type(j) for j in plain_joins] == [JoinNode] == [
-        type(j) for j in joins]
-    consts = {"build_0": ex._materialize_build(joins[0])}
-    pages = list(ex._source_pages(ex._chain_leaf(root)))
+    lowered = ex._lower(root, k)
+    assert lowered.compacts and not lowered.uncompacted().compacts
+    plain = jax.jit(lowered.uncompacted().fn())
+    chain = jax.jit(lowered.fn())
+    assert [type(j) for j in lowered.uncompacted().joins] == [JoinNode] == [
+        type(j) for j in lowered.joins]
+    consts = {"build_0": ex._materialize_build(lowered.joins[0])}
+    pages = list(ex._source_pages(lowered.leaf))
     assert len(pages) == 4
     return plain, chain, consts, pages
+
+
+def _compaction(lowered):
+    """(kind of the stage the chain compacts in front of, k), or
+    None."""
+    for i, stage in enumerate(lowered.stages):
+        if stage.kind == "compact":
+            return lowered.stages[i + 1].kind, stage.params.k
+    return None
 
 
 def _same_page(got, want):
@@ -162,7 +177,8 @@ def test_compacted_partial_aggregate_equals_the_uncompacted_programs(env):
     fit, the compacting program's partial aggregate is today's."""
     runner, _ = env
     root = _chain_root(runner, QUERIES[14])
-    assert runner.executor._compact_point(root)[1] == 5  # 2.4% fits 1/32
+    # 2.4% fits 1/32
+    assert _compaction(runner.executor._lower(root)) == ("probe", 5)
     plain, chain, consts, pages = _programs(runner, root, 5)
     for page in pages:
         got, over = chain(page, consts)
@@ -181,9 +197,7 @@ def test_a_page_that_does_not_fit_says_so_and_the_chain_runs_whole(
     plain, chain, consts, pages = _programs(runner, root, 12)
     for page in pages:
         assert bool(chain(page, consts)[1])
-    point = ex._compact_point
-    monkeypatch.setattr(ex, "_compact_point",
-                        lambda node, compact_k=None: point(node, 12))
+    _force_k(monkeypatch, ex, 12)
     before = local.compact_counts()
     outs = list(ex._chain_pages(root))
     assert local.compact_counts() == (before[0], before[1] + 4)
@@ -194,18 +208,20 @@ def test_a_page_that_does_not_fit_says_so_and_the_chain_runs_whole(
 
 def test_a_prefix_of_the_chain_compacts_as_the_chain(env):
     """``_time_chain`` times prefixes that end in the probe: the
-    compacting builder gives the small page there, same live rows."""
+    chain's prefix gives the small page there, same live rows."""
     runner, _ = env
     ex = runner.executor
     root = _chain_root(runner, QUERIES[14])
     probe = root.source
     assert isinstance(probe, JoinNode)
-    assert ex._compact_point(probe) is None  # the gate wants the partial
-    joins = []
-    plain = ex._build_stage(probe, [])
-    chain = ex._build_chain(probe, joins, ex._compact_point(root))
-    consts = {"build_0": ex._materialize_build(joins[0])}
-    page = next(iter(ex._source_pages(ex._chain_leaf(probe))))
+    assert not ex._lower(probe).compacts  # the gate wants the partial
+    lowered = ex._lower(root)
+    upto = [s.node for s in lowered.stages].index(probe) + 1
+    assert lowered.name(upto) == "chain_leaf_filter_compact_probe"
+    plain = ex._lower(probe).fn()
+    chain = lowered.fn(upto)
+    consts = {"build_0": ex._materialize_build(lowered.joins[0])}
+    page = next(iter(ex._source_pages(lowered.leaf)))
     want = plain(page, consts)
     got, over = chain(page, consts)
     assert not bool(over)
@@ -216,35 +232,34 @@ def test_a_prefix_of_the_chain_compacts_as_the_chain(env):
 # -- the gate ----------------------------------------------------------------
 
 def test_compact_k():
-    assert local._compact_k(0.0119) == 5  # q14: 2.38% fits 1/32
-    assert local._compact_k(1 / 64) == 5  # exactly 2 x share = 1/32
-    assert local._compact_k(0.0625) == 3
-    assert local._compact_k(0.068) == 0  # q6's: under 3 is not worth it
-    assert local._compact_k(0.537) == 0  # q3's lineitem filter
-    assert local._compact_k(1.0) == 0
+    assert chain_mod._compact_k(0.0119) == 5  # q14: 2.38% fits 1/32
+    assert chain_mod._compact_k(1 / 64) == 5  # exactly 2 x share = 1/32
+    assert chain_mod._compact_k(0.0625) == 3
+    assert chain_mod._compact_k(0.068) == 0  # q6's: under 3 is not worth it
+    assert chain_mod._compact_k(0.537) == 0  # q3's lineitem filter
+    assert chain_mod._compact_k(1.0) == 0
 
 
 @pytest.mark.parametrize("q", [1, 3, 6])
 def test_gate_leaves_other_chains_as_they_were(env, q):
     """q6's, q1's and q3's chains: no compaction point, so the chain
-    builder gives ``_build_stage``'s program, to the letter of its
-    lowered text (``_build_stage`` is the parent's recursion: PERF.md,
-    PR 26, compared all of these queries' programs with the parent's),
-    under the name it had."""
+    gives the program of the chain that may never compact, to the
+    letter of its lowered text (PERF.md, PR 26 and PR 30, compared all
+    of these queries' programs with the parent's), under the name it
+    had."""
     runner, _ = env
     ex = runner.executor
     root = _chain_root(runner, QUERIES[q])
-    assert ex._compact_point(root) is None
-    name = local._chain_name(ex._stage_signature(root))
+    lowered = ex._lower(root)
+    assert not lowered.compacts
+    name = lowered.name()
     assert name == {1: "chain_leaf_filter_agg_k2a8",
                     3: "chain_leaf_filter_probe_agg_k3a1",
                     6: "chain_leaf_filter_agg_k0a1"}[q]
-    joins = []
-    built = [ex._build_chain(root, joins, ex._compact_point(root)),
-             ex._build_stage(root, [])]
+    built = [lowered.fn(), ex._lower(root, 0).fn()]
     consts = {f"build_{i}": ex._materialize_build(j)
-              for i, j in enumerate(joins)}
-    page = next(iter(ex._source_pages(ex._chain_leaf(root))))
+              for i, j in enumerate(lowered.joins)}
+    page = next(iter(ex._source_pages(lowered.leaf)))
     texts = [jax.jit(local._named(f, name)).lower(page, consts).as_text(
         debug_info=True) for f in built]
     assert texts[0] == texts[1]
@@ -252,37 +267,52 @@ def test_gate_leaves_other_chains_as_they_were(env, q):
     assert "filter:compact" not in texts[0]
 
 
-def test_gate_wants_a_scan_a_small_partial_and_a_filter(env, monkeypatch):
+def _over(node, leaf_of):
+    """The chain rooted at ``node`` over another leaf."""
+    import dataclasses
+
+    if chain_mod.chain_leaf(node) is node:
+        return leaf_of(node)
+    field = "left" if isinstance(node, JoinNode) else "source"
+    return dataclasses.replace(
+        node, **{field: _over(getattr(node, field), leaf_of)})
+
+
+def test_gate_wants_a_scan_a_small_partial_and_a_filter(env):
     runner, _ = env
     ex = runner.executor
     root = _chain_root(runner, QUERIES[14])
-    assert ex._compact_point(root) == (root.source, 5)
-    assert ex._compact_point(root, compact_k=0) is None
+    lowered = ex._lower(root)
+    assert _compaction(lowered) == ("probe", 5)
+    at = [s.kind for s in lowered.stages].index("compact")
+    assert lowered.stages[at + 1].node is root.source
+    assert not ex._lower(root, compact_k=0).compacts
     # no filter in front of the probe
     bare = _chain_root(runner, "select sum(l_extendedprice) from lineitem, "
                                "part where l_partkey = p_partkey")
-    assert ex._compact_point(bare) is None
-    assert ex._compact_point(bare, compact_k=5) is None
+    assert not ex._lower(bare).compacts
+    assert not ex._lower(bare, compact_k=5).compacts
     # its pages are held until the last: they have to be small
-    ex._agg_overrides[root] = local.COMPACT_MAX_GROUPS + 1
-    assert ex._compact_point(root) is None
+    ex._agg_overrides[root] = chain_mod.COMPACT_MAX_GROUPS + 1
+    assert not ex._lower(root).compacts
     ex._agg_overrides[root] = 1
+    assert ex._lower(root).compacts
     # after a miss the source is read again: it has to be a scan
-    monkeypatch.setattr(ex, "_chain_leaf", lambda node: node)
-    assert ex._compact_point(root) is None
+    limited = _over(root, lambda scan: LimitNode(source=scan, count=1 << 40))
+    ex._agg_overrides[limited] = 1
+    assert isinstance(ex._lower(limited).leaf, LimitNode)
+    assert not ex._lower(limited).compacts
 
 
 def test_compacting_chain_is_named_and_scoped(env):
     runner, _ = env
     ex = runner.executor
     root = _chain_root(runner, QUERIES[14])
-    sig = ex._stage_signature(root)
-    assert local._chain_name(sig) == \
-        "chain_leaf_filter_compact_probe_agg_k0a2"
-    joins = []
-    stage = ex._build_chain(root, joins, ex._compact_point(root))
-    consts = {"build_0": ex._materialize_build(joins[0])}
-    page = next(iter(ex._source_pages(ex._chain_leaf(root))))
+    lowered = ex._lower(root)
+    assert lowered.name() == "chain_leaf_filter_compact_probe_agg_k0a2"
+    stage = lowered.fn()
+    consts = {"build_0": ex._materialize_build(lowered.joins[0])}
+    page = next(iter(ex._source_pages(lowered.leaf)))
     text = jax.jit(stage).lower(page, consts).as_text(debug_info=True)
     # the compaction is the filter's: no scope of its own at op: level
     assert "op:Filter/filter:compact" in text

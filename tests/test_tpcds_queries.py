@@ -72,15 +72,14 @@ def test_tpcds_query(env, qid):
     runner, oracle = env
     # bound live compiled executables: the 99-query corpus in ONE
     # process accumulates thousands of XLA:CPU programs across the
-    # runner's chain/fold caches plus jax's own jit caches, and past
+    # program registry plus jax's own jit caches, and past
     # ~30 queries the next compile segfaults (r5, deterministic).
     # Dropping every cache each ~10 queries trades recompiles for a
     # bounded executable arena.
     _since_clear[0] += 1
     if _since_clear[0] >= 10:
         _since_clear[0] = 0
-        runner.executor._chain_cache.clear()
-        runner.executor._fold_cache.clear()
+        runner.executor.programs.clear()
         runner.executor._builds.clear()
         runner._plans.clear()
         import jax

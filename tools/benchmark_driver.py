@@ -99,7 +99,7 @@ def cold_compile_report(args):
 
     from presto_tpu.exec.programs import (
         ProgramRegistry, enable_persistent_cache,
-        persistent_cache_stats, structural_sharing_enabled,
+        persistent_cache_stats,
     )
 
     suite = dict(load_suite(args.suite))
@@ -114,12 +114,6 @@ def cold_compile_report(args):
     registry = ProgramRegistry()
     runner = build_runner(args, programs=registry)
 
-    def reg_stats():
-        # with structural sharing disabled (the A/B baseline) programs
-        # land in the executor's private per-node registry instead
-        own = getattr(runner.executor, "_own_registry", None)
-        return (own or registry).stats()
-
     queries = []
     prev_programs = prev_compile = 0.0
     for name in names:
@@ -129,7 +123,7 @@ def cold_compile_report(args):
         t0 = time.perf_counter()
         runner.execute(suite[name])
         warm = time.perf_counter() - t0
-        s = reg_stats()
+        s = registry.stats()
         queries.append({
             "query": name,
             "rows": len(res),
@@ -150,11 +144,10 @@ def cold_compile_report(args):
         "sequence": names,
         "sf": args.sf,
         "backend": jax.default_backend(),
-        "structural_sharing": structural_sharing_enabled(),
         "persistent_cache_dir": cache_dir,
         "total_warmup_s": round(sum(q["warmup_s"] for q in queries), 3),
         "distinct_programs": int(prev_programs),
-        "registry": reg_stats(),
+        "registry": registry.stats(),
         "persistent": persistent_cache_stats(),
         "queries": queries,
     }
