@@ -37,7 +37,7 @@ from presto_tpu.ops.aggregate import grouped_aggregate
 from presto_tpu.ops.filter_project import (
     compact_page, filter_page, project_page,
 )
-from presto_tpu.ops.join import probe_join
+from presto_tpu.ops.join import probe_fetch, probe_join, probe_lookup
 from presto_tpu.page import Block, Page
 from presto_tpu.planner.plan import (
     AggregationNode,
@@ -49,6 +49,7 @@ from presto_tpu.planner.plan import (
     ProjectNode,
     TableScanNode,
 )
+from presto_tpu.types import INTEGER
 
 
 def is_streaming_join(node: JoinNode) -> bool:
@@ -166,6 +167,53 @@ class Probe(NamedTuple):
             )
 
 
+class Lookup(NamedTuple):
+    """Not a plan node's: where a chain compacts inside an inner probe
+    of a unique build (``_compact_at``), the probe runs as its two
+    halves with the compaction between them.  This is the first,
+    ``ops/join.probe_lookup`` over the whole page: the rows that found
+    their key stay live, and the candidate position in the build rides
+    with them as one more (int32) column, the page's last, for
+    :class:`Fetch` to take off again."""
+
+    left_keys: Tuple[Expr, ...]
+    key_domains: tuple
+    null_safe: bool
+
+    @classmethod
+    def of(cls, node, max_groups):
+        return cls(tuple(node.left_keys), tuple(node.key_domains or ()),
+                   node.null_safe_keys)
+
+    def apply(self, page, consts, build_key):
+        with jax.named_scope("op:Join"):
+            pos, match, _ = probe_lookup(
+                consts[build_key], page, list(self.left_keys),
+                key_domains=list(self.key_domains), null_safe=self.null_safe)
+            at = Block(pos.astype(jnp.int32), match, INTEGER)
+            return Page(tuple(page.blocks) + (at,), match)
+
+
+class Fetch(NamedTuple):
+    """The second half of the probe :class:`Lookup` began, over the
+    small page: every live row matched, so the probe is an inner
+    ``ops/join.probe_fetch`` at the position column."""
+
+    build_arity: int
+
+    @classmethod
+    def of(cls, node, max_groups):
+        return cls(len(node.right.channels))
+
+    def apply(self, page, consts, build_key):
+        with jax.named_scope("op:Join"):
+            *blocks, at = page.blocks
+            return probe_fetch(
+                consts[build_key], Page(tuple(blocks), page.row_mask),
+                at.data, page.row_mask, "inner",
+                list(range(self.build_arity)))
+
+
 class Cross1(NamedTuple):
     @classmethod
     def of(cls, node, max_groups):
@@ -184,8 +232,9 @@ class Compact(NamedTuple):
     compacts is ``fn(page, consts) -> (page, over)``, ``over`` a device
     scalar saying the page held more live rows than fitted.  The answer
     is then of the rows that fitted only, and the caller must not use
-    it (``LocalRunner._chain_pages`` runs the chain again,
-    uncompacted)."""
+    it (``LocalRunner._chain_pages`` raises after the last split, and
+    the aggregation that was consuming starts again over the chain that
+    does not compact)."""
 
     k: int
 
@@ -197,16 +246,19 @@ class Compact(NamedTuple):
 #: stage kind -> its params class: the fields are the signature, the
 #: ``apply`` the program
 KINDS = {"filter": Filter, "project": Project, "agg_partial": AggPartial,
-         "probe": Probe, "cross1": Cross1, "compact": Compact}
+         "probe": Probe, "cross1": Cross1, "compact": Compact,
+         "lookup": Lookup, "fetch": Fetch}
 
-#: the kinds whose stage reads a build side, ``consts["build_<i>"]``
-_BUILDS = ("probe", "cross1")
+#: the kinds whose stage is the last to read a build side,
+#: ``consts["build_<i>"]``: a ``lookup`` reads the build of the
+#: ``fetch`` behind it
+_BUILDS = ("probe", "cross1", "fetch")
 
 # what XLA would call a program that nothing names (``local._named``
 # names every chain the registry holds): after its outermost stage
 _UNNAMED = {"filter": "filter_stage", "project": "project_stage",
             "agg_partial": "agg_stage", "probe": "probe_stage",
-            "cross1": "cross_stage"}
+            "cross1": "cross_stage", "lookup": "probe_stage"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -257,11 +309,6 @@ class Chain:
     def compacts(self) -> bool:
         return any(s.kind == "compact" for s in self.stages)
 
-    def uncompacted(self) -> "Chain":
-        """The same chain with no compaction: what runs after a miss."""
-        return Chain(self.leaf, tuple(
-            s for s in self.stages if s.kind != "compact"))
-
     def signature(self, upto: Optional[int] = None) -> tuple:
         """What the program of the first ``upto`` stages (all of them:
         None) depends on, leaf first."""
@@ -272,17 +319,29 @@ class Chain:
         """The program's name: the stage kinds leaf first, an
         aggregation tagged with its counts of keys and aggregates
         (``chain_leaf_filter_agg_k2a8`` is TPC-H q1's), a compaction as
-        ``compact`` where it happens
-        (``chain_leaf_filter_compact_probe_agg_k0a2`` is q14's).  Of
-        the structure only: the name is part of the persistent compile
-        cache's key (``local._named``)."""
-        tags = ["leaf"]
+        ``compact`` where it happens in front of a probe
+        (``chain_leaf_filter_compact_probe_agg_k0a2`` is q14's).  A
+        probe that compacts between its halves is still one ``probe``,
+        and which it is comes last
+        (``chain_leaf_filter_probe_agg_k3a1_compact_in_probe0`` is
+        q3's: the benchmark's tests know q3's program by the head of
+        its old name, and no PR but a ``benchmark`` one may change
+        them).  Of the structure only: the name is part of the
+        persistent compile cache's key (``local._named``)."""
+        tags, probes, inside = ["leaf"], 0, None
         for s in self.stages[:upto]:
             if s.kind == "agg_partial":
                 tags.append(f"agg_k{len(s.params.group_exprs)}"
                             f"a{len(s.params.aggs)}")
-            else:
+            elif s.kind == "lookup":
+                inside = probes
+            elif s.kind == "fetch":
+                tags.append("probe")
+            elif s.kind != "compact" or inside is None:
                 tags.append(s.kind)
+            probes += s.kind in ("probe", "fetch")
+        if inside is not None:
+            tags.append(f"compact_in_probe{inside}")
         return "chain_" + "_".join(tags)
 
     def fn(self, upto: Optional[int] = None) -> Callable:
@@ -325,8 +384,9 @@ def lower_chain(root: PlanNode, *, max_groups: Callable[[AggregationNode], int],
     aggregation's capacity: what ``LocalRunner._streaming`` (a join
     whose build spilled is demoted) and ``LocalRunner._max_groups``
     (a capacity retry raises it) answer.  ``compact_k`` sets the
-    compaction's k instead of the estimate's (0: never compact), for
-    tests and for callers that take pages only."""
+    compaction's k, at the place the estimates chose, instead of
+    theirs (0: never compact), for tests, for a chain whose compaction
+    missed and for callers that take pages only."""
     stages: List[Stage] = []
     node = root
     while True:
@@ -339,80 +399,109 @@ def lower_chain(root: PlanNode, *, max_groups: Callable[[AggregationNode], int],
     stages.reverse()
     at = _compact_at(node, stages, compact_k)
     if at is not None:
-        stages.insert(at[0], Stage("compact", Compact(at[1])))
+        i, k, inside = at
+        compact = Stage("compact", Compact(k))
+        if inside:
+            # the probe as its two halves around the compaction; the
+            # second keeps the node: the build side, and the time
+            # ``_time_chain`` books to the join
+            probe = stages[i].node
+            stages[i:i + 1] = [
+                Stage("lookup", Lookup.of(probe, max_groups)), compact,
+                Stage("fetch", Fetch.of(probe, max_groups), probe)]
+        else:
+            stages.insert(i, compact)
     return Chain(node, tuple(stages))
 
 
-# A compacting chain holds its partial pages, of max_groups rows each,
-# until its last split is dispatched (``LocalRunner._chain_pages``):
-# with at most this many groups all of them together stay under one
-# page of the scan (the capacity ladder's step, ``bucket_capacity``).
-COMPACT_MAX_GROUPS = 1 << 16
+#: the smallest k worth a compaction, by where it sits: in front of a
+#: probe it saves the probe's five gathers a dead slot (PERF.md,
+#: PR 26); inside one, the fetch's gathers and, since the chain ends in
+#: a partial aggregation, a sort and the group side of an aggregation
+#: (PERF.md, PR 31: a q3 page takes 283 ms whole, 269 at k = 1, 156 at
+#: k = 2, 63 at k = 3; a miss costs the try and the whole pass again)
+COMPACT_MIN_K = {False: 3, True: 2}
 
 
-def _compact_k(share: float) -> int:
+def _compact_k(share: float, floor: int = COMPACT_MIN_K[False]) -> int:
     """The k of ``_compact_at`` for an estimated live share: the
-    largest with ``2 * share <= 2**-k``, or 0 (no compaction) below 3.
-    The factor of two is room for the estimate; a chain one of whose
-    pages still holds more runs again, uncompacted."""
+    largest with ``2 * share <= 2**-k``, or 0 (no compaction) below
+    ``floor``.  The factor of two is room for the estimate; a chain
+    one of whose pages still holds more runs again, uncompacted."""
     k = 0
     while k < 30 and 2.0 * share * (2 << k) <= 1.0:
         k += 1
-    return k if k >= 3 else 0
+    return k if k >= floor else 0
 
 
 def _compact_at(leaf: PlanNode, stages: List[Stage],
-                compact_k: Optional[int]) -> Optional[Tuple[int, int]]:
-    """Where the chain compacts its page and how far: ``(i, k)``, the
-    live rows of the input of the probe ``stages[i]`` moved to a page
-    of ``capacity >> k`` before it is probed, or None.
+                compact_k: Optional[int]) -> Optional[Tuple[int, int, bool]]:
+    """Where the chain compacts its page and how far: ``(i, k,
+    inside)``, the live rows moved to a page of ``capacity >> k`` in
+    front of the probe ``stages[i]`` or, ``inside``, between its lookup
+    and its fetch; or None.
 
-    The probe is the one nearest the leaf with a FilterNode in
-    front of it since the leaf or the probe before; k the largest
-    for which twice the filters' estimated share of their source's
-    rows fits ``2**-k``, and at least 3 (``_compact_k``).  Only a
-    chain over a table scan that ends in a partial aggregation of
-    at most ``COMPACT_MAX_GROUPS`` groups compacts: its pages are
-    held until the last has said whether it fitted, and after a
-    miss the scan is read again (``LocalRunner._chain_pages``).  The
-    answer is a function of the plan and the catalog's column metadata
-    alone, never of what a run observed: a served statement must find
-    its program compiled."""
+    One compaction a chain, at the first place that qualifies, leaf
+    first; at a probe, in front of it before inside it:
+
+    - in front of a probe with a FilterNode before it since the leaf
+      or the probe before: k from the filters' estimated share of
+      their source's rows, at least 3;
+    - inside an ``inner`` probe of a unique build (the row-aligned
+      join that emits build columns; ``left`` keeps every row, ``semi``
+      / ``anti`` / ``mark`` fetch nothing): k from the join's estimated
+      rows over the leaf scan's, at least 2.  The rows that found no
+      key are dropped after the lookup, before anything is fetched for
+      them.
+
+    k is the largest for which twice the share fits ``2**-k``
+    (``_compact_k``).  Only a chain over a table scan that ends in a
+    partial aggregation compacts: after a miss the consumer has to
+    start again and the source has to be read again
+    (``LocalRunner._chain_pages``).  The answer is a function of the
+    plan and the catalog's column metadata alone, never of what a run
+    observed: a served statement must find its program compiled."""
     if compact_k == 0 or not (
             stages and stages[-1].kind == "agg_partial"
-            and stages[-1].params.max_groups <= COMPACT_MAX_GROUPS
             and isinstance(leaf, TableScanNode)):
         return None
+    from presto_tpu.planner.stats import StatsCalculator
+
+    calc = StatsCalculator()  # memoized: one walk of the plan for all
     for i, s in enumerate(stages):
         if s.kind != "probe":
             continue
-        share = _filtered_share(s.node.left)
-        if share is not None:
-            k = _compact_k(share) if compact_k is None else compact_k
+        places = [(False, _filtered_share(s.node.left, calc))]
+        if s.params.kind == "inner":
+            places.append((True, _estimated_share(s.node, leaf, calc)))
+        for inside, share in places:
+            if share is None:
+                continue
+            k = _compact_k(share, COMPACT_MIN_K[inside])
             if k:
-                return i, k
+                return i, compact_k or k, inside
     return None
 
 
-def _filtered_share(node: PlanNode) -> Optional[float]:
-    """The textbook estimate (``StatsCalculator`` without history)
-    of the share of their source's rows that the filters at the
-    top of ``node`` keep; None without a filter there, or where the
+def _estimated_share(node: PlanNode, of: PlanNode, calc) -> Optional[float]:
+    """The textbook estimate (``calc``: a ``StatsCalculator`` without
+    history) of ``node``'s rows as a share of ``of``'s; None where the
     estimate would have to read a materialized page."""
-    from presto_tpu.planner.stats import StatsCalculator
-
-    source, filtered = node, False
-    while isinstance(source, (FilterNode, ProjectNode)):
-        filtered = filtered or isinstance(source, FilterNode)
-        source = source.source
-    if not filtered:
-        return None
-    below = [source]
+    below = [node]
     while below:
         n = below.pop()
         if isinstance(n, PrecomputedNode):
             return None
         below.extend(n.sources)
-    calc = StatsCalculator()
-    rows = calc.rows(source)
+    rows = calc.rows(of)
     return calc.rows(node) / rows if rows > 0 else None
+
+
+def _filtered_share(node: PlanNode, calc) -> Optional[float]:
+    """The estimated share of their source's rows that the filters at
+    the top of ``node`` keep; None without a filter there."""
+    source, filtered = node, False
+    while isinstance(source, (FilterNode, ProjectNode)):
+        filtered = filtered or isinstance(source, FilterNode)
+        source = source.source
+    return _estimated_share(node, source, calc) if filtered else None

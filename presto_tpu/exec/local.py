@@ -314,6 +314,17 @@ class GroupCapacityExceeded(Exception):
         self.node = node
 
 
+class CompactionMissed(Exception):
+    """A page of a compacting chain held more live rows than its small
+    page (``_chain_pages``): the pages the chain gave are not to be
+    used.  The chain is marked, and the aggregation that was consuming
+    it (``node`` is the chain's root, its partial step) starts again
+    over the chain that does not compact (``_run_aggregation``)."""
+
+    def __init__(self, node):
+        self.node = node
+
+
 def _split_pruned(constraints, stats) -> bool:
     """True if split min/max stats prove no row can satisfy ALL the
     pushed-down conjuncts (ORC stripe-stats pruning role), via the
@@ -522,6 +533,15 @@ class _AggFoldTower:
             cap = page.capacity
         self.levels[cap] = (page, live, tag)
 
+    def release(self) -> None:
+        """Drop every level and its reservation: the pages came from a
+        try that is not to be used (``CompactionMissed``)."""
+        mem = self.runner._mem if self.account else None
+        for _, _, tag in self.levels.values():
+            if mem is not None and tag is not None:
+                mem.free(tag)
+        self.levels.clear()
+
     def finish_single(self) -> Optional[Page]:
         """One mode='single' merge over the surviving level pages,
         largest first (deterministic program signature)."""
@@ -627,6 +647,12 @@ class LocalRunner:
         self._builds_tls = _threading.local()
         # joins demoted out of fused chains because their build spilled
         self._force_expanding: set = set()
+        # chains (by root) one of whose pages did not fit its compaction
+        self._no_compact: set = set()
+        # the partial aggregation whose chain the breaker on this
+        # thread is about to consume, and can consume again: only its
+        # chain may compact (``_run_aggregation_impl``)
+        self._restartable_tls = _threading.local()
         # per-query split-scheduler stats (consumer-thread-local: the
         # scheduler's worker threads report through the shared stats
         # object, but the accumulator is owned by the query thread) and
@@ -904,7 +930,10 @@ class LocalRunner:
     def _lower(self, node: PlanNode,
                compact_k: Optional[int] = None) -> Chain:
         """The streaming chain rooted at ``node``, as this runner
-        stands: its demoted joins, its capacity retries."""
+        stands: its demoted joins, its capacity retries, its missed
+        compactions."""
+        if node in self._no_compact:
+            compact_k = 0
         return lower_chain(node, streaming=self._streaming,
                            max_groups=self._max_groups, compact_k=compact_k)
 
@@ -1235,7 +1264,10 @@ class LocalRunner:
         # pop the unordered grant FIRST: it applies to this chain only,
         # never to nested chains pulled while materializing builds
         unordered = self._take_unordered()
-        chain = self._lower(node)
+        # only the chain of the aggregation that asked, and can start
+        # again, may compact; not after a miss
+        restartable = getattr(self._restartable_tls, "root", None) is node
+        chain = self._lower(node, None if restartable else 0)
         joins = chain.joins
         try:
             consts = {f"build_{i}": self._materialize_build(j) for i, j in enumerate(joins)}
@@ -1253,27 +1285,28 @@ class LocalRunner:
             yield from self._chain_outputs(chain.leaf, fn, consts, unordered)
             return
         # A compacting program answers for the rows that fitted its
-        # small page and says whether all did.  Its (small, partial)
-        # pages are held until the last split is dispatched; then ONE
-        # read says whether any page held more, and if so the chain
-        # runs again under the program that does not compact.  So no
-        # row is ever dropped, whatever the estimate was worth.
+        # small page and says whether all did.  Each page goes to the
+        # consumer as it is produced and only the ``over`` scalars are
+        # kept; after the last split ONE read says whether any page
+        # held more, and if so this raises: the consumer drops what it
+        # built and starts again over the chain that does not compact.
+        # So no row is ever dropped, whatever the estimate was worth.
         from presto_tpu.obs import METRICS
 
-        outs = list(self._chain_outputs(chain.leaf, fn, consts, unordered))
-        fitted = not any(host_read([over for _, over in outs],
-                                   "compact_taken"))
+        flags = []
+        for page, over in self._chain_outputs(chain.leaf, fn, consts,
+                                              unordered):
+            flags.append(over)
+            yield page
+        fitted = not any(host_read(flags, "compact_taken"))
         which = "compacted" if fitted else "fallback"
         setattr(_HOST_READS, which,
-                getattr(_HOST_READS, which, 0) + len(outs))
+                getattr(_HOST_READS, which, 0) + len(flags))
         METRICS.counter("chain.compact_pages" if fitted
-                        else "chain.compact_fallback_pages").inc(len(outs))
-        if fitted:
-            yield from (page for page, _ in outs)
-            return
-        del outs
-        whole = self._chain_program(chain.uncompacted())
-        yield from self._chain_outputs(chain.leaf, whole, consts, unordered)
+                        else "chain.compact_fallback_pages").inc(len(flags))
+        if not fitted:
+            self._no_compact.add(node)
+            raise CompactionMissed(node)
 
     def _chain_program(self, chain: Chain):
         """``chain``'s program, named after its stages and compiled, or
@@ -1896,8 +1929,14 @@ class LocalRunner:
         from presto_tpu.memory import ExceededMemoryLimitError
 
         try:
-            return self._host_finalize_aggs(
-                node, self._run_aggregation_impl(node))
+            try:
+                out = self._run_aggregation_impl(node)
+            except CompactionMissed:
+                # a page of the source's chain did not fit its small
+                # page: nothing built from that try is kept, and the
+                # chain, marked by now, runs whole
+                out = self._run_aggregation_impl(node)
+            return self._host_finalize_aggs(node, out)
         except ExceededMemoryLimitError as e:
             if f"agg_accumulator@{id(node)}#" not in e.tag:
                 raise
@@ -2050,10 +2089,6 @@ class LocalRunner:
         """Breaker: stream partial pages and fold-merge with a bounded
         accumulator (2*max_groups concat each step, static shapes)."""
         mg = self._max_groups(node)
-        aggs = list(node.aggs)
-        num_keys = len(node.group_exprs)
-        kd = node.key_domains
-
         if node.step == "final":
             source: PlanNode = node.source
         else:
@@ -2073,6 +2108,24 @@ class LocalRunner:
                 self._partial_nodes[node] = partial
             self._agg_overrides[partial] = mg
             source = partial
+
+        # what follows consumes ``source``'s pages here and nowhere
+        # else, and ``_run_aggregation`` can run it again: the one
+        # consumer a chain may compact for (``_chain_pages``)
+        asked = getattr(self._restartable_tls, "root", None)
+        self._restartable_tls.root = source
+        try:
+            return self._fold_partials(node, source, mg)
+        finally:
+            self._restartable_tls.root = asked
+
+    def _fold_partials(self, node: AggregationNode, source: PlanNode,
+                       mg: int) -> Page:
+        """``_run_aggregation_impl`` over the partial pages of
+        ``source``."""
+        aggs = list(node.aggs)
+        num_keys = len(node.group_exprs)
+        kd = node.key_domains
 
         if node.group_exprs and not self._packed_direct(node, mg):
             # sort-path partials: live-extent compaction + tower merge.
@@ -2095,6 +2148,9 @@ class LocalRunner:
             try:
                 for p in self._pages(source):
                     tower.add(p)
+            except CompactionMissed:
+                tower.release()
+                raise
             finally:
                 self._unordered_tls.ok = False
             if node.step == "single" and tower.suspect_truncation \

@@ -1881,6 +1881,10 @@ def grouped_aggregate(
     mode='single' emits finalized values; 'partial' emits state columns
     (for exchange + merge_aggregate).
 
+    The output page has ``max_groups`` slots; on the sort path
+    ``min(max_groups, page.capacity)``, since a page has no more groups
+    than rows.
+
     Overflow: if the input has more than ``max_groups`` distinct keys
     the output is silently truncated to the first ``max_groups`` groups
     in key order — pass ``return_count=True`` to get (page, num_groups)
@@ -1941,7 +1945,12 @@ def grouped_aggregate(
             out = _emit(key_blocks, states, aggs, present, mode, group_exprs, key_dicts, agg_dicts)
             return (out, jnp.sum(present.astype(jnp.int32))) if return_count else out
 
-    # sort path
+    # sort path.  A page has no more groups than rows: where the
+    # capacity asked for is larger (a chain that compacted its page,
+    # tiny splits), the group-side arrays and the output page take the
+    # page's, and such a page cannot truncate.  Not above: on the
+    # packed-direct path the slot IS the key.
+    max_groups = min(max_groups, page.capacity)
     gid, num_groups, rep_rows, ctx = _sorted_group_ids(
         key, live, max_groups, want_ctx=True)
     states = _partial_states(page, aggs, gid, max_groups, ctx=ctx)

@@ -287,6 +287,53 @@ def _probe_keys(page: Page, key_exprs: Sequence[Expr], key_domains,
     return jnp.where(ok, key, jnp.iinfo(key.dtype).max - 1), ok
 
 
+def probe_lookup(
+    build: JoinBuild,
+    probe: Page,
+    probe_key_exprs: Sequence[Expr],
+    key_domains: Optional[Sequence[Optional[Tuple[int, int]]]] = None,
+    null_safe: bool = False,
+):
+    """The first half of ``probe_join``: per probe row the candidate
+    sorted position in ``build``, whether a live probe row found its
+    key there, and whether the row's key took part (no NULL in it, or
+    ``null_safe``).  Nothing of the build's rows is read yet, so a
+    chain may move the matched rows to a smaller page between this and
+    ``probe_fetch`` (``exec/chain.Lookup``)."""
+    key, ok = _probe_keys(probe, probe_key_exprs, key_domains, null_safe)
+    pos_c, found = _lookup_first(build, key)
+    return pos_c, found & probe.row_mask, ok
+
+
+def probe_fetch(
+    build: JoinBuild,
+    probe: Page,
+    pos_c: jax.Array,
+    match: jax.Array,
+    kind: str = "inner",
+    build_output: Optional[Sequence[int]] = None,
+) -> Page:
+    """The second half of ``probe_join`` for the kinds that emit build
+    columns (inner | left): the build row behind each candidate
+    position, and the selected build blocks gathered there."""
+    build_row = build.perm[pos_c]
+    if build_output is None:
+        build_output = range(len(build.page.blocks))
+    out_blocks: List[Block] = list(probe.blocks)
+    for i in build_output:
+        b = build.page.blocks[i]
+        data = b.data[build_row]
+        valid = b.valid[build_row] & match
+        out_blocks.append(Block(data, valid, b.type, b.dictionary))
+    if kind == "inner":
+        mask = probe.row_mask & match
+    elif kind == "left":
+        mask = probe.row_mask
+    else:
+        raise ValueError(kind)
+    return Page(tuple(out_blocks), mask)
+
+
 def probe_join(
     build: JoinBuild,
     probe: Page,
@@ -297,9 +344,10 @@ def probe_join(
     null_safe: bool = False,
     null_aware: bool = False,
 ) -> Page:
-    """Probe-aligned join for unique (or first-match) build keys.
+    """Probe-aligned join for unique (or first-match) build keys:
+    ``probe_lookup``, then the kind's use of it.
 
-    kind: inner | left | semi | anti.
+    kind: inner | left | semi | anti | mark.
     Output: probe blocks followed by the selected build blocks
     (build_output indexes into build.page.blocks; default all).
     semi/anti emit probe blocks only, with the row mask filtered.
@@ -311,10 +359,8 @@ def probe_join(
     NULL mark.  IN over an empty subquery stays FALSE for every probe,
     NULL keys included.
     """
-    key, ok = _probe_keys(probe, probe_key_exprs, key_domains, null_safe)
-    pos_c, found = _lookup_first(build, key)
-    match = found & probe.row_mask
-    build_row = build.perm[pos_c]
+    pos_c, match, ok = probe_lookup(build, probe, probe_key_exprs,
+                                    key_domains, null_safe)
 
     if null_aware and kind in ("semi", "anti", "mark") \
             and build.has_null_key is not None:
@@ -348,21 +394,7 @@ def probe_join(
         mark = Block(match, jnp.ones_like(probe.row_mask), BOOLEAN)
         return Page(tuple(probe.blocks) + (mark,), probe.row_mask)
 
-    if build_output is None:
-        build_output = range(len(build.page.blocks))
-    out_blocks: List[Block] = list(probe.blocks)
-    for i in build_output:
-        b = build.page.blocks[i]
-        data = b.data[build_row]
-        valid = b.valid[build_row] & match
-        out_blocks.append(Block(data, valid, b.type, b.dictionary))
-    if kind == "inner":
-        mask = probe.row_mask & match
-    elif kind == "left":
-        mask = probe.row_mask
-    else:
-        raise ValueError(kind)
-    return Page(tuple(out_blocks), mask)
+    return probe_fetch(build, probe, pos_c, match, kind, build_output)
 
 
 def probe_expand(

@@ -56,18 +56,19 @@ def _other(value):
 @pytest.fixture(scope="module")
 def stages(catalog):
     """One real stage of each kind that a plan node gives, by kind:
-    q14's chain (filter, compact, probe, partial aggregation) and the
-    projection over its answer."""
+    q14's chain (filter, compact, probe, partial aggregation) with the
+    projection over its answer, and q3's (lookup, compact, fetch)."""
     runner, _ = _fresh(catalog)
     ex = runner.executor
     found = {}
-    node = runner.binder.plan(QUERIES[14])
-    while node.sources:
-        if isinstance(node, AggregationNode):
-            node = dataclasses.replace(node, step="partial")
-        for stage in ex._lower(node).stages:
-            found.setdefault(stage.kind, stage)
-        node = node.sources[0]
+    for q in (14, 3):
+        node = runner.binder.plan(QUERIES[q])
+        while node.sources:
+            if isinstance(node, AggregationNode):
+                node = dataclasses.replace(node, step="partial")
+            for stage in ex._lower(node).stages:
+                found.setdefault(stage.kind, stage)
+            node = node.sources[0]
     return found
 
 
@@ -76,9 +77,9 @@ FIELDS = [(kind, field) for kind, params in sorted(KINDS.items())
 
 
 def test_stage_kinds_are_the_grammar():
-    assert sorted(KINDS) == ["agg_partial", "compact", "cross1", "filter",
-                             "probe", "project"]
-    assert len(FIELDS) >= 12
+    assert sorted(KINDS) == ["agg_partial", "compact", "cross1", "fetch",
+                             "filter", "lookup", "probe", "project"]
+    assert len(FIELDS) >= 16
 
 
 @pytest.mark.parametrize("kind,field", FIELDS)
@@ -109,7 +110,8 @@ def test_every_param_is_in_the_signature(stages, kind, field):
 CHAINS = {
     1: {"chain_leaf_filter_agg_k2a8", "chain_leaf_project"},
     3: {"chain_leaf_filter", "chain_leaf_filter_probe",
-        "chain_leaf_filter_probe_agg_k3a1", "chain_leaf_project"},
+        "chain_leaf_filter_probe_agg_k3a1_compact_in_probe0",
+        "chain_leaf_project"},
     4: {"chain_leaf_filter", "chain_leaf_filter_compact_probe_agg_k1a1",
         "chain_leaf_project"},
     6: {"chain_leaf_filter_agg_k0a1", "chain_leaf_project"},
@@ -147,8 +149,12 @@ def test_the_registry_holds_what_lower_chain_describes(catalog, q,
         assert not chain_mod._member(chain.leaf, ex._streaming)
         tags = chain.name().split("_")
         assert len(chain.joins) == tags.count("probe") + tags.count("cross1")
+        kinds = [s.kind for s in chain.stages]
+        assert kinds.count("lookup") == kinds.count("fetch") == \
+            chain.name().count("_compact_in_probe")
         assert all(isinstance(j, JoinNode) for j in chain.joins)
-        assert [s.node for s in chain.stages if s.kind != "compact"] == \
+        assert [s.node for s in chain.stages
+                if s.kind not in ("compact", "lookup")] == \
             _members(node, chain.leaf)
     # every registered chain was described by a lowering of this run
     assert set(held) == {("chain", True, ir_signature(c.signature()))

@@ -454,7 +454,8 @@ PROGRAMS = {
         "jit_join_build": {"op:JoinBuild"},
         "jit_chain_leaf_filter_probe": {"op:Filter", "op:Join",
                                         "join:lookup"},
-        "jit_chain_leaf_filter_probe_agg_k3a1": _PROBE_AGG | {"agg:sort"},
+        "jit_chain_leaf_filter_probe_agg_k3a1_compact_in_probe0":
+            _PROBE_AGG | {"agg:sort", "filter:compact"},
         "jit_agg_tower_final": {"op:Aggregation", "agg:sort", "agg:reduce"},
         "jit_topn": {"op:TopN"},
         "jit_chain_leaf_project": set()},
@@ -534,7 +535,8 @@ def test_scopes_change_neither_answers_nor_program_count(
         rows, count, added = bare[q]
         assert not any(added.values()), added  # the scopes are gone
         # named after the closure
-        assert ("jit_compact_stage" if q == 14 else "jit_agg_stage") in added
+        assert ("jit_compact_stage" if q in (14, 3)
+                else "jit_agg_stage") in added
         assert (rows, count) == four_queries[q][:2]
 
 
@@ -597,10 +599,11 @@ def test_host_reads_in_stats_and_spans(statement_server, q):
         assert reads[-1] == "host_read:result"
         if q in (14, 3):  # one uniqueness check per primary-key build
             assert "host_read:unique_ok" in reads
-        # q14's chain compacts in front of its probe: one read says
-        # whether every page fitted, and the stats count the pages
-        assert reads.count("host_read:compact_taken") == (q == 14)
-        assert (pages[-1]["compactedPages"] > 0) == (q == 14)
+        # q14's chain compacts in front of its probe and q3's inside
+        # it: one read says whether every page fitted, and the stats
+        # count the pages
+        assert reads.count("host_read:compact_taken") == (q in (14, 3))
+        assert (pages[-1]["compactedPages"] > 0) == (q in (14, 3))
         assert pages[-1]["compactFallbackPages"] == 0
     assert counts["false"] == counts["true"] >= 1
 

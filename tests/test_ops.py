@@ -83,6 +83,31 @@ def test_grouped_aggregate(domains):
         assert avg == pytest.approx(e["sum"] / e["count"])
 
 
+@pytest.mark.parametrize("mode", ["single", "partial"])
+@pytest.mark.parametrize("path", ["sort", "packed_direct"])
+def test_a_page_has_no_more_groups_than_rows(path, mode):
+    """On the sort path a capacity over the page's is the page's: the
+    same groups as at a capacity that fits, in a page of 8 slots, and
+    the count with them.  The packed-direct layout keeps the capacity
+    it was given: there the slot is the key."""
+    p = _agg_page()
+    assert p.capacity == 8
+    domains = [(0, 2)] if path == "packed_direct" else None
+    big, n_big = grouped_aggregate(
+        p, [col(0, BIGINT)], AGGS, max_groups=64, key_domains=domains,
+        mode=mode, return_count=True)
+    fit, n_fit = grouped_aggregate(
+        p, [col(0, BIGINT)], AGGS, max_groups=8, key_domains=domains,
+        mode=mode, return_count=True)
+    assert sorted(rows(big)) == sorted(rows(fit)) and len(rows(big)) == 3
+    assert int(n_big) == int(n_fit) == 3
+    assert fit.capacity == 8
+    assert big.capacity == (8 if path == "sort" else 64)
+    if mode == "single":
+        assert {r[0]: r[1] for r in rows(big)} == {
+            g: e["sum"] for g, e in _expected().items()}
+
+
 def test_global_aggregate():
     p = _agg_page()
     out = grouped_aggregate(p, [], AGGS, max_groups=1)
@@ -163,6 +188,23 @@ def test_inner_join_unique():
     jb = build_join(b, [col(0, BIGINT)])
     out = probe_join(jb, p, [col(0, BIGINT)], kind="inner", build_output=[1])
     assert sorted(rows(out)) == [(10, 6, 1.0), (20, 5, 2.0), (20, 9, 2.0), (30, 8, 3.0)]
+
+
+@pytest.mark.parametrize("kind", ["inner", "left"])
+def test_probe_join_is_lookup_then_fetch(kind):
+    """The two halves a chain may put a compaction between: the lookup
+    reads no build row, the fetch nothing of the probe's keys."""
+    from presto_tpu.ops.join import probe_fetch, probe_lookup
+
+    b, p = _build_probe()
+    jb = build_join(b, [col(0, BIGINT)])
+    pos, match, ok = probe_lookup(jb, p, [col(0, BIGINT)])
+    assert pos.shape == match.shape == ok.shape == (p.capacity,)
+    assert np.asarray(match)[:5].tolist() == [True, True, False, True, True]
+    assert np.asarray(ok)[:5].all()
+    out = probe_fetch(jb, p, pos, match, kind, [1])
+    assert rows(out) == rows(probe_join(jb, p, [col(0, BIGINT)], kind=kind,
+                                        build_output=[1]))
 
 
 def test_left_join_nulls():
