@@ -1,6 +1,7 @@
 """Kernels: per traced pass, the time chip 0 ran operations under the
-scopes ``op:Filter`` and ``op:Project`` (``exec/local._build_stage``);
-median over the traced passes.  Near zero where XLA fuses the
+scopes ``op:Filter`` and ``op:Project`` (opened per chain stage by the
+stage's ``apply`` in ``exec/chain.py``; a chain's compaction is
+``op:Filter/filter:compact``); median over the traced passes.  Near zero where XLA fuses the
 predicate and the projections into their consumer: a fusion is booked
 to the scope on its own metadata (``benchmark/scopes.py``)."""
 

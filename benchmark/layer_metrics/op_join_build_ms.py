@@ -7,7 +7,8 @@ from benchmark import scopes
 
 NAME = "op_join_build_ms"
 UNIT = "ms"
-WORKLOADS = ["tpch_sf10.join", "tpch_sf1.join_agg"]
+WORKLOADS = ["tpch_sf10.join", "tpch_sf1.join_agg",
+             "tpch_sf1_fkjoin.csr_join"]
 
 
 def read(run):

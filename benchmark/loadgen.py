@@ -4,12 +4,16 @@ sends, and its ``loop`` names the loop here that reads the rest.
 
 ``closed_passes``: ``clients`` closed-loop clients, each sending the
 mix's ordered query list over and over and waiting for each answer.
-One *pass* is one trip through the list.  The seed rotates the list's
-starting point and sets the run of trailing spaces each statement
-carries, so that every statement's text is new to the server (parse,
-bind and plan run every time; ``QueryRunner._plans`` is keyed by text)
-while structure and literals stay fixed (no program is new).  The same
-seed gives the same statements in the same order.
+One *pass* is one trip through the list.  The seed rotates the starting
+point of the window's passes (``Statements.order``) and sets the run of
+trailing spaces each statement carries, so that every statement's text
+is new to the server (parse, bind and plan run every time;
+``QueryRunner._plans`` is keyed by text) while structure and literals
+stay fixed (no program is new).  The same seed gives the same
+statements in the same order.  The seed does not order the warm-up:
+``run.py`` sends that in the mix's own list order for every seed and
+takes its texts from the same ``Statements.text``, so a process starts
+alike whatever the seed and no text repeats (PERF.md, PR 33).
 """
 
 from __future__ import annotations
@@ -52,8 +56,9 @@ class Pass:
 
 
 class Statements:
-    """One client's statements: the rotated pass order, and for each
-    query the next text no earlier statement had."""
+    """One client's statements: the window's rotated pass order, and
+    for each query the next text no earlier statement had, warm-up
+    included."""
 
     def __init__(self, mix: dict, queries: List[Query], seed: int,
                  client: int = 0):

@@ -11,11 +11,13 @@
 3. loads the configuration's tables onto the device (``tables.py``);
 4. starts ``CoordinatorServer(QueryRunner(catalog))`` on loopback, opens
    ``StatementClient``s and sets the layout's session properties;
-5. warms up: every query of the cell twice, each answer compared with
-   the stored reference;
+5. warms up: every query of the cell twice, in the mix's own list order
+   whatever the seed (the seed orders the window's passes, not the start
+   of the process), each answer compared with the stored reference;
 6. measures for ``--seconds`` (``loadgen.py``);
 7. prints the observations (sample counts, per-query medians, phases)
-   on one line and the contract's result object as the last line.
+   on one line and the contract's result object as the last line, what
+   ``correct`` compared under its last key, ``compared``.
 
 Everything up to the start of (6) is ``setup_s``.  With ``--trace 0``
 the metrics are the end-to-end ones; with ``--trace 1`` a few passes of
@@ -292,12 +294,15 @@ def main(argv=None) -> int:
                                  for s in list(tracer.spans)]
             return rec
 
-        # warm-up: each query twice; the first call compiles or loads
+        # warm-up: each query twice; the first call compiles or loads.
+        # In the mix's list order for every seed: which statement a
+        # process runs first sets where its buffers fall in HBM, and a
+        # gather's time depends on that (PERF.md, PR 27, PR 33)
         cache0 = persistent_cache_stats()
         programs0 = registry.program_count()
         warm: List[loadgen.QueryRecord] = []
         first_call_s = 0.0
-        for query in statements[0].order:
+        for query in cell.queries:
             first = submit(0, query, statements[0].text(query))
             second = submit(0, query, statements[0].text(query))
             first_call_s += first.client_ms / 1e3
@@ -380,17 +385,21 @@ def main(argv=None) -> int:
     for p in good:
         for q in p.queries:
             by_query.setdefault(q.name, []).append(q)
+    pass_ms = [p.ms for p in good]
+    p25, p75 = stats.quartiles(pass_ms) or (None, None)
     observations = {
         "cell": cell.name, "seed": args.seed, "seconds": args.seconds,
         "trace": args.trace, "passes": len(good),
         "traced_passes": len(run.traced),
+        "warm_up_order": [q.name for q in cell.queries],
         "queries_per_pass": [q.name for q in statements[0].order],
         "setup_s": setup_s, "phases": phases, "warm_up": warm_up,
         "window_counters": counters,
-        "pass_ms": {"p50": stats.median([p.ms for p in good]),
-                    "min": min((p.ms for p in good), default=None),
-                    "max": max((p.ms for p in good), default=None),
-                    "p95": (stats.percentile([p.ms for p in good], 95)
+        "pass_ms": {"p50": stats.median(pass_ms),
+                    "p25": p25, "p75": p75,
+                    "min": min(pass_ms, default=None),
+                    "max": max(pass_ms, default=None),
+                    "p95": (stats.percentile(pass_ms, 95)
                             if len(good) >= TAIL_MIN_PASSES else None)},
         "per_query_p50": {
             name: {"n": len(qs),
@@ -414,8 +423,20 @@ def main(argv=None) -> int:
     }
     if breakdown is not None:
         result["breakdown"] = breakdown
+    # what ``correct`` compared, each number beside its limit: last in
+    # the result's line and the last lines of standard error
+    answers = len(warm) + len(records)
+    result["compared"] = {
+        "answers_wrong": {"value": len(failed), "limit": 0, "of": answers},
+        "passes": {"value": len(good), "at_least": 1},
+        "first_wrong": [f"{r.name} #{r.seq}: {r.why}"[:200]
+                        for r in failed[:3]],
+    }
     print(json.dumps(observations), flush=True)
     print(json.dumps(result), flush=True)
+    log(f"compared: answers_wrong {len(failed)} (limit 0) of {answers} "
+        f"answers, warm-up and window")
+    log(f"compared: passes {len(good)} (at least 1)")
     return 0
 
 

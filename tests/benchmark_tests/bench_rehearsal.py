@@ -20,7 +20,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 BENCHMARK = os.path.join(REPO, "benchmark")
 TINY_SF = 0.01
 TINY_SPLIT_ROWS = 8192
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
 
 
 def make_copy(tmp: str) -> str:

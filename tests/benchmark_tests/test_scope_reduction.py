@@ -216,5 +216,7 @@ def test_cpu_rehearsal_reports_host_reads_and_names_programs(rehearsed):
                 for name, _ in rehearsed["breakdown"]["device_ops"]}
     assert programs and not any(p.startswith("jit_agg_stage")
                                 for p in programs)
-    assert any(p.startswith("jit_chain_leaf_filter_probe_agg_k")
+    # q3's chain, by its leaf and its aggregation and not by what stands
+    # between them: a later PR may name the stages of its probe
+    assert any(p.startswith("jit_chain_leaf_filter") and "agg_k3a1" in p
                for p in programs)
