@@ -45,6 +45,7 @@ not just plausible.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, List, Optional, Tuple
 
 from presto_tpu.analysis import ranges
@@ -71,8 +72,10 @@ from presto_tpu.planner.plan import (
 )
 
 __all__ = [
+    "EvidenceContext",
     "KernelSoundnessError",
     "analyze_kernels",
+    "arith_report",
     "assert_kernel_sound",
     "predicted_intervals",
 ]
@@ -101,6 +104,54 @@ class KernelSoundnessError(Exception):
 # ---------------------------------------------------------------------------
 # channel-interval propagation (bottom-up over the plan DAG)
 # ---------------------------------------------------------------------------
+
+class EvidenceContext(_Context):
+    """The context code generation is proved under
+    (``exec/local.LocalRunner``, one per query).
+
+    A plan outlives the table state it was bound against: plans are
+    cached by SQL text, and a scan enumerates its splits when the query
+    runs.  So nothing bound into the plan is evidence here.  A scan's
+    domains are read from its connector when a chain first asks, and
+    only from a connector that versions its tables (``table_version``:
+    the domain read between two equal versions is that version's); the
+    split count is read BEFORE them and kept in ``splits``, and the
+    scan runs over those splits and no later ones, so a row appended
+    since is the next statement's (the connector's side of the
+    bargain: a declared domain is made true before the rows it covers
+    can be counted; docs/static-analysis.md).  Row counts bound
+    nothing (``_agg_output_values``): the handle's is the bind's."""
+
+    def __init__(self, catalog):
+        super().__init__()
+        self._catalog = catalog
+        #: id(scan) -> the number of splits its evidence covers
+        self.splits: Dict[int, int] = {}
+
+    def channels(self, node: PlanNode) -> list:
+        if isinstance(node, TableScanNode) and id(node) not in self._channels:
+            self._channels[id(node)] = [
+                dataclasses.replace(c, domain=d)
+                for c, d in zip(node.channels, self._scan_domains(node))]
+        return super().channels(node)
+
+    def _scan_domains(self, node: TableScanNode) -> list:
+        conn = self._catalog.connector(node.handle.connector_name)
+        version = getattr(conn, "table_version", None)
+        domain = getattr(conn, "column_domain", None)
+        if version is None or domain is None:
+            return [None] * len(node.columns)
+        table = node.handle.table
+        v = version(table)
+        if node.splits is None and hasattr(conn, "num_splits"):
+            self.splits[id(node)] = conn.num_splits(table)
+        doms = [domain(table, node.handle.columns[i].name)
+                for i in node.columns]
+        if version(table) != v:  # a write landed in between
+            return [None] * len(node.columns)
+        return doms
+
+
 
 def _scan_values(node: TableScanNode, ctx: _Context) -> List[AbstractValue]:
     out = [ranges.channel_value_of_channel(c) for c in ctx.channels(node)]
@@ -147,7 +198,8 @@ def _agg_output_values(node: AggregationNode, env: List[AbstractValue],
 
     keys = [eval_expr(e, env) for e in node.group_exprs]
     try:
-        hi_rows = derive_properties(node.source).hi
+        hi_rows = (None if isinstance(ctx, EvidenceContext)
+                   else derive_properties(node.source).hi)
     except Exception:
         hi_rows = None
     outs = []
@@ -241,6 +293,12 @@ def channel_values(node: PlanNode, ctx: _Context,
                 # from the arms don't survive; the merged channel's own
                 # domain does
                 vals.append(ranges.channel_value_of_channel(merged_chans[i]))
+            elif t is None or any(
+                    i >= len(ctx.channels(s)) or ctx.channels(s)[i].type != t
+                    for s in node.inputs):
+                # an arm in another representation (a decimal of
+                # another scale): its interval is not the merged lane's
+                vals.append(top(t) if t is not None else arms[0][i])
             else:
                 v = arms[0][i]
                 for a in arms[1:]:
@@ -464,3 +522,45 @@ def predicted_intervals(plan: PlanNode) -> Dict[int, List[Optional[Tuple]]]:
                 preds.append(None)
         out[id(node)] = preds
     return out
+
+
+def arith_report(plan: PlanNode, ctx: _Context, max_groups) -> List[str]:
+    """What the intervals prove for code generation, one line per
+    guarded arithmetic site and per limb sum of a short addend:
+    ``<node> <label>: <expr> proven|checked`` (EXPLAIN (TYPE
+    VALIDATE); ``exec/local.LocalRunner.arith_report`` brings its
+    :class:`EvidenceContext` and its ``max_groups(node)``).
+    ``proven``: the executor's chains compile the site without its
+    runtime guard (``exec/chain.lower_chain`` asks the same
+    ``channel_values``, ``ranges.site_proven`` and ``ops/aggregate.
+    one_lane_sums``); for a sum, that a page of up to the stated rows
+    reduces in one int64 lane."""
+    from presto_tpu.ops.aggregate import (
+        agg_exprs, limb_sum_site, one_lane_sums,
+    )
+
+    order: List[PlanNode] = []
+    _walk(plan, ctx, set(), order)
+    memo: Dict[int, List[AbstractValue]] = {}
+    lines: List[str] = []
+    for node in order:
+        if ctx.channel_error(node) is not None:
+            continue
+        for root, src, label in _node_exprs(node):
+            env = channel_values(src, ctx, memo)
+            exprs = (agg_exprs((), [root]) if isinstance(root, AggCall)
+                     else [root])
+            for site, ok in zip(ranges.arith_sites(exprs),
+                                ranges.prove_sites(exprs, env)):
+                lines.append(f"{ctx.name(node)} {label}: {site!r} "
+                             f"{'proven' if ok else 'checked'}")
+            if isinstance(root, AggCall) and limb_sum_site(root) \
+                    and getattr(node, "step", None) != "final":
+                rows = ranges.sum_lane_rows(eval_expr(root.arg, env))
+                (one,) = one_lane_sums(
+                    [root], [rows], max_groups(node) if node.group_exprs else 1)
+                lines.append(
+                    f"{ctx.name(node)} {label}: {root!r} "
+                    + (f"proven (one int64 lane a page of up to {rows} "
+                       f"rows)" if one else "checked (limbs row by row)"))
+    return lines

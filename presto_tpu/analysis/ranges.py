@@ -32,7 +32,9 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 from presto_tpu.expr.ir import (
     AggCall,
@@ -273,6 +275,38 @@ def _rescale_iv(lo, hi, from_scale: int, to_scale: int):
     return (lo, hi)
 
 
+def _coerced(args: Sequence[AbstractValue], arg_types: Sequence[Type],
+             out_type: Type) -> Optional[List[AbstractValue]]:
+    """``args`` as the compiler's ``_coerce`` brings value operands to
+    ``out_type`` (the branches of if/case/coalesce, greatest/least): a
+    decimal or integer operand of a decimal result is up-scaled, of a
+    double result divided by its scale.  None for a coercion without a
+    rule here (the caller falls back to the type contract)."""
+    out = []
+    for a, t in zip(args, arg_types):
+        if t == out_type:
+            out.append(a)
+        elif out_type.name in ("double", "real") \
+                and t.value_shape == () and not t.is_string:
+            f = 10.0 ** _scale_of(t)
+            out.append(dataclasses.replace(
+                a, lo=a.lo / f if a.lo != -INF else a.lo,
+                hi=a.hi / f if a.hi != INF else a.hi))
+        elif out_type.is_decimal and not t.is_long_decimal \
+                and (t.is_decimal or device_int_bounds(t) is not None) \
+                and _scale_of(t) <= out_type.scale:
+            lo, hi = _rescale_iv(a.lo, a.hi, _scale_of(t), out_type.scale)
+            out.append(dataclasses.replace(a, lo=lo, hi=hi))
+        elif device_int_bounds(out_type) is not None \
+                and not out_type.is_decimal and not t.is_decimal \
+                and device_int_bounds(t) is not None \
+                and t.name not in ("date", "timestamp", "time"):
+            out.append(a)  # integer widening keeps the value
+        else:
+            return None
+    return out
+
+
 def transfer(fn: str, out_type: Type, args: Sequence[AbstractValue],
              arg_types: Sequence[Type]):
     """Raw (pre-clamp) result interval of ``fn`` plus null/nan bits, as
@@ -346,6 +380,10 @@ def transfer(fn: str, out_type: Type, args: Sequence[AbstractValue],
             return AbstractValue(*type_bounds(out_type),
                                  may_null=strict_null, known=False)
         lo, hi = _rescale_iv(a.lo, a.hi, _scale_of(t0), out_type.scale)
+        if out_type.scale < _scale_of(t0):
+            # the kernel's down-scale floors (``_rescale``) where
+            # ``_rescale_iv`` truncates: one unit of slack each way
+            lo, hi = lo - 1, hi + 1
         return AbstractValue(lo, hi, may_null=strict_null, known=a.known)
     if fn in ("cast_double", "to_unixtime"):
         a = args[0]
@@ -406,29 +444,47 @@ def transfer(fn: str, out_type: Type, args: Sequence[AbstractValue],
         return AbstractValue(0, INF, may_null=strict_null, known=False)
 
     if fn in ("greatest", "least"):
-        lo = (max if fn == "greatest" else min)(a.lo for a in args)
-        hi = (max if fn == "greatest" else min)(a.hi for a in args)
+        vals = _coerced(args, arg_types, out_type)
+        if vals is None:
+            return top(out_type)
+        lo = (max if fn == "greatest" else min)(a.lo for a in vals)
+        hi = (max if fn == "greatest" else min)(a.hi for a in vals)
         # NULL if ANY argument is NULL (kernel parity)
         return AbstractValue(lo, hi, may_null=strict_null,
                              may_nan=nan_in, known=known)
 
     if fn == "coalesce":
-        out = args[0]
-        for a in args[1:]:
+        vals = _coerced(args, arg_types, out_type)
+        if vals is None:
+            return top(out_type)
+        out = vals[0]
+        for a in vals[1:]:
             out = out.join(a)
         return AbstractValue(out.lo, out.hi,
                              may_null=all(a.may_null for a in args),
                              may_nan=out.may_nan, known=known)
-    if fn == "if":
-        # args: cond, then, else?  missing else -> NULL
-        branches = list(args[1:]) or [AbstractValue(0, 0, may_null=True)]
+    if fn in ("if", "case"):
+        # if: cond, then, else? (missing else -> NULL);
+        # case: when1, then1, ..., else
+        if fn == "if":
+            conds, picks = [0], list(range(1, len(args)))
+        else:
+            conds = list(range(0, len(args) - 1, 2))
+            picks = list(range(1, len(args) - 1, 2)) + [len(args) - 1]
+        branches = _coerced([args[i] for i in picks],
+                            [arg_types[i] for i in picks], out_type)
+        if branches is None:
+            return top(out_type)
+        branches = branches or [AbstractValue(0, 0, may_null=True)]
         out = branches[0]
         for a in branches[1:]:
             out = out.join(a)
-        may_null = (any(a.may_null for a in branches) or len(args) < 3
-                    or args[0].may_null)
+        may_null = (any(a.may_null for a in branches)
+                    or (fn == "if" and len(args) < 3)
+                    or any(args[i].may_null for i in conds))
         return AbstractValue(out.lo, out.hi, may_null=may_null,
-                             may_nan=out.may_nan, known=known)
+                             may_nan=out.may_nan,
+                             known=all(a.known for a in branches))
     if fn == "nullif":
         a = args[0]
         return AbstractValue(a.lo, a.hi, may_null=True,
@@ -511,14 +567,29 @@ def null_effect(fn: str) -> str:
 # ---------------------------------------------------------------------------
 
 def eval_expr(e: Expr, env: List[AbstractValue],
-              on_hazard: Optional[Callable] = None) -> AbstractValue:
+              on_hazard: Optional[Callable] = None,
+              memo: Optional[Dict[int, AbstractValue]] = None
+              ) -> AbstractValue:
     """Abstract value of ``e`` over per-channel values ``env``.
 
     ``on_hazard(kind, expr, raw, bounds)`` fires for every device-width
     escape found along the way (``kind`` ∈ {"overflow", "lossy-cast",
     "division"}); the returned value is already clamped to the device
     width (escaped lanes NULL at runtime, so in-flight values can't
-    exceed it)."""
+    exceed it).  ``memo`` (by ``id``, for one ``env`` and while the
+    caller keeps the expressions alive) makes a walk that asks for
+    every sub-expression linear; hazards are not reported through it."""
+    if memo is not None:
+        got = memo.get(id(e))
+        if got is None:
+            got = memo[id(e)] = _eval_expr(e, env, None, memo)
+        return got
+    return _eval_expr(e, env, on_hazard, None)
+
+
+def _eval_expr(e: Expr, env: List[AbstractValue],
+               on_hazard: Optional[Callable],
+               memo: Optional[Dict[int, AbstractValue]]) -> AbstractValue:
     if isinstance(e, Literal):
         return from_literal(e)
     if isinstance(e, ColumnRef):
@@ -545,10 +616,10 @@ def eval_expr(e: Expr, env: List[AbstractValue],
         # TRY subtree: the reference returns NULL exactly where our
         # kernels NULL the lane, so trappable escapes beneath are not
         # deviations — evaluate without hazard reporting
-        v = eval_expr(e.args[0], env, None)
+        v = eval_expr(e.args[0], env, None, memo)
         return dataclasses.replace(v, may_null=True)
 
-    args = [eval_expr(a, env, on_hazard) for a in e.args]
+    args = [eval_expr(a, env, on_hazard, memo) for a in e.args]
     arg_types = [a.type for a in e.args]
     raw = transfer(e.fn, e.type, args, arg_types)
 
@@ -556,13 +627,25 @@ def eval_expr(e: Expr, env: List[AbstractValue],
         _report_hazards(e, args, arg_types, raw, on_hazard)
 
     # clamp to the device width: escaped lanes are NULLed by the kernel
-    # guards, so downstream propagation stays inside the lane bounds
+    # guards, so downstream propagation stays inside the lane bounds.
+    # A kernel WITHOUT such a guard (an up-scaling decimal cast) wraps
+    # its escaped lanes to anywhere in the lane, and they stay valid:
+    # all that is known of them is the lane itself.
     dev = device_int_bounds(e.type)
     if dev is not None and (raw.lo < dev[0] or raw.hi > dev[1]):
-        raw = AbstractValue(max(raw.lo, dev[0]), min(raw.hi, dev[1]),
-                            may_null=True, may_nan=raw.may_nan,
+        lo, hi = dev
+        if e.fn in _NULLS_ESCAPED_LANES:
+            lo, hi = max(raw.lo, lo), min(raw.hi, hi)
+        raw = AbstractValue(lo, hi, may_null=True, may_nan=raw.may_nan,
                             known=raw.known)
     return raw
+
+
+#: kernels whose guard NULLs a lane that escaped the device width
+#: (expr/compile.py: ``_ovf_*``, ``_rescale_guard``, the range checks
+#: of the narrowing casts)
+_NULLS_ESCAPED_LANES = frozenset({
+    "add", "sub", "mul", "neg", "abs", "cast_smallint", "cast_tinyint"})
 
 
 def _report_hazards(e: Call, args, arg_types, raw: AbstractValue,
@@ -601,3 +684,129 @@ def channel_value_of_channel(ch) -> AbstractValue:
             and not t.is_raw_string:
         return from_channel(t, ch.domain)
     return top(t)
+
+
+# ---------------------------------------------------------------------------
+# proofs for code generation
+# ---------------------------------------------------------------------------
+# The same intervals license the cheapest exact form of the generated
+# arithmetic (expr/compile.py ``_compile_arith``, ops/aggregate.py
+# ``_partial_states``).  A proof holds for every lane that is live and
+# non-NULL: a dead or NULL lane may hold anything (a row the scan's
+# pushed-down conjunct will mask, a wrapped product under a guard that
+# fired), and nothing reads its data.  Only ``known`` intervals prove
+# (literals, connector domains, VALUES rows): the type contract alone
+# never drops a guard.
+
+#: the kernels whose compiled form carries a runtime guard an interval
+#: can discharge: the wrap mask of add/sub/mul/neg on integer lanes
+#: (with the ``_rescale_guard`` of a decimal operand), the zero check
+#: of div/mod
+GUARDED_FNS = frozenset({"add", "sub", "mul", "neg", "div", "mod"})
+
+
+def arith_sites(exprs: Sequence[Optional[Expr]]) -> Iterator[Call]:
+    """The guarded arithmetic calls of ``exprs`` in pre-order, each
+    expression in turn: THE order of a stage's ``proven`` tuple.
+    Lambda bodies are not entered (their lanes have no interval), long
+    decimals and the float forms of add/sub/mul carry no guard."""
+    for root in exprs:
+        stack = [root]
+        while stack:
+            e = stack.pop()
+            if not isinstance(e, Call):
+                continue
+            if is_guarded(e):
+                yield e
+            stack.extend(reversed(e.args))
+
+
+def is_guarded(e: Call) -> bool:
+    if e.fn not in GUARDED_FNS or e.type.is_long_decimal:
+        return False
+    if e.fn in ("div", "mod"):
+        return e.type.value_shape == ()
+    return device_int_bounds(e.type) is not None
+
+
+def _inside(lo, hi, bounds) -> bool:
+    return bounds[0] <= lo and hi <= bounds[1]
+
+
+def site_proven(e: Call, env: Sequence[AbstractValue],
+                memo: Optional[Dict[int, AbstractValue]] = None) -> bool:
+    """True when no guard of the guarded call ``e`` can fire over
+    ``env``: the raw result of add/sub/mul/neg (and each up-scaled
+    decimal operand) stays inside the lane, the divisor of div/mod
+    excludes zero (and ``INT_MIN / -1`` cannot meet)."""
+    if memo is None:
+        memo = {}
+    args = [eval_expr(a, env, None, memo) for a in e.args]
+    if not all(a.known for a in args):
+        return False
+    arg_types = [a.type for a in e.args]
+    if not e.type.is_decimal and device_int_bounds(e.type) is not None \
+            and np.result_type(*(t.np_dtype for t in arg_types)) \
+            != e.type.np_dtype:
+        # the integer kernels compute in the promotion of their
+        # operands' lanes; the proof is of the declared lane
+        return False
+    so = _scale_of(e.type)
+    if e.type.is_decimal and e.fn in ("add", "sub", "mod"):
+        # operands are up-scaled to the result's scale first
+        for a, t in zip(args, arg_types):
+            if not _inside(*_rescale_iv(a.lo, a.hi, _scale_of(t), so), I64):
+                return False
+    if e.fn in ("div", "mod"):
+        a, b = args
+        if b.lo <= 0 <= b.hi:
+            return False
+        dev = device_int_bounds(e.type)
+        if e.fn == "div" and dev is not None and not e.type.is_decimal:
+            return not (a.lo <= dev[0] and b.lo <= -1 <= b.hi)
+        return True
+    raw = transfer(e.fn, e.type, args, arg_types)
+    return raw.known and _inside(raw.lo, raw.hi, device_int_bounds(e.type))
+
+
+def prove_sites(exprs: Sequence[Optional[Expr]],
+                env: Sequence[AbstractValue]) -> Tuple[bool, ...]:
+    """One outcome per :func:`arith_sites` site of ``exprs`` over the
+    channel values ``env``: True = compiled without its guard."""
+    memo: Dict[int, AbstractValue] = {}
+    return tuple(site_proven(e, env, memo) for e in arith_sites(exprs))
+
+
+def proven_table(exprs: Sequence[Optional[Expr]],
+                 proven: Sequence[bool]) -> Dict[int, bool]:
+    """``id(site) -> outcome`` for the compiler, from a stage's
+    expressions and the ``proven`` tuple its lowering signed.  A tuple
+    of another length than the sites (no proof was made) proves
+    nothing."""
+    sites = list(arith_sites(exprs))
+    if len(sites) != len(proven):
+        return {}
+    return {id(e): bool(ok) for e, ok in zip(sites, proven)}
+
+
+#: a page has at most this many rows (row numbers are int32)
+MAX_PAGE_ROWS = 1 << 31
+#: a proof that holds only for pages under this many rows is not taken
+MIN_LANE_ROWS = 1 << 10
+
+
+def sum_lane_rows(v: AbstractValue) -> int:
+    """The page capacity up to which the sum of an int64-lane addend
+    with value ``v`` cannot wrap one int64 lane: the largest power of
+    two ``r <= MAX_PAGE_ROWS`` with ``max(|lo|, |hi|) * r`` inside
+    int64, or 0 (no proof).  A power of two so that domains that differ
+    and prove the same sign the same program."""
+    if not v.known:
+        return 0
+    m = max(abs(v.lo), abs(v.hi))
+    if m == INF:
+        return 0
+    r = MAX_PAGE_ROWS
+    while r >= MIN_LANE_ROWS and m * r > I64[1]:
+        r >>= 1
+    return r if r >= MIN_LANE_ROWS else 0

@@ -71,8 +71,42 @@ class MemoryConnector:
         self._bump_version(name)
 
     def append_pages(self, name: str, pages: Sequence[Page]) -> None:
-        self._tables[name].extend(_to_device(p) for p in pages)
+        pages = [_to_device(p) for p in pages]
+        # the domain first: it is true of every row that can be counted
+        # (docs/static-analysis.md, what a connector owes)
+        self._widen_domains(name, pages)
+        self._tables[name].extend(pages)
         self._bump_version(name)
+
+    def _widen_domains(self, name: str, pages: Sequence[Page]) -> None:
+        """A declared domain is a licence: the planner packs keys by
+        it and the executor drops an arithmetic guard or sums in one
+        lane where it proves that safe (docs/static-analysis.md).  So
+        an append keeps it true: each declared column's domain widens
+        to the new pages' observed min and max (live, non-NULL rows),
+        and a column that cannot be observed as one scalar lane loses
+        its domain."""
+        import numpy as np
+
+        doms = self._domains.get(name)
+        if not doms:
+            return
+        for i, (col, t) in enumerate(self._schemas[name]):
+            dom = doms.get(col)
+            if dom is None:
+                continue
+            if t.value_shape != () or t.is_raw_string:
+                doms[col] = None
+                continue
+            lo, hi = dom
+            for p in pages:
+                b = p.blocks[i]
+                live = np.asarray(p.row_mask) & np.asarray(b.valid)
+                if live.any():
+                    vals = np.asarray(b.data)[live]
+                    lo = min(lo, vals.min().tolist())
+                    hi = max(hi, vals.max().tolist())
+            doms[col] = (lo, hi)
 
     def drop_table(self, name: str) -> None:
         for d in (self._tables, self._schemas, self._domains, self._pks,

@@ -718,7 +718,27 @@ class Tpch:
         if t.is_string:
             return (0, len(self.dictionary_for(table, column)) - 1)
         max_orderkey = int(((self.n_orders - 1) >> 3) << 5 | ((self.n_orders - 1) & 7)) + 1
+        # Money, in cents (scale 2), each from the formula that
+        # generates it.  A declared domain is a licence: the executor
+        # drops an arithmetic guard and sizes a sum's accumulator by it
+        # (docs/static-analysis.md), so a bound here must hold for every
+        # row the generator can emit at any scale factor.
+        # _retail_price: 90000 + (k // 10) % 20001 + 100 * (k % 1000)
+        retail = (90000, 90000 + 20000 + 100 * 999)  # 209900
+        # l_extendedprice = qty * retail, qty uniform in [1, 50]
+        extprice = (retail[0], 50 * retail[1])  # 10,495,000
+        # o_totalprice = sum over an order's 1..7 lines
+        # (_lines_per_order) of ext * (100 + tax) * (100 - disc) // 10000,
+        # tax in [0, 8], disc in [0, 10]
+        totalprice = (extprice[0] * 100 * 90 // 10000,
+                      7 * (extprice[1] * 108 * 100 // 10000))
         doms: Dict[str, Tuple[int, int]] = {
+            "s_acctbal": (-99999, 999999),  # _uniform_int(-99999, 999999)
+            "c_acctbal": (-99999, 999999),  # the same draw
+            "p_retailprice": retail,
+            "ps_supplycost": (100, 100000),  # _uniform_int(100, 100000)
+            "o_totalprice": totalprice,
+            "l_extendedprice": extprice,
             "r_regionkey": (0, 4),
             "n_nationkey": (0, 24),
             "n_regionkey": (0, 4),

@@ -33,7 +33,9 @@ import jax
 import jax.numpy as jnp
 
 from presto_tpu.expr.ir import AggCall, Expr
-from presto_tpu.ops.aggregate import grouped_aggregate
+from presto_tpu.ops.aggregate import (
+    agg_exprs, grouped_aggregate, limb_sum_site, one_lane_sums,
+)
 from presto_tpu.ops.filter_project import (
     compact_page, filter_page, project_page,
 )
@@ -91,33 +93,52 @@ def cross_append_single(q: Page, r: Page) -> Page:
 # ---------------------------------------------------------------------------
 # the stage kinds: params, how a plan node gives them, the program
 # ---------------------------------------------------------------------------
-# ``of(node, max_groups)`` reads a member node; ``apply(page, consts,
-# build_key)`` is the stage over one page, under the operator's scope
-# (the host spans' names: what a device trace books the time to).
+# ``of(node, max_groups, env)`` reads a member node; ``apply(page,
+# consts, build_key)`` is the stage over one page, under the operator's
+# scope (the host spans' names: what a device trace books the time to).
+#
+# ``env`` is the proven interval of each channel of the stage's input
+# page (``analysis.kernel_soundness.channel_values`` of the node's
+# source; None: no proof is made).  A stage that compiles expressions
+# signs what the intervals PROVED, never their numbers: ``proven``, one
+# outcome per guarded arithmetic site in ``analysis.ranges.arith_sites``
+# order (True = compiled without its runtime guard; ``()`` = no proof
+# was made, every guard stays: the program before there were proofs).
+# Two tables whose domains differ and prove the same share a program.
+
+
+def _prove(exprs, env) -> Tuple[bool, ...]:
+    if env is None:
+        return ()
+    from presto_tpu.analysis.ranges import prove_sites
+
+    return prove_sites(exprs, env)
 
 
 class Filter(NamedTuple):
     predicate: Expr
+    proven: Tuple[bool, ...] = ()
 
     @classmethod
-    def of(cls, node, max_groups):
-        return cls(node.predicate)
+    def of(cls, node, max_groups, env=None):
+        return cls(node.predicate, _prove([node.predicate], env))
 
     def apply(self, page, consts, build_key):
         with jax.named_scope("op:Filter"):
-            return filter_page(page, self.predicate)
+            return filter_page(page, self.predicate, self.proven)
 
 
 class Project(NamedTuple):
     projections: Tuple[Expr, ...]
+    proven: Tuple[bool, ...] = ()
 
     @classmethod
-    def of(cls, node, max_groups):
-        return cls(tuple(node.projections))
+    def of(cls, node, max_groups, env=None):
+        return cls(tuple(node.projections), _prove(node.projections, env))
 
     def apply(self, page, consts, build_key):
         with jax.named_scope("op:Project"):
-            return project_page(page, list(self.projections))
+            return project_page(page, list(self.projections), self.proven)
 
 
 class AggPartial(NamedTuple):
@@ -126,12 +147,38 @@ class AggPartial(NamedTuple):
     max_groups: int  # resolved: key domains and capacity retries applied
     key_domains: tuple
     presorted: bool
+    proven: Tuple[bool, ...] = ()
+    #: per aggregate: None = no limb sum of a short addend there; else
+    #: what the addend's interval proves of a page's sum: the page
+    #: capacity (a power of two, ``ranges.sum_lane_rows``) up to which
+    #: it stays inside one int64 lane, 0 = no proof.  A capacity, since
+    #: a page's is not known before it arrives; what the aggregation
+    #: does with it is ``ops/aggregate.one_lane_sums``
+    lane_rows: Tuple[Optional[int], ...] = ()
 
     @classmethod
-    def of(cls, node, max_groups):
+    def of(cls, node, max_groups, env=None):
+        proven, lane_rows = (), ()
+        if env is not None:
+            from presto_tpu.analysis.ranges import eval_expr, sum_lane_rows
+
+            proven = _prove(agg_exprs(node.group_exprs, node.aggs), env)
+            lanes = tuple(
+                sum_lane_rows(eval_expr(a.arg, env))
+                if limb_sum_site(a) else None for a in node.aggs)
+            if any(r is not None for r in lanes):
+                lane_rows = lanes
         return cls(tuple(node.group_exprs), tuple(node.aggs),
                    max_groups(node), tuple(node.key_domains),
-                   bool(node.presorted))
+                   bool(node.presorted), proven, lane_rows)
+
+    def sums(self) -> Tuple[bool, ...]:
+        """One outcome per limb sum of a short addend: True = one int64
+        lane for every page its proof covers (``lane_rows``)."""
+        one = one_lane_sums(self.aggs, self.lane_rows,
+                            self.max_groups if self.group_exprs else 1)
+        return tuple(o for o, r in zip(one, self.lane_rows)
+                     if r is not None)
 
     def apply(self, page, consts, build_key):
         with jax.named_scope("op:Aggregation"):
@@ -139,6 +186,7 @@ class AggPartial(NamedTuple):
                 page, list(self.group_exprs), list(self.aggs),
                 self.max_groups, key_domains=list(self.key_domains),
                 mode="partial", presorted=self.presorted,
+                proven=self.proven, lane_rows=self.lane_rows,
             )
 
 
@@ -151,7 +199,7 @@ class Probe(NamedTuple):
     build_arity: int
 
     @classmethod
-    def of(cls, node, max_groups):
+    def of(cls, node, max_groups, env=None):
         return cls(tuple(node.left_keys), tuple(node.key_domains or ()),
                    node.kind, node.null_safe_keys,
                    getattr(node, "null_aware", False),
@@ -181,7 +229,7 @@ class Lookup(NamedTuple):
     null_safe: bool
 
     @classmethod
-    def of(cls, node, max_groups):
+    def of(cls, node, max_groups, env=None):
         return cls(tuple(node.left_keys), tuple(node.key_domains or ()),
                    node.null_safe_keys)
 
@@ -202,7 +250,7 @@ class Fetch(NamedTuple):
     build_arity: int
 
     @classmethod
-    def of(cls, node, max_groups):
+    def of(cls, node, max_groups, env=None):
         return cls(len(node.right.channels))
 
     def apply(self, page, consts, build_key):
@@ -216,7 +264,7 @@ class Fetch(NamedTuple):
 
 class Cross1(NamedTuple):
     @classmethod
-    def of(cls, node, max_groups):
+    def of(cls, node, max_groups, env=None):
         return cls()
 
     def apply(self, page, consts, build_key):
@@ -309,6 +357,24 @@ class Chain:
     def compacts(self) -> bool:
         return any(s.kind == "compact" for s in self.stages)
 
+    def arith_counts(self) -> Tuple[int, int]:
+        """(checked, proven): the guarded arithmetic sites and the limb
+        sums of short addends this chain compiles with and without
+        their runtime guard (a sum's guard is its row-by-row limb
+        split).  A sum counts as proven for the pages its proof covers
+        (``AggPartial.lane_rows``, 2^26 rows for TPC-H q1's widest): a
+        larger page would split row by row all the same.  A stage
+        lowered without intervals counts nothing: it made no proof."""
+        checked = proven = 0
+        for s in self.stages:
+            p = s.params
+            sites = list(getattr(p, "proven", ()))
+            if isinstance(p, AggPartial):
+                sites += p.sums()
+            proven += sum(sites)
+            checked += len(sites) - sum(sites)
+        return checked, proven
+
     def signature(self, upto: Optional[int] = None) -> tuple:
         """What the program of the first ``upto`` stages (all of them:
         None) depends on, leaf first."""
@@ -378,7 +444,9 @@ class Chain:
 
 def lower_chain(root: PlanNode, *, max_groups: Callable[[AggregationNode], int],
                 streaming: Callable[[JoinNode], bool] = streams,
-                compact_k: Optional[int] = None) -> Chain:
+                compact_k: Optional[int] = None,
+                intervals: Optional[Callable[[PlanNode], list]] = None
+                ) -> Chain:
     """The chain rooted at ``root``.  ``streaming`` says which joins
     probe inside a chain and ``max_groups`` resolves a partial
     aggregation's capacity: what ``LocalRunner._streaming`` (a join
@@ -386,7 +454,11 @@ def lower_chain(root: PlanNode, *, max_groups: Callable[[AggregationNode], int],
     (a capacity retry raises it) answer.  ``compact_k`` sets the
     compaction's k, at the place the estimates chose, instead of
     theirs (0: never compact), for tests, for a chain whose compaction
-    missed and for callers that take pages only."""
+    missed and for callers that take pages only.  ``intervals(node)``
+    gives the proven interval of each output channel of a plan node
+    (``analysis.kernel_soundness.channel_values`` under the caller's
+    memo); a stage is handed its source's, and without it no stage
+    proves anything (every guard stays)."""
     stages: List[Stage] = []
     node = root
     while True:
@@ -394,7 +466,8 @@ def lower_chain(root: PlanNode, *, max_groups: Callable[[AggregationNode], int],
         if member is None:
             break
         kind, source = member
-        stages.append(Stage(kind, KINDS[kind].of(node, max_groups), node))
+        env = intervals(source) if intervals is not None else None
+        stages.append(Stage(kind, KINDS[kind].of(node, max_groups, env), node))
         node = source
     stages.reverse()
     at = _compact_at(node, stages, compact_k)

@@ -22,6 +22,7 @@ LookupJoinPageBuilder).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
@@ -351,7 +352,8 @@ def _named(f, name: str):
 # this thread's counts, never reset: .n host reads; .compacted and
 # .fallback pages of compacting chains (``compact_counts``);
 # .expand_retries and .expanded_rows of expanding probes
-# (``expand_counts``)
+# (``expand_counts``); .arith_checked and .arith_proven sites of the
+# chains lowered (``arith_counts``)
 _HOST_READS = threading.local()
 
 
@@ -379,6 +381,17 @@ def expand_counts() -> Tuple[int, int]:
     differences across it, like ``host_reads``."""
     return (getattr(_HOST_READS, "expand_retries", 0),
             getattr(_HOST_READS, "expanded_rows", 0))
+
+
+def arith_counts() -> Tuple[int, int]:
+    """(checked, proven): the guarded arithmetic sites and limb sums
+    that the chains this thread has run so far compiled with and
+    without their runtime guard (``Chain.arith_counts``, known on the
+    host when ``_chain_pages`` lowers a chain, whether or not its
+    program was already compiled); a query's counts are the
+    differences across it, like ``host_reads``."""
+    return (getattr(_HOST_READS, "arith_checked", 0),
+            getattr(_HOST_READS, "arith_proven", 0))
 
 
 def host_read(x, why: str):
@@ -664,6 +677,9 @@ class LocalRunner:
         # before pulling a chain; the TOP-level chain takes completion-
         # order delivery, nested chains (join builds) stay ordered
         self._unordered_tls = _threading.local()
+        # the evidence this thread's query proves its chains under:
+        # (EvidenceContext, channel_values memo), ``_proving``
+        self._evidence_tls = _threading.local()
 
     # ------------------------------------------------------------------
     def run(self, plan: PlanNode, query_id: Optional[str] = None) -> MaterializedResult:
@@ -702,7 +718,8 @@ class LocalRunner:
                 self._mem = QueryMemoryContext(
                     self.memory_pool, query_id or uuid.uuid4().hex[:8])
             try:
-                yield
+                with self._proving():
+                    yield
             finally:
                 self.last_task_stats = self._task_stats.as_dict()
                 if self._mem is not None:
@@ -934,8 +951,67 @@ class LocalRunner:
         compactions."""
         if node in self._no_compact:
             compact_k = 0
+        proving = getattr(self._evidence_tls, "state", None) is not None
         return lower_chain(node, streaming=self._streaming,
-                           max_groups=self._max_groups, compact_k=compact_k)
+                           max_groups=self._max_groups, compact_k=compact_k,
+                           intervals=self._intervals if proving else None)
+
+    @contextlib.contextmanager
+    def _proving(self):
+        """The scope chains are proved in: one query on this thread.
+
+        Inside, ``_intervals`` answers from one ``kernel_soundness.
+        EvidenceContext`` and one memo (both key on ``id(node)``, which
+        is only stable while the query's plan is alive), so a plan is
+        walked once however many chains it lowers, every chain of the
+        query sees a table in ONE state (domains and split count read
+        together, the first time a chain asks), and ``_source_pages``
+        runs each scan over the splits counted then.  Outside it (a
+        worker's fragment pulled through ``_pages``) nothing is
+        proved and every guard stays, as in the mesh and HTTP tiers."""
+        from presto_tpu.analysis.kernel_soundness import EvidenceContext
+
+        outer = getattr(self._evidence_tls, "state", None)
+        self._evidence_tls.state = (EvidenceContext(self.catalog), {})
+        try:
+            yield
+        finally:
+            self._evidence_tls.state = outer
+
+    def _intervals(self, node: PlanNode) -> list:
+        """The proven interval of each output channel of ``node``
+        (``analysis.kernel_soundness.channel_values``) under this
+        thread's ``_proving`` scope."""
+        from presto_tpu.analysis.kernel_soundness import channel_values
+
+        return channel_values(node, *self._evidence_tls.state)
+
+    def _evidence_leaf(self, leaf: PlanNode) -> PlanNode:
+        """``leaf`` for ``_source_pages``: inside a ``_proving`` scope
+        a scan is held to the splits counted when its domains were
+        read (``EvidenceContext.splits``; read now if no chain has
+        asked yet, so that one that asks later cannot be proved by a
+        younger table than was scanned): a row appended since is the
+        next statement's.  Any other leaf as it is.  Asked on the
+        query's thread: the scheduler's producer has no scope."""
+        state = getattr(self._evidence_tls, "state", None)
+        if state is None or not isinstance(leaf, TableScanNode):
+            return leaf
+        state[0].channels(leaf)
+        n = state[0].splits.get(id(leaf))
+        if n is None:
+            return leaf
+        return dataclasses.replace(leaf, splits=list(range(n)))
+
+    def arith_report(self, plan: PlanNode) -> List[str]:
+        """EXPLAIN (TYPE VALIDATE)'s ``arithmetic:`` block: what this
+        runner's chains would prove of ``plan`` now."""
+        from presto_tpu.analysis.kernel_soundness import (
+            EvidenceContext, arith_report,
+        )
+
+        return arith_report(plan, EvidenceContext(self.catalog),
+                            self._max_groups)
 
     def _exclusive_times(self, plan: PlanNode) -> Dict[PlanNode, float]:
         out: Dict[PlanNode, float] = {}
@@ -962,7 +1038,8 @@ class LocalRunner:
             for s in chain.leaf.sources:
                 walk(s)
 
-        walk(plan)
+        with self._proving():  # the guards the query's programs had
+            walk(plan)
         return out
 
     def _time_chain(self, chain: Chain, out: Dict[PlanNode, float]) -> None:
@@ -1281,8 +1358,14 @@ class LocalRunner:
             yield from self._pages_impl(node)
             return
         fn = self._chain_program(chain)
+        checked, proven = chain.arith_counts()
+        _HOST_READS.arith_checked = getattr(
+            _HOST_READS, "arith_checked", 0) + checked
+        _HOST_READS.arith_proven = getattr(
+            _HOST_READS, "arith_proven", 0) + proven
+        leaf = self._evidence_leaf(chain.leaf)
         if not chain.compacts:
-            yield from self._chain_outputs(chain.leaf, fn, consts, unordered)
+            yield from self._chain_outputs(leaf, fn, consts, unordered)
             return
         # A compacting program answers for the rows that fitted its
         # small page and says whether all did.  Each page goes to the
@@ -1294,8 +1377,7 @@ class LocalRunner:
         from presto_tpu.obs import METRICS
 
         flags = []
-        for page, over in self._chain_outputs(chain.leaf, fn, consts,
-                                              unordered):
+        for page, over in self._chain_outputs(leaf, fn, consts, unordered):
             flags.append(over)
             yield page
         fitted = not any(host_read(flags, "compact_taken"))
@@ -1412,7 +1494,9 @@ class LocalRunner:
             # split enumeration happens at EXECUTION time, not plan time
             # (DistributedExecutionPlanner opens SplitSources during
             # planDistribution, so cached plans see connector-side
-            # changes — e.g. shardstore compaction/rebalance)
+            # changes — e.g. shardstore compaction/rebalance); a scan
+            # whose domains a chain was proved by comes with the splits
+            # counted then (``_evidence_leaf``)
             if node.splits is not None:
                 splits = node.splits
             else:

@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import fnmatch
 import re
-from typing import Callable, Optional, Sequence, Tuple
+from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -907,13 +907,29 @@ class ExprCompiler:
     """Compiles expressions against a fixed input schema (types +
     dictionaries), mirroring how the reference compiles per plan node."""
 
-    def __init__(self, input_types: Sequence[Type], dictionaries: Sequence[Optional[Dictionary]]):
+    def __init__(self, input_types: Sequence[Type],
+                 dictionaries: Sequence[Optional[Dictionary]],
+                 proven: Optional[Dict[int, bool]] = None):
+        """``proven``: ``id(site) -> bool`` for the guarded arithmetic
+        calls of the expressions to compile (``analysis.ranges.
+        proven_table``: what the plan's intervals proved, as a chain's
+        stage signed it at lowering, so that the program is a function
+        of its signature and not of an interval's numbers).  A site
+        proved compiles without its runtime guard; absent, every guard
+        stays."""
         self.input_types = list(input_types)
         self.dictionaries = list(dictionaries)
+        self._proven = proven or {}
 
     @classmethod
-    def for_page(cls, page: Page) -> "ExprCompiler":
-        return cls([b.type for b in page.blocks], [b.dictionary for b in page.blocks])
+    def for_page(cls, page: Page, proven=None) -> "ExprCompiler":
+        return cls([b.type for b in page.blocks],
+                   [b.dictionary for b in page.blocks], proven=proven)
+
+    def _guard_free(self, expr: Call) -> bool:
+        """True where the interval analysis proved that no runtime
+        guard of the arithmetic call ``expr`` can fire."""
+        return self._proven.get(id(expr), False)
 
     # ------------------------------------------------------------------
     def compile(self, expr: Expr) -> CompiledExpr:
@@ -1011,9 +1027,13 @@ class ExprCompiler:
 
                 return lambda page: ((lambda dv: (d128.neg(dv[0]), dv[1]))(a(page)))
 
+            free = self._guard_free(expr)
+            lane = expr.type.np_dtype
+
             def run_neg(page):
                 d, v = a(page)
-                if jnp.issubdtype(d.dtype, jnp.integer):
+                if jnp.issubdtype(d.dtype, jnp.integer) \
+                        and not (free and d.dtype == lane):
                     # -INT_MIN wraps in place; NULL that lane (deviation:
                     # the reference raises ARITHMETIC_OVERFLOW)
                     v = v & jnp.logical_not(_ovf_neg(d))
@@ -2998,6 +3018,9 @@ class ExprCompiler:
         a, b = self.compile(lhs), self.compile(rhs)
         ta, tb, tr = lhs.type, rhs.type, expr.type
         op = expr.fn
+        # proven by the plan's intervals: no guard of this call can
+        # fire, so none is emitted (analysis/ranges.site_proven)
+        free = self._guard_free(expr)
 
         def run_arith(page):
             (da, va), (db, vb) = a(page), b(page)
@@ -3012,7 +3035,7 @@ class ExprCompiler:
                     "div": lambda: da2 / jnp.where(db2 == 0, 1.0, db2),
                     "mod": lambda: jnp.mod(da2, jnp.where(db2 == 0, 1.0, db2)),
                 }[op]()
-                if op in ("div", "mod"):
+                if op in ("div", "mod") and not free:
                     valid = valid & (db2 != 0)
                 return d, valid
             if tr.name == "double":
@@ -3024,7 +3047,7 @@ class ExprCompiler:
                     "div": lambda: da2 / jnp.where(db2 == 0, 1.0, db2),
                     "mod": lambda: jnp.mod(da2, jnp.where(db2 == 0, 1.0, db2)),
                 }[op]()
-                if op in ("div", "mod"):
+                if op in ("div", "mod") and not free:
                     valid = valid & (db2 != 0)
                 return d, valid
             if tr.is_long_decimal:
@@ -3057,16 +3080,20 @@ class ExprCompiler:
                 db2 = db.astype(jnp.int64)
                 if op == "mul":
                     d = da2 * db2  # scale sa+sb == tr.scale
-                    valid = valid & jnp.logical_not(_ovf_mul(da2, db2, d))
+                    if not free:
+                        valid = valid & jnp.logical_not(_ovf_mul(da2, db2, d))
                 else:
                     da2, oa = _rescale_guard(da2, sa, tr.scale)
                     db2, ob = _rescale_guard(db2, sb, tr.scale)
-                    valid = valid & jnp.logical_not(oa | ob)
+                    if not free:
+                        valid = valid & jnp.logical_not(oa | ob)
                     d = {
                         "add": lambda: da2 + db2,
                         "sub": lambda: da2 - db2,
                         "mod": lambda: _trunc_mod(da2, db2),
                     }[op]()
+                    if free:
+                        return d, valid
                     if op == "add":
                         valid = valid & jnp.logical_not(_ovf_add(da2, db2, d))
                     elif op == "sub":
@@ -3083,6 +3110,11 @@ class ExprCompiler:
                 "div": lambda: _trunc_div(da, db),
                 "mod": lambda: _trunc_mod(da, db),
             }[op]()
+            if free and d.dtype == tr.np_dtype:
+                # the proof is of the declared lane (``site_proven``
+                # refuses operand types that promote to another; this
+                # is the same for the lanes as compiled)
+                return d, valid
             if op == "add":
                 valid = valid & jnp.logical_not(_ovf_add(da, db, d))
             elif op == "sub":
@@ -3558,18 +3590,33 @@ def _days_from_civil(y: jax.Array, m: jax.Array, d: jax.Array) -> jax.Array:
 
 # -- module-level helpers ----------------------------------------------------
 
-def compile_expr(expr: Expr, page_or_types, dictionaries=None) -> CompiledExpr:
+def proven_sites(exprs: Sequence[Optional[Expr]],
+                 proven: Optional[Sequence[bool]]) -> Optional[dict]:
+    """``ExprCompiler``'s ``proven`` table for the expressions a stage
+    compiles and the outcomes its lowering signed
+    (``analysis.ranges.proven_table``); None where nothing was proved."""
+    if not proven:
+        return None
+    from presto_tpu.analysis.ranges import proven_table
+
+    return proven_table(exprs, proven)
+
+
+def compile_expr(expr: Expr, page_or_types, dictionaries=None,
+                 proven=None) -> CompiledExpr:
     if isinstance(page_or_types, Page):
-        c = ExprCompiler.for_page(page_or_types)
+        c = ExprCompiler.for_page(page_or_types, proven=proven)
     else:
-        c = ExprCompiler(page_or_types, dictionaries or [None] * len(page_or_types))
+        c = ExprCompiler(page_or_types,
+                         dictionaries or [None] * len(page_or_types),
+                         proven=proven)
     return c.compile(expr)
 
 
-def compile_filter(expr: Expr, page_or_types, dictionaries=None):
+def compile_filter(expr: Expr, page_or_types, dictionaries=None, proven=None):
     """Compile a predicate to ``page -> bool mask`` (NULL -> excluded),
     the PageFilter analog."""
-    f = compile_expr(expr, page_or_types, dictionaries)
+    f = compile_expr(expr, page_or_types, dictionaries, proven)
 
     def run(page: Page) -> jax.Array:
         d, v = f(page)

@@ -43,7 +43,7 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from presto_tpu.expr.compile import ExprCompiler
+from presto_tpu.expr.compile import ExprCompiler, proven_sites
 from presto_tpu.expr.ir import AggCall, Expr
 from presto_tpu.page import Block, Page
 from presto_tpu.types import BIGINT, DOUBLE, VARCHAR, DecimalType, Type
@@ -386,17 +386,69 @@ def _seg_assoc(op, identity, vals, gid, n):
     return jnp.where(present, scanned[ends], identity)
 
 
+def agg_exprs(group_exprs: Sequence[Expr],
+              aggs: Sequence[AggCall]) -> List[Optional[Expr]]:
+    """Every expression a (partial) aggregation compiles, in THE order
+    its ``proven`` outcomes are signed in (``analysis.ranges.
+    arith_sites`` walks them): the keys, then each aggregate's
+    arguments and filter."""
+    out: List[Optional[Expr]] = list(group_exprs)
+    for a in aggs:
+        out += [a.arg, a.arg2, a.arg3, a.filter]
+    return out
+
+
+def limb_sum_site(agg: AggCall) -> bool:
+    """True for the sums a proof can move: a short (int64-lane) addend
+    whose state is limbs, split row by row unless its interval says the
+    page's sum fits one lane (``_partial_states``)."""
+    return (agg.fn in ("sum", "sum0", "avg") and agg.arg is not None
+            and not agg.arg.type.is_long_decimal
+            and _sum_type(agg.arg.type).is_long_decimal)
+
+
+def one_lane_sums(aggs: Sequence[AggCall],
+                  lane_rows: Optional[Sequence[Optional[int]]],
+                  n_groups: int,
+                  capacity: Optional[int] = None) -> Tuple[bool, ...]:
+    """Per aggregate, whether its limb sum reduces in ONE int64 lane
+    and lifts the group sums to limbs afterwards, instead of splitting
+    every row: THE rule, for ``grouped_aggregate`` and for whoever
+    counts its outcome (``exec/chain.Chain.arith_counts``).
+
+    ``lane_rows[i]`` is what the plan's intervals proved of aggregate
+    ``i``'s addend: the page capacity (``analysis.ranges.
+    sum_lane_rows``) up to which the page's sum stays inside int64; 0
+    or None = no proof.  One lane where that covers the page
+    (``capacity``; None: any page the proof covers) and the groups are
+    few enough for the masked reduce, where it was measured (PERF.md,
+    PR 36: 16-20 ms a 2^23-row page saved).  Over sorted runs
+    (``_SortCtx.sum``) the one-lane form compiled to more gather
+    fusions than the limbs and q3 lost 4 ms a page: limbs there."""
+    if not lane_rows or n_groups + 1 > SMALL_SEG_LIMIT:
+        return (False,) * len(aggs)
+    return tuple(
+        bool(limb_sum_site(a) and r
+             and (capacity is None or capacity <= r))
+        for a, r in zip(aggs, lane_rows))
+
+
 @jax.named_scope("agg:reduce")
 def _partial_states(page: Page, aggs: Sequence[AggCall], gid: jax.Array, n: int,
-                    ctx: "Optional[_SortCtx]" = None):
+                    ctx: "Optional[_SortCtx]" = None,
+                    c: Optional[ExprCompiler] = None,
+                    one_lane: Sequence[bool] = ()):
     """Compute per-group state columns for each aggregate.
 
     gid must already be ``n`` for dead rows (dropped by segment ops via
-    an extra slot)."""
-    c = ExprCompiler.for_page(page)
+    an extra slot).  ``one_lane[i]``: aggregate ``i``'s limb sum is
+    proven to fit one int64 lane over this page (``one_lane_sums``);
+    absent: limbs row by row."""
+    if c is None:
+        c = ExprCompiler.for_page(page)
     out: List[List[jax.Array]] = []
     live = page.row_mask
-    for agg in aggs:
+    for i, agg in enumerate(aggs):
         if agg.filter is not None:
             fd, fv = c.compile(agg.filter)(page)
             rowsel = live & fd & fv
@@ -436,6 +488,15 @@ def _partial_states(page: Page, aggs: Sequence[AggCall], gid: jax.Array, n: int,
                 and _sum_type(agg.arg.type).is_long_decimal:
             from presto_tpu.ops import decimal128 as d128
 
+            if one_lane and one_lane[i]:
+                # |addend| * capacity is proven inside int64: reduce in
+                # one lane and lift the n group sums to the limb state,
+                # not every row (the same integer, the same canonical
+                # limbs)
+                vals = jnp.where(nonnull, data.astype(jnp.int64), 0)
+                s = d128.from_int64(_gsum(ctx, vals, gid_nn, n))
+                out.append([s, cnt])
+                continue
             # covers short p>15 args too: their scaled-int64 lanes lift
             # to two-limb rows first, then the same base-1e9 digit fold
             if not agg.arg.type.is_long_decimal:
@@ -1873,10 +1934,19 @@ def grouped_aggregate(
     mode: str = "single",
     return_count: bool = False,
     presorted: bool = False,
+    proven: Optional[Sequence[bool]] = None,
+    lane_rows: Optional[Sequence[Optional[int]]] = None,
 ) -> Page:
     """Aggregate ``page`` by ``group_exprs``.  With ``presorted=True``
     the input is promised to arrive with equal group keys contiguous
     (streaming aggregation) and grouping skips the argsort.
+
+    ``proven`` and ``lane_rows`` are what the plan's intervals proved
+    (``exec/chain.AggPartial``): one outcome per guarded arithmetic
+    site of ``agg_exprs(group_exprs, aggs)``, and per aggregate the
+    page capacity up to which its sum fits one int64 lane
+    (``one_lane_sums`` decides with it).  Absent: every guard, and
+    per-row limbs.
 
     mode='single' emits finalized values; 'partial' emits state columns
     (for exchange + merge_aggregate).
@@ -1892,7 +1962,10 @@ def grouped_aggregate(
     with a larger capacity (the reference instead rehashes:
     MultiChannelGroupByHash.java:138-145 tryRehash).
     """
-    c = ExprCompiler.for_page(page)
+    c = ExprCompiler.for_page(page, proven=proven_sites(
+        agg_exprs(group_exprs, aggs), proven))
+    one_lane = one_lane_sums(aggs, lane_rows,
+                             max_groups if group_exprs else 1, page.capacity)
     kd = [c.compile(e)(page) for e in group_exprs]
     key_dicts = expr_key_dicts(page, group_exprs)
     datas = canonicalize_codes([d for d, _ in kd], key_dicts)
@@ -1906,7 +1979,7 @@ def grouped_aggregate(
     if not group_exprs:
         # global aggregation: one group
         gid = jnp.where(live, 0, 1)
-        states = _partial_states(page, aggs, gid, 1)
+        states = _partial_states(page, aggs, gid, 1, c=c, one_lane=one_lane)
         key_blocks: List[Block] = []
         out_mask = jnp.ones(1, dtype=jnp.bool_)
         out = _emit(key_blocks, states, aggs, out_mask, mode, group_exprs, key_dicts, agg_dicts)
@@ -1917,7 +1990,8 @@ def grouped_aggregate(
     if presorted:
         # streaming path: run boundaries from the input order itself
         gid, num_groups, rep_rows, ctx = _presorted_group_ids(key, live, max_groups)
-        states = _partial_states(page, aggs, gid, max_groups, ctx=ctx)
+        states = _partial_states(page, aggs, gid, max_groups, ctx=ctx, c=c,
+                                 one_lane=one_lane)
         key_blocks = []
         for (d, v), e, dic in zip(kd, group_exprs, key_dicts):
             key_blocks.append(Block(d[rep_rows].astype(e.type.np_dtype),
@@ -1937,7 +2011,8 @@ def grouped_aggregate(
             prod *= card
         if prod <= min(max_groups, DIRECT_GROUP_LIMIT):
             gid = jnp.where(live, key, max_groups)
-            states = _partial_states(page, aggs, gid, max_groups)
+            states = _partial_states(page, aggs, gid, max_groups, c=c,
+                                     one_lane=one_lane)
             present = _seg_sum(live.astype(jnp.int64), gid, max_groups + 1)[:max_groups] > 0
             key_blocks = _unpack_key_blocks(
                 cards, key_domains, group_exprs, key_dicts, prod, max_groups
@@ -1953,7 +2028,8 @@ def grouped_aggregate(
     max_groups = min(max_groups, page.capacity)
     gid, num_groups, rep_rows, ctx = _sorted_group_ids(
         key, live, max_groups, want_ctx=True)
-    states = _partial_states(page, aggs, gid, max_groups, ctx=ctx)
+    states = _partial_states(page, aggs, gid, max_groups, ctx=ctx, c=c,
+                             one_lane=one_lane)
     key_blocks = []
     for (d, v), e, dic in zip(kd, group_exprs, key_dicts):
         kb_data = d[rep_rows].astype(e.type.np_dtype)

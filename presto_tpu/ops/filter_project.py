@@ -19,26 +19,36 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from presto_tpu.expr.compile import ExprCompiler, compile_filter
+from presto_tpu.expr.compile import (
+    ExprCompiler, compile_filter, proven_sites,
+)
 from presto_tpu.expr.ir import Expr
 from presto_tpu.page import Block, Page
 
 
-def filter_page(page: Page, predicate: Expr) -> Page:
-    """Rows where predicate is not TRUE (false or NULL) are masked out."""
-    return Page(page.blocks, compile_filter(predicate, page)(page))
+def filter_page(page: Page, predicate: Expr,
+                proven: Optional[Sequence[bool]] = None) -> Page:
+    """Rows where predicate is not TRUE (false or NULL) are masked out.
+    ``proven``: one outcome per guarded arithmetic site of the
+    predicate (``analysis.ranges.arith_sites`` order; True = the plan's
+    intervals proved the guard away), None = every guard stays."""
+    return Page(page.blocks, compile_filter(
+        predicate, page, proven=proven_sites([predicate], proven))(page))
 
 
-def project_page(page: Page, projections: Sequence[Expr]) -> Page:
+def project_page(page: Page, projections: Sequence[Expr],
+                 proven: Optional[Sequence[bool]] = None) -> Page:
     """Produce a new Page with one block per projection expression.
 
     Dictionary provenance: a projection that is a bare ColumnRef keeps
     the source block's dictionary (dictionary-aware projection,
-    DictionaryAwarePageProjection.java analog).
+    DictionaryAwarePageProjection.java analog).  ``proven`` as
+    ``filter_page``'s, over all projections in order.
     """
     from presto_tpu.expr.compile import expr_dictionary
 
-    c = ExprCompiler.for_page(page)
+    c = ExprCompiler.for_page(
+        page, proven=proven_sites(list(projections), proven))
     dicts = [b.dictionary for b in page.blocks]
     blocks: List[Block] = []
     for e in projections:

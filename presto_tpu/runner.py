@@ -14,8 +14,8 @@ from typing import Optional
 
 from presto_tpu.catalog import Catalog
 from presto_tpu.exec.local import (
-    LocalRunner, MaterializedResult, QueryStats, compact_counts, expand_counts,
-    host_reads,
+    LocalRunner, MaterializedResult, QueryStats, arith_counts, compact_counts,
+    expand_counts, host_reads,
 )
 from presto_tpu.session import Session
 from presto_tpu.sql import ast
@@ -246,6 +246,7 @@ class QueryRunner:
                     reads0 = host_reads()
                     compact0 = compact_counts()
                     expand0 = expand_counts()
+                    arith0 = arith_counts()
                     with obs.span("execute", cat="lifecycle"):
                         res = None
                         if prepared is not None:
@@ -314,6 +315,11 @@ class QueryRunner:
             # the rows expanding probes emitted (_probe_with_retry)
             res.expand_retries, res.expanded_rows = (
                 n - n0 for n, n0 in zip(expand_counts(), expand0))
+            # guarded arithmetic sites and limb sums the statement's
+            # chains compiled with / without their runtime guard
+            # (Chain.arith_counts; the plan's intervals decide)
+            res.arith_checked, res.arith_proven = (
+                n - n0 for n, n0 in zip(arith_counts(), arith0))
             # serving-tier surfaces: whether this result came from the
             # structural cache, and the executor's observed peak bytes
             # (the admission controller's projection source for the
@@ -404,6 +410,11 @@ class QueryRunner:
                 assert_kernel_sound(plan)
                 report = getattr(plan, "_optimizer_report", None)
                 summary = report.summary() if report else "optimizer: n/a"
+                # which arithmetic the plan's intervals let the chains
+                # compile without its runtime guard, site by site
+                sites = self.executor.arith_report(plan)
+                if sites:
+                    summary += "\narithmetic:\n  " + "\n  ".join(sites)
                 return MaterializedResult(
                     ["Valid", "Optimizer"], [BOOLEAN, VARCHAR],
                     [(True, summary)])

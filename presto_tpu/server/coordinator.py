@@ -204,6 +204,8 @@ class _QueryState:
         self.compact_fallback_pages: Optional[int] = None
         self.expand_retries: Optional[int] = None
         self.expanded_rows: Optional[int] = None
+        self.arith_checked: Optional[int] = None
+        self.arith_proven: Optional[int] = None
         # client-supplied request correlation (X-Presto-Trace-Token)
         self.trace_token: Optional[str] = None
         # deadline bookkeeping: the effective limit (None = none) and
@@ -822,6 +824,8 @@ class CoordinatorServer:
                     res, "compact_fallback_pages", None)
                 q.expand_retries = getattr(res, "expand_retries", None)
                 q.expanded_rows = getattr(res, "expanded_rows", None)
+                q.arith_checked = getattr(res, "arith_checked", None)
+                q.arith_proven = getattr(res, "arith_proven", None)
                 q.cache_hit = getattr(res, "cache_hit", None)
                 q.queued_ms = getattr(res, "queued_ms", None)
                 q.memory_blocked_ms = getattr(res, "memory_blocked_ms",
@@ -921,6 +925,9 @@ class CoordinatorServer:
         if q.expand_retries is not None:
             out["stats"]["expandRetries"] = q.expand_retries
             out["stats"]["expandedRows"] = q.expanded_rows
+        if q.arith_checked is not None:
+            out["stats"]["arithChecked"] = q.arith_checked
+            out["stats"]["arithProven"] = q.arith_proven
         # serving tier: result provenance (structural result cache)
         if q.cache_hit is not None:
             out["stats"]["cacheHit"] = q.cache_hit

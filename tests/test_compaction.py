@@ -6,6 +6,8 @@ answers as the chain that does not, whether its pages fit or the
 aggregation over it has to start again; chains the gate leaves alone
 build the program they always built."""
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -381,7 +383,10 @@ def test_only_an_inner_probe_of_a_unique_build_compacts_inside(env, changes):
         assert ex._lower(other).leaf is other.source  # a breaker now
 
 
-def test_a_filter_in_front_wins_and_the_program_is_the_plain_composition(env):
+@pytest.mark.parametrize("proving", [True, False],
+                         ids=["as_the_executor_runs_it", "without_intervals"])
+def test_a_filter_in_front_wins_and_the_program_is_the_plain_composition(
+        env, proving):
     """q14's chain has both: a filter in front of its probe that
     qualifies, and an inner probe of a unique build.  It compacts in
     front, once, and its program is what it was before a chain could
@@ -394,15 +399,21 @@ def test_a_filter_in_front_wins_and_the_program_is_the_plain_composition(env):
     runner, _ = env
     ex = runner.executor
     root = _chain_root(runner, QUERIES[14])
-    lowered = ex._lower(root)
+    # the chain the executor runs, proved in a query's scope (PR 36),
+    # with what its stages signed handed to the operators by hand; and
+    # the chain lowered without the plan's intervals, which proves
+    # nothing: the program from before there were proofs
+    with ex._proving() if proving else contextlib.nullcontext():
+        lowered = ex._lower(root)
     assert [s.kind for s in lowered.stages] == [
         "filter", "compact", "probe", "agg_partial"]
+    assert lowered.arith_counts() == ((0, 6) if proving else (0, 0))
     assert lowered.stages[2].params.kind == "inner"
     flt, cmp_, probe, agg = (s.params for s in lowered.stages)
 
     def by_hand(page, consts):
         with jax.named_scope("op:Filter"):
-            page = filter_page(page, flt.predicate)
+            page = filter_page(page, flt.predicate, flt.proven)
         with jax.named_scope("op:Filter"):
             page, live = compact_page(page, page.capacity >> cmp_.k)
         cap_out = page.capacity
@@ -416,7 +427,8 @@ def test_a_filter_in_front_wins_and_the_program_is_the_plain_composition(env):
             page = grouped_aggregate(
                 page, list(agg.group_exprs), list(agg.aggs), agg.max_groups,
                 key_domains=list(agg.key_domains), mode="partial",
-                presorted=agg.presorted)
+                presorted=agg.presorted, proven=agg.proven,
+                lane_rows=agg.lane_rows)
         return page, live > cap_out
 
     consts = {"build_0": ex._materialize_build(lowered.joins[0])}
