@@ -94,8 +94,13 @@ def cross_append_single(q: Page, r: Page) -> Page:
 # the stage kinds: params, how a plan node gives them, the program
 # ---------------------------------------------------------------------------
 # ``of(node, max_groups, env)`` reads a member node; ``apply(page,
-# consts, build_key)`` is the stage over one page, under the operator's
-# scope (the host spans' names: what a device trace books the time to).
+# consts, build_key, probe)`` is the stage over one page, under the
+# operator's scope (the host spans' names: what a device trace books
+# the time to).  ``probe`` is the ordinal of the stage's probe in its
+# chain, counted from the leaf (``Chain.fn`` counts it as it counts the
+# builds): the probing stages open ``probe:<i>`` inside ``op:Join``, so
+# a trace tells the probes of one chain apart.  A scope is metadata:
+# it is no part of a signature and of no program's text.
 #
 # ``env`` is the proven interval of each channel of the stage's input
 # page (``analysis.kernel_soundness.channel_values`` of the node's
@@ -123,7 +128,7 @@ class Filter(NamedTuple):
     def of(cls, node, max_groups, env=None):
         return cls(node.predicate, _prove([node.predicate], env))
 
-    def apply(self, page, consts, build_key):
+    def apply(self, page, consts, build_key, probe):
         with jax.named_scope("op:Filter"):
             return filter_page(page, self.predicate, self.proven)
 
@@ -136,7 +141,7 @@ class Project(NamedTuple):
     def of(cls, node, max_groups, env=None):
         return cls(tuple(node.projections), _prove(node.projections, env))
 
-    def apply(self, page, consts, build_key):
+    def apply(self, page, consts, build_key, probe):
         with jax.named_scope("op:Project"):
             return project_page(page, list(self.projections), self.proven)
 
@@ -180,7 +185,7 @@ class AggPartial(NamedTuple):
         return tuple(o for o, r in zip(one, self.lane_rows)
                      if r is not None)
 
-    def apply(self, page, consts, build_key):
+    def apply(self, page, consts, build_key, probe):
         with jax.named_scope("op:Aggregation"):
             return grouped_aggregate(
                 page, list(self.group_exprs), list(self.aggs),
@@ -205,8 +210,8 @@ class Probe(NamedTuple):
                    getattr(node, "null_aware", False),
                    len(node.right.channels))
 
-    def apply(self, page, consts, build_key):
-        with jax.named_scope("op:Join"):
+    def apply(self, page, consts, build_key, probe):
+        with jax.named_scope(f"op:Join/probe:{probe}"):
             return probe_join(
                 consts[build_key], page, list(self.left_keys),
                 key_domains=list(self.key_domains), kind=self.kind,
@@ -233,8 +238,8 @@ class Lookup(NamedTuple):
         return cls(tuple(node.left_keys), tuple(node.key_domains or ()),
                    node.null_safe_keys)
 
-    def apply(self, page, consts, build_key):
-        with jax.named_scope("op:Join"):
+    def apply(self, page, consts, build_key, probe):
+        with jax.named_scope(f"op:Join/probe:{probe}"):
             pos, match, _ = probe_lookup(
                 consts[build_key], page, list(self.left_keys),
                 key_domains=list(self.key_domains), null_safe=self.null_safe)
@@ -253,8 +258,8 @@ class Fetch(NamedTuple):
     def of(cls, node, max_groups, env=None):
         return cls(len(node.right.channels))
 
-    def apply(self, page, consts, build_key):
-        with jax.named_scope("op:Join"):
+    def apply(self, page, consts, build_key, probe):
+        with jax.named_scope(f"op:Join/probe:{probe}"):
             *blocks, at = page.blocks
             return probe_fetch(
                 consts[build_key], Page(tuple(blocks), page.row_mask),
@@ -267,7 +272,7 @@ class Cross1(NamedTuple):
     def of(cls, node, max_groups, env=None):
         return cls()
 
-    def apply(self, page, consts, build_key):
+    def apply(self, page, consts, build_key, probe):
         with jax.named_scope("op:CrossSingle"):
             return cross_append_single(page, consts[build_key])
 
@@ -286,7 +291,7 @@ class Compact(NamedTuple):
 
     k: int
 
-    def apply(self, page, consts, build_key):
+    def apply(self, page, consts, build_key, probe):
         with jax.named_scope("op:Filter"):
             return compact_page(page, max(page.capacity >> self.k, 1))
 
@@ -301,6 +306,10 @@ KINDS = {"filter": Filter, "project": Project, "agg_partial": AggPartial,
 #: ``consts["build_<i>"]``: a ``lookup`` reads the build of the
 #: ``fetch`` behind it
 _BUILDS = ("probe", "cross1", "fetch")
+#: the kinds that end a probe (a ``lookup`` is the first half of the
+#: ``fetch`` behind it and shares its ordinal): what ``Chain.probes``
+#: counts and the scope ``probe:<i>`` numbers
+_PROBES = ("probe", "fetch")
 
 # what XLA would call a program that nothing names (``local._named``
 # names every chain the registry holds): after its outermost stage
@@ -357,6 +366,12 @@ class Chain:
     def compacts(self) -> bool:
         return any(s.kind == "compact" for s in self.stages)
 
+    @property
+    def probes(self) -> int:
+        """The probes this chain runs in a row over each page (a probe
+        that compacts between its halves is one)."""
+        return sum(s.kind in _PROBES for s in self.stages)
+
     def arith_counts(self) -> Tuple[int, int]:
         """(checked, proven): the guarded arithmetic sites and the limb
         sums of short addends this chain compiles with and without
@@ -405,7 +420,7 @@ class Chain:
                 tags.append("probe")
             elif s.kind != "compact" or inside is None:
                 tags.append(s.kind)
-            probes += s.kind in ("probe", "fetch")
+            probes += s.kind in _PROBES
         if inside is not None:
             tags.append(f"compact_in_probe{inside}")
         return "chain_" + "_".join(tags)
@@ -419,15 +434,16 @@ class Chain:
         # the life of the process, and a Stage would pin its plan node
         # (and through it the whole plan) behind it
         stages = self.stages[:upto]
-        steps, builds = [], 0
+        steps, builds, probes = [], 0, 0
         for s in stages:
-            steps.append((s.params, f"build_{builds}"))
+            steps.append((s.params, f"build_{builds}", probes))
             builds += s.kind in _BUILDS
+            probes += s.kind in _PROBES
 
         def run(page, consts):
             live = None
-            for params, key in steps:
-                out = params.apply(page, consts, key)
+            for params, key, probe in steps:
+                out = params.apply(page, consts, key, probe)
                 if isinstance(params, Compact):
                     page, live = out
                     cap_out = page.capacity

@@ -353,7 +353,8 @@ def _named(f, name: str):
 # .fallback pages of compacting chains (``compact_counts``);
 # .expand_retries and .expanded_rows of expanding probes
 # (``expand_counts``); .arith_checked and .arith_proven sites of the
-# chains lowered (``arith_counts``)
+# chains lowered (``arith_counts``); .chain_probes of them
+# (``chain_probes``)
 _HOST_READS = threading.local()
 
 
@@ -392,6 +393,15 @@ def arith_counts() -> Tuple[int, int]:
     differences across it, like ``host_reads``."""
     return (getattr(_HOST_READS, "arith_checked", 0),
             getattr(_HOST_READS, "arith_proven", 0))
+
+
+def chain_probes() -> int:
+    """Probes of the chains this thread has run so far: a chain that
+    probes four builds in a row over each page counts four, whatever
+    its pages (``Chain.probes``, known on the host when ``_chain_pages``
+    lowers a chain, like ``arith_counts``); a query's count is the
+    difference across it."""
+    return getattr(_HOST_READS, "chain_probes", 0)
 
 
 def host_read(x, why: str):
@@ -1363,6 +1373,8 @@ class LocalRunner:
             _HOST_READS, "arith_checked", 0) + checked
         _HOST_READS.arith_proven = getattr(
             _HOST_READS, "arith_proven", 0) + proven
+        _HOST_READS.chain_probes = getattr(
+            _HOST_READS, "chain_probes", 0) + chain.probes
         leaf = self._evidence_leaf(chain.leaf)
         if not chain.compacts:
             yield from self._chain_outputs(leaf, fn, consts, unordered)

@@ -14,8 +14,8 @@ from typing import Optional
 
 from presto_tpu.catalog import Catalog
 from presto_tpu.exec.local import (
-    LocalRunner, MaterializedResult, QueryStats, arith_counts, compact_counts,
-    expand_counts, host_reads,
+    LocalRunner, MaterializedResult, QueryStats, arith_counts, chain_probes,
+    compact_counts, expand_counts, host_reads,
 )
 from presto_tpu.session import Session
 from presto_tpu.sql import ast
@@ -247,6 +247,7 @@ class QueryRunner:
                     compact0 = compact_counts()
                     expand0 = expand_counts()
                     arith0 = arith_counts()
+                    probes0 = chain_probes()
                     with obs.span("execute", cat="lifecycle"):
                         res = None
                         if prepared is not None:
@@ -320,6 +321,9 @@ class QueryRunner:
             # (Chain.arith_counts; the plan's intervals decide)
             res.arith_checked, res.arith_proven = (
                 n - n0 for n, n0 in zip(arith_counts(), arith0))
+            # probes the statement's chains run in a row over each of
+            # their pages (Chain.probes, summed over the chains)
+            res.chain_probes = chain_probes() - probes0
             # serving-tier surfaces: whether this result came from the
             # structural cache, and the executor's observed peak bytes
             # (the admission controller's projection source for the

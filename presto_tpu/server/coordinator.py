@@ -206,6 +206,7 @@ class _QueryState:
         self.expanded_rows: Optional[int] = None
         self.arith_checked: Optional[int] = None
         self.arith_proven: Optional[int] = None
+        self.chain_probes: Optional[int] = None
         # client-supplied request correlation (X-Presto-Trace-Token)
         self.trace_token: Optional[str] = None
         # deadline bookkeeping: the effective limit (None = none) and
@@ -826,6 +827,7 @@ class CoordinatorServer:
                 q.expanded_rows = getattr(res, "expanded_rows", None)
                 q.arith_checked = getattr(res, "arith_checked", None)
                 q.arith_proven = getattr(res, "arith_proven", None)
+                q.chain_probes = getattr(res, "chain_probes", None)
                 q.cache_hit = getattr(res, "cache_hit", None)
                 q.queued_ms = getattr(res, "queued_ms", None)
                 q.memory_blocked_ms = getattr(res, "memory_blocked_ms",
@@ -928,6 +930,8 @@ class CoordinatorServer:
         if q.arith_checked is not None:
             out["stats"]["arithChecked"] = q.arith_checked
             out["stats"]["arithProven"] = q.arith_proven
+        if q.chain_probes is not None:
+            out["stats"]["chainProbes"] = q.chain_probes
         # serving tier: result provenance (structural result cache)
         if q.cache_hit is not None:
             out["stats"]["cacheHit"] = q.cache_hit

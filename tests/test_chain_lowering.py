@@ -2,9 +2,15 @@
 for its program (exec/programs.py): a stage's every parameter is in
 the chain's signature; what a run registers is what ``lower_chain``
 describes; a served runner keeps nothing per statement; a capacity
-retry and a demoted build find their programs by signature alone."""
+retry and a demoted build find their programs by signature alone; the
+probes of one chain open ``probe:<i>`` inside ``op:Join``, which is
+metadata and no part of a program's text, and a statement's final page
+counts them (``stats.chainProbes``)."""
 
 import dataclasses
+import re
+
+import jax
 
 import pytest
 
@@ -22,6 +28,7 @@ from presto_tpu.sql.binder import Binder
 from presto_tpu.types import BIGINT
 
 from tests.oracle import assert_rows_match
+from tests.tpcds_queries import QUERIES as DS_QUERIES
 from tests.tpch_queries import QUERIES
 
 
@@ -264,3 +271,127 @@ def _probe_chain(runner, plan):
     while not isinstance(node, JoinNode):
         node = node.sources[0]
     return runner._lower(node)
+
+
+# -- (e) the probes of one chain are told apart -----------------------------
+
+@pytest.fixture(scope="module")
+def star():
+    """TPC-DS's ``store_sales`` star at SF0.01, the demographic cross
+    product cut to 20,000 rows."""
+    from presto_tpu.connectors.tpcds import Tpcds
+
+    catalog = Catalog()
+    catalog.register("tpcds", Tpcds(sf=0.01, cd_rows=20000))
+    return catalog
+
+
+def _probing_chains(catalog, sql):
+    """(chain, one of its pages, its consts) of every chain of ``sql``
+    that probes, taken where the executor calls the chain's program."""
+    runner, _ = _fresh(catalog)
+    ex = runner.executor
+    program, called = ex._chain_program, []
+
+    def recording(chain):
+        fn = program(chain)
+
+        def call(page, consts):
+            called.append((chain, page, consts))
+            return fn(page, consts)
+
+        return call
+
+    ex._chain_program = recording
+    assert runner.execute(sql).rows
+    probing = {id(c[0]): c for c in reversed(called) if c[0].probes}
+    assert probing
+    return list(probing.values())
+
+
+def _debug_text(chain, page, consts, upto=None):
+    return jax.jit(chain.fn(upto)).lower(page, consts).as_text(
+        debug_info=True)
+
+
+def _scopes(chain, page, consts, upto=None):
+    """(the ``<outer>/probe:<i>`` scope pairs, the count of ``probe:``
+    scopes) in the lowered chain's debug locations."""
+    text = _debug_text(chain, page, consts, upto)
+    return (set(re.findall(r"([a-z]+:[A-Za-z]+)/(probe:\d+)", text)),
+            len(re.findall(r"/probe:\d+", text)))
+
+
+@pytest.mark.parametrize("q,k", [(3, 2), (7, 4)])
+def test_a_chain_of_k_probes_opens_k_scopes_inside_op_join(star, q, k):
+    (chain, page, consts), = _probing_chains(star, DS_QUERIES[q])
+    assert chain.probes == k == len(chain.joins)
+    assert chain.name().count("_probe") - 1 == k  # ..._compact_in_probe0
+    pairs, n = _scopes(chain, page, consts)
+    assert pairs == {("op:Join", f"probe:{i}") for i in range(k)}
+    # nowhere but directly inside op:Join
+    text = _debug_text(chain, page, consts)
+    assert n == len(re.findall(r"op:Join/probe:\d+", text)) > 0
+    # the unfiltered fact chain compacts inside its first probe: the
+    # lookup and the fetch share probe:0, the compaction between them
+    # is the filter's
+    kinds = [s.kind for s in chain.stages]
+    at = kinds.index("lookup")
+    assert kinds[at:at + 3] == ["lookup", "compact", "fetch"]
+    assert kinds.count("probe") == k - 1 and "probe" not in kinds[:at]
+    for upto in (at + 1, at + 2, at + 3):
+        assert _scopes(chain, page, consts, upto)[0] == {
+            ("op:Join", "probe:0")}
+    lookup_only = _scopes(chain, page, consts, at + 1)[1]
+    assert _scopes(chain, page, consts, at + 2)[1] == lookup_only
+    assert _scopes(chain, page, consts, at + 3)[1] > lookup_only
+    if k > 1:
+        assert _scopes(chain, page, consts, at + 4)[0] == {
+            ("op:Join", "probe:0"), ("op:Join", "probe:1")}
+
+
+@pytest.mark.parametrize("q", [14, 3])
+def test_the_probe_scope_is_no_part_of_a_programs_text(catalog, q,
+                                                       monkeypatch):
+    """q14's and q3's chains lower to the text they had before the
+    scope (what the persistent compile cache hashes, so no cell's
+    programs compile anew); only the debug locations differ."""
+    chains = _probing_chains(catalog, QUERIES[q])
+    # q3 probes in two chains: orders against customer, lineitem
+    # against that join
+    assert len(chains) == {14: 1, 3: 2}[q]
+    lowered = [jax.jit(chain.fn()).lower(page, consts)
+               for chain, page, consts in chains]
+    for low in lowered:
+        assert "probe:" not in low.as_text()
+        assert "op:Join/probe:0" in low.as_text(debug_info=True)
+    named_scope = jax.named_scope
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: named_scope(name.split("/probe:")[0]))
+    for (chain, page, consts), low in zip(chains, lowered):
+        before = jax.jit(chain.fn()).lower(page, consts)
+        assert "probe:" not in before.as_text(debug_info=True)
+        assert before.as_text() == low.as_text()
+
+
+def test_chain_probes_ride_the_final_page(star):
+    """``stats.chainProbes`` beside ``arithChecked``: 4 for ds_q07's
+    fact chain, 0 for a statement whose chains probe nothing."""
+    from presto_tpu.client import StatementClient
+    from presto_tpu.server.coordinator import CoordinatorServer
+
+    srv = CoordinatorServer(QueryRunner(star))
+    srv.start()
+    try:
+        client = StatementClient(srv.uri)
+        counts = {}
+        for name, sql in (("ds_q07", DS_QUERIES[7]), ("ds_q03", DS_QUERIES[3]),
+                          ("scan", "select sum(ss_quantity) from store_sales "
+                                   "where ss_quantity < 24")):
+            pages = []
+            client.execute(sql, on_progress=pages.append)
+            assert "arithChecked" in pages[-1]
+            counts[name] = pages[-1]["chainProbes"]
+    finally:
+        srv.stop()
+    assert counts == {"ds_q07": 4, "ds_q03": 2, "scan": 0}
