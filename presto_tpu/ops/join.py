@@ -42,12 +42,15 @@ from presto_tpu.page import Block, Page
 _I64_MAX = jnp.iinfo(jnp.int64).max
 
 # Direct-address lookup table cap: when the packed-key domain is dense
-# enough, the build also materializes CSR-style ``starts`` offsets over
-# the FULL key domain so every probe resolves its match range with two
-# int32 gathers instead of ~log2(build) serialized binary-search rounds
-# (the TPU answer to PagesHash.java:152's O(1) open-addressing probe).
-# Bounded in absolute size (HBM) and relative to the build (so a tiny
-# build over a huge sparse domain doesn't pay a domain-sized sort).
+# enough, the build also materializes a table over the FULL key domain
+# so every probe resolves its match without ~log2(build) serialized
+# binary-search rounds (the TPU answer to PagesHash.java:152's O(1)
+# open-addressing probe).  Two tables, one per leg: the sorted leg's
+# CSR ``starts`` offsets (two int32 gathers give a key's [lo, hi)), and
+# a unique build's ``rank`` (one int32 gather gives the key's sorted
+# position, or -1 where it is absent).  Bounded in absolute size (HBM)
+# and relative to the build (so a tiny build over a huge sparse domain
+# doesn't pay a domain-sized sort).
 DIRECT_DOMAIN_MAX = 1 << 26
 DIRECT_DOMAIN_PER_ROW = 64
 
@@ -120,8 +123,9 @@ class JoinBuild:
     sorted_keys: jax.Array  # packed keys (cap,), max-sentinel padded
     perm: jax.Array  # int32 (cap,): sorted pos -> build row
     page: Page  # original build page (payload source)
-    # optional direct-address table: starts[k] = first sorted position
-    # with key >= k, for k in [0, domain_size]; int32 (domain_size+1,)
+    # sorted leg's optional direct-address table: starts[k] = first
+    # sorted position with key >= k, for k in [0, domain_size]; int32
+    # (domain_size+1,)
     starts: Optional[jax.Array] = None
     # sort-free unique-build path: False iff the planner's uniqueness
     # promise was violated at runtime (caller rebuilds via the sort)
@@ -131,10 +135,14 @@ class JoinBuild:
     # had any live row at all — device bool scalars
     has_null_key: Optional[jax.Array] = None
     nonempty: Optional[jax.Array] = None
+    # unique-direct leg's table: rank[k] = the sorted position of key k,
+    # -1 where no live build row has it; int32 (domain_size,)
+    rank: Optional[jax.Array] = None
 
     def tree_flatten(self):
         return (self.sorted_keys, self.perm, self.page, self.starts,
-                self.unique_ok, self.has_null_key, self.nonempty), None
+                self.unique_ok, self.has_null_key, self.nonempty,
+                self.rank), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -156,9 +164,10 @@ def build_join(
     — the INTERSECT/EXCEPT comparison; default SQL joins drop them).
     ``unique``: the planner promises distinct build keys (primary-key
     joins) — with a dense exact domain the build then skips the sort
-    entirely: ranks come from a prefix count over the domain, the
-    direct-address table from its cumulative sum (PagesHash's
-    addressing rebuilt as two scatters + one scan; a violated promise
+    entirely: ranks come from a prefix count over the domain, and the
+    direct-address table holds each key's rank, -1 where it is absent
+    (PagesHash's addressing rebuilt as two scatters + one scan, probed
+    by one gather a row; a violated promise
     is detected and reported through ``unique_ok`` for the caller to
     rebuild via the sort path)."""
     c = ExprCompiler.for_page(page)
@@ -193,18 +202,20 @@ def build_join(
         counts = jnp.zeros(prod_u + 1, jnp.int32).at[slot].add(
             jnp.where(live, 1, 0))
         present = jnp.minimum(counts[:prod_u], 1)
-        starts_u = jnp.concatenate([
-            jnp.zeros(1, jnp.int32), jnp.cumsum(present).astype(jnp.int32)])
-        rank = starts_u[key_c.astype(jnp.int64)]
+        table = jnp.where(present > 0,
+                          jnp.cumsum(present).astype(jnp.int32) - 1, -1)
+        # a live row's key is present, so its entry is its rank
+        rank = table[key_c.astype(jnp.int32)]
         tgt = jnp.where(live, rank.astype(jnp.int64), cap)
         sorted_keys = jnp.full((cap,), jnp.iinfo(key.dtype).max,
                                dtype=key.dtype).at[tgt].set(key, mode="drop")
         order_u = jnp.zeros((cap,), jnp.int32).at[tgt].set(
             jnp.arange(cap, dtype=jnp.int32), mode="drop")
         collision = jnp.any(counts[:prod_u] > 1)
-        return JoinBuild(sorted_keys, order_u, page, starts_u,
+        return JoinBuild(sorted_keys, order_u, page,
                          unique_ok=jnp.logical_not(collision),
-                         has_null_key=has_null, nonempty=nonempty)
+                         has_null_key=has_null, nonempty=nonempty,
+                         rank=table)
 
     with jax.named_scope("join:index"):
         order = jnp.argsort(key)
@@ -238,9 +249,20 @@ def build_null_flags(page: Page, key_exprs: Sequence[Expr]):
             jnp.any(page.row_mask))
 
 
+def _rank_of(build: JoinBuild, key: jax.Array):
+    """A unique-direct build's one gather: (sorted position, found) per
+    probe row, position 0 where the key is absent or off the domain."""
+    d = build.rank.shape[0]
+    r = build.rank[jnp.clip(key, 0, d - 1).astype(jnp.int32)]
+    found = (r >= 0) & (key >= 0) & (key < d)
+    return jnp.where(found, r, 0), found
+
+
 @jax.named_scope("join:lookup")
 def _lookup_first(build: JoinBuild, key: jax.Array):
     """(candidate sorted position, key-match mask) per probe row."""
+    if build.rank is not None:
+        return _rank_of(build, key)
     if build.starts is not None:
         d = build.starts.shape[0] - 1
         kk = jnp.clip(key, 0, d - 1)
@@ -256,6 +278,9 @@ def _lookup_first(build: JoinBuild, key: jax.Array):
 @jax.named_scope("join:lookup")
 def _lookup_range(build: JoinBuild, key: jax.Array):
     """[lo, hi) sorted-position match range per probe row."""
+    if build.rank is not None:
+        lo, found = _rank_of(build, key)
+        return lo, lo + found.astype(lo.dtype)
     if build.starts is not None:
         d = build.starts.shape[0] - 1
         kk = jnp.clip(key, 0, d - 1)

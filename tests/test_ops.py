@@ -392,45 +392,105 @@ def test_kernels_jit_cleanly():
     assert len(rows(out)) == 3
 
 
-def test_unique_direct_build_matches_sorted():
-    """The sort-free unique-build path (rank by domain prefix count)
-    produces the same lookups as the sorted build."""
-    import numpy as np
+# unique-direct leg against the sorted build: 120 distinct keys of the
+# domain [1, 200]; probes inside it, below it (0 packs to the NULL code,
+# -3 below that), above it, NULL, and one key that packs to the probe
+# sentinel (the int32 key's max - 1)
+_U_DOM = [(1, 200)]
+_U_SENTINEL = np.iinfo(np.int32).max - 1
 
-    from presto_tpu.expr.ir import ColumnRef
-    from presto_tpu.ops.join import build_join, probe_join
-    from presto_tpu.page import Page
-    from presto_tpu.types import BIGINT
 
-    def col(i, t):
-        return ColumnRef(type=t, index=i)
-
+def _unique_build_probe(build_case):
     rng = np.random.default_rng(3)
-    keys = rng.permutation(np.arange(1, 201))[:120]  # unique, dense
-    payload = keys * 10
-    b = Page.from_arrays([keys.astype(np.int64), payload.astype(np.int64)],
-                         [BIGINT, BIGINT])
-    probe_keys = rng.integers(1, 260, size=300).astype(np.int64)
-    p = Page.from_arrays([probe_keys], [BIGINT])
-    dom = [(1, 200)]
-    jb_u = build_join(b, [col(0, BIGINT)], key_domains=dom, unique=True)
-    assert jb_u.unique_ok is not None and bool(jb_u.unique_ok)
-    jb_s = build_join(b, [col(0, BIGINT)], key_domains=dom)
-    results = []
-    for jb in (jb_u, jb_s):
-        out = probe_join(jb, p, [col(0, BIGINT)], key_domains=dom,
-                         kind="inner")
-        import numpy as _np
+    keys = rng.permutation(np.arange(1, 201))[:120].astype(np.int64)
+    key_valid = np.ones(len(keys), dtype=bool)
+    live = np.ones(len(keys), dtype=bool)
+    if build_case == "null_key":
+        key_valid[7] = False
+    elif build_case == "empty":
+        live[:] = False
+    b = Page.from_arrays([keys, keys * 10], [BIGINT, BIGINT],
+                         valids=[key_valid, None])
+    b = Page(b.blocks, jnp.asarray(live))
+    probe_keys = np.concatenate([
+        rng.integers(1, 201, size=300),
+        [0, -3, 201, 250, _U_SENTINEL, keys[0], keys[7]]]).astype(np.int64)
+    probe_valid = np.ones(len(probe_keys), dtype=bool)
+    probe_valid[::37] = False
+    p = Page.from_arrays([probe_keys, -np.arange(len(probe_keys))],
+                         [BIGINT, BIGINT], valids=[probe_valid, None])
+    return b, p
 
-        mask = _np.asarray(out.row_mask)
-        vals = _np.asarray(out.blocks[-1].data)
-        valid = _np.asarray(out.blocks[-1].valid)
-        results.append({i: int(vals[i]) for i in range(len(probe_keys))
-                        if mask[i] and valid[i]})
-    assert results[0] == results[1]
-    # sanity: every matched payload is key * 10
-    for i, v in results[0].items():
-        assert v == int(probe_keys[i]) * 10
+
+@pytest.mark.parametrize("build_case", ["dense", "null_key", "empty"])
+@pytest.mark.parametrize("probe_op", [
+    "inner", "left", "semi", "anti", "mark", "semi_null_aware",
+    "anti_null_aware", "mark_null_aware", "expand_inner", "expand_left"])
+def test_unique_direct_build_matches_sorted(build_case, probe_op):
+    """The sort-free unique-build path (rank by domain prefix count,
+    one gather of its key -> rank table a probe row) produces the same
+    answers as the sorted build, for every probe kind."""
+    b, p = _unique_build_probe(build_case)
+    jb_u = build_join(b, [col(0, BIGINT)], key_domains=_U_DOM, unique=True)
+    assert jb_u.unique_ok is not None and bool(jb_u.unique_ok)
+    assert jb_u.rank is not None and jb_u.starts is None
+    jb_s = build_join(b, [col(0, BIGINT)], key_domains=_U_DOM)
+    assert jb_s.rank is None
+    got = []
+    for jb in (jb_u, jb_s):
+        if probe_op.startswith("expand_"):
+            out, total, matched = probe_expand(
+                jb, p, [col(0, BIGINT)], out_capacity=p.capacity + 8,
+                key_domains=_U_DOM, kind=probe_op[len("expand_"):],
+                return_matched=True)
+            got.append((rows(out), int(total),
+                        np.flatnonzero(np.asarray(matched)).tolist()))
+        else:
+            kind, _, aware = probe_op.partition("_")
+            out = probe_join(jb, p, [col(0, BIGINT)], key_domains=_U_DOM,
+                             kind=kind, null_aware=bool(aware))
+            got.append(rows(out))
+    assert got[0] == got[1]
+    if probe_op == "inner":
+        # every matched payload is key * 10; an empty build matches none
+        assert all(r[2] == r[0] and r[3] == r[0] * 10 for r in got[0])
+        assert (len(got[0]) == 0) == (build_case == "empty")
+
+
+def _gathers_over(jaxpr, n):
+    """Gathers in ``jaxpr`` (sub-jaxprs included) whose indices have
+    ``n`` rows."""
+    count = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather" and \
+                eqn.invars[1].aval.shape[0] == n:
+            count += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            count += _gathers_over(sub, n)
+    return count
+
+
+@pytest.mark.parametrize("leg", ["unique_direct", "sorted_starts"])
+def test_lookup_gathers_per_probe_row(leg):
+    """The mechanism, pinned: a unique-direct build's lookup is one
+    gather a probe row (its key -> rank table); the sorted leg's CSR
+    ``starts`` takes two (lo and hi)."""
+    from presto_tpu.ops.join import probe_lookup, set_direct_join_override
+
+    b, p = _unique_build_probe("dense")
+    set_direct_join_override(True)
+    try:
+        jb = build_join(b, [col(0, BIGINT)], key_domains=_U_DOM,
+                        unique=leg == "unique_direct")
+    finally:
+        set_direct_join_override(None)
+    assert (jb.rank is not None, jb.starts is not None) == (
+        (True, False) if leg == "unique_direct" else (False, True))
+    assert p.capacity not in (b.capacity, jb.capacity, _U_DOM[0][1] + 1)
+    jaxpr = jax.make_jaxpr(lambda jb, p: probe_lookup(
+        jb, p, [col(0, BIGINT)], key_domains=_U_DOM))(jb, p)
+    assert _gathers_over(jaxpr.jaxpr, p.capacity) == (
+        1 if leg == "unique_direct" else 2)
 
 
 def test_unique_direct_collision_detected():
