@@ -228,9 +228,11 @@ class MemoryConnector:
         return page
 
     def row_count(self, table: str) -> int:
-        import numpy as np
+        """One blocking read a page (bind time asks per statement)."""
+        from presto_tpu.obs.reads import host_read
 
-        return sum(int(np.asarray(p.num_rows())) for p in self._tables[table])
+        return sum(int(host_read(p.num_rows(), "row_count"))
+                   for p in self._tables[table])
 
     def column_domain(self, table: str, column: str) -> Optional[Tuple[int, int]]:
         return self._domains.get(table, {}).get(column)

@@ -33,6 +33,8 @@ import numpy as np
 
 from presto_tpu.catalog import Catalog
 from presto_tpu.exec.chain import Chain, is_streaming_join, lower_chain
+from presto_tpu.exec.programs import dispatched
+from presto_tpu.obs.reads import host_read
 from presto_tpu.ops.aggregate import grouped_aggregate, merge_aggregate
 from presto_tpu.ops.join import JoinBuild, build_join, probe_expand, probe_join
 from presto_tpu.ops.sort import limit_page, sort_page, sort_perm, topn_page
@@ -152,7 +154,8 @@ def _pad_arrays_impl(arrs, mask, pad):
     return out, jnp.concatenate([mask, jnp.zeros((pad,), jnp.bool_)])
 
 
-_pad_arrays = jax.jit(_pad_arrays_impl, static_argnums=(2,))
+_pad_arrays = dispatched(
+    "pad_arrays", jax.jit(_pad_arrays_impl, static_argnums=(2,)))
 
 
 def slice_page(page: Page, n: int) -> Page:
@@ -349,39 +352,29 @@ def _named(f, name: str):
     return f
 
 
-# this thread's counts, never reset: .n host reads; .compacted and
-# .fallback pages of compacting chains (``compact_counts``);
-# .expand_retries and .expanded_rows of expanding probes
-# (``expand_counts``); .arith_checked and .arith_proven sites of the
-# chains lowered (``arith_counts``); .chain_probes of them
-# (``chain_probes``)
-_HOST_READS = threading.local()
-
-
-def host_reads() -> int:
-    """Blocking host reads this thread has made so far.  A query's
-    count is the difference across it: the reads are made on the
-    query's consumer thread (like ``_task_stats``), never on a
-    scheduler worker, and nothing is kept on the shared runner."""
-    return getattr(_HOST_READS, "n", 0)
+# this thread's counts, never reset: .compacted and .fallback pages of
+# compacting chains (``compact_counts``); .expand_retries and
+# .expanded_rows of expanding probes (``expand_counts``);
+# .arith_checked and .arith_proven sites of the chains lowered
+# (``arith_counts``); .chain_probes of them (``chain_probes``).  A
+# query's counts are the differences across it, like
+# ``obs.reads.host_reads``
+_COUNTS = threading.local()
 
 
 def compact_counts() -> Tuple[int, int]:
     """(pages of chains that ran compacted, pages of chains that had
-    to run again whole) on this thread so far (``_chain_pages``); a
-    query's counts are the differences across it, like
-    ``host_reads``."""
-    return (getattr(_HOST_READS, "compacted", 0),
-            getattr(_HOST_READS, "fallback", 0))
+    to run again whole) on this thread so far (``_chain_pages``)."""
+    return (getattr(_COUNTS, "compacted", 0),
+            getattr(_COUNTS, "fallback", 0))
 
 
 def expand_counts() -> Tuple[int, int]:
     """(expanding probes that ran a second time at a larger capacity,
     rows expanding probes emitted: the ``total`` each read) on this
-    thread so far (``_probe_with_retry``); a query's counts are the
-    differences across it, like ``host_reads``."""
-    return (getattr(_HOST_READS, "expand_retries", 0),
-            getattr(_HOST_READS, "expanded_rows", 0))
+    thread so far (``_probe_with_retry``)."""
+    return (getattr(_COUNTS, "expand_retries", 0),
+            getattr(_COUNTS, "expanded_rows", 0))
 
 
 def arith_counts() -> Tuple[int, int]:
@@ -389,47 +382,29 @@ def arith_counts() -> Tuple[int, int]:
     that the chains this thread has run so far compiled with and
     without their runtime guard (``Chain.arith_counts``, known on the
     host when ``_chain_pages`` lowers a chain, whether or not its
-    program was already compiled); a query's counts are the
-    differences across it, like ``host_reads``."""
-    return (getattr(_HOST_READS, "arith_checked", 0),
-            getattr(_HOST_READS, "arith_proven", 0))
+    program was already compiled)."""
+    return (getattr(_COUNTS, "arith_checked", 0),
+            getattr(_COUNTS, "arith_proven", 0))
 
 
 def chain_probes() -> int:
     """Probes of the chains this thread has run so far: a chain that
     probes four builds in a row over each page counts four, whatever
     its pages (``Chain.probes``, known on the host when ``_chain_pages``
-    lowers a chain, like ``arith_counts``); a query's count is the
-    difference across it."""
-    return getattr(_HOST_READS, "chain_probes", 0)
+    lowers a chain, like ``arith_counts``)."""
+    return getattr(_COUNTS, "chain_probes", 0)
 
 
-def host_read(x, why: str):
-    """``x``, a device array or a pytree of them, as NumPy on the
-    host.  Every read on the execution path that makes the host wait
-    for the device goes through here, so that each is a
-    ``host_read:<why>`` span when the query traces (what a device-idle
-    gap is booked to), one of ``device.get_calls`` / ``device.get_bytes``
-    and one of the query's ``hostReads``.  Not routed here: the
-    ``collect_stats`` row count, the range sanitizer, ``parallel/``."""
-    from presto_tpu.obs import METRICS, span
-
-    with span("host_read:" + why, cat="device"):
-        out = jax.device_get(x)
-    _HOST_READS.n = getattr(_HOST_READS, "n", 0) + 1
-    METRICS.counter("device.get_calls").inc()
-    METRICS.counter("device.get_bytes").inc(sum(
-        getattr(a, "nbytes", 0) for a in jax.tree_util.tree_leaves(out)))
-    return out
-
-
-@jax.jit
 def _extent_live(mask):
     """(highest live index + 1, live count) of a row mask, as one
     2-element device array so the host pays a single transfer."""
     idx = jnp.arange(mask.shape[0], dtype=jnp.int32)
     extent = jnp.max(jnp.where(mask, idx, -1)) + 1
     return jnp.stack([extent, jnp.sum(mask.astype(jnp.int32))])
+
+
+# the program keeps its name, jit__extent_live
+_extent_live = dispatched("extent_live", jax.jit(_extent_live))
 
 
 class _AggFoldTower:
@@ -586,11 +561,11 @@ def _probe_with_retry(probe_fn, build, page):
     cap = max(int(page.capacity), 1024)
     res = probe_fn(build, page, cap)
     total = int(host_read(res[1], "probe_total"))
-    _HOST_READS.expanded_rows = getattr(
-        _HOST_READS, "expanded_rows", 0) + total
+    _COUNTS.expanded_rows = getattr(
+        _COUNTS, "expanded_rows", 0) + total
     if total > cap:
-        _HOST_READS.expand_retries = getattr(
-            _HOST_READS, "expand_retries", 0) + 1
+        _COUNTS.expand_retries = getattr(
+            _COUNTS, "expand_retries", 0) + 1
         res = probe_fn(build, page, bucket_capacity(total))
     return res
 
@@ -959,12 +934,18 @@ class LocalRunner:
         """The streaming chain rooted at ``node``, as this runner
         stands: its demoted joins, its capacity retries, its missed
         compactions."""
+        from presto_tpu.obs import span
+
         if node in self._no_compact:
             compact_k = 0
         proving = getattr(self._evidence_tls, "state", None) is not None
-        return lower_chain(node, streaming=self._streaming,
-                           max_groups=self._max_groups, compact_k=compact_k,
-                           intervals=self._intervals if proving else None)
+        # the evidence a proof reads (domains, split counts) is read
+        # here, lazily, the first time a chain of the query asks
+        with span("chain:lower", cat="operator"):
+            return lower_chain(node, streaming=self._streaming,
+                               max_groups=self._max_groups,
+                               compact_k=compact_k,
+                               intervals=self._intervals if proving else None)
 
     @contextlib.contextmanager
     def _proving(self):
@@ -1367,15 +1348,21 @@ class LocalRunner:
             self._force_expanding.add(victim)
             yield from self._pages_impl(node)
             return
-        fn = self._chain_program(chain)
-        checked, proven = chain.arith_counts()
-        _HOST_READS.arith_checked = getattr(
-            _HOST_READS, "arith_checked", 0) + checked
-        _HOST_READS.arith_proven = getattr(
-            _HOST_READS, "arith_proven", 0) + proven
-        _HOST_READS.chain_probes = getattr(
-            _HOST_READS, "chain_probes", 0) + chain.probes
-        leaf = self._evidence_leaf(chain.leaf)
+        from presto_tpu.obs import span
+
+        # the rest of the lowering, once the builds are there: the
+        # program by the chain's signature, its counts, the leaf's
+        # split count from the query's evidence
+        with span("chain:lower", cat="operator"):
+            fn = self._chain_program(chain)
+            checked, proven = chain.arith_counts()
+            _COUNTS.arith_checked = getattr(
+                _COUNTS, "arith_checked", 0) + checked
+            _COUNTS.arith_proven = getattr(
+                _COUNTS, "arith_proven", 0) + proven
+            _COUNTS.chain_probes = getattr(
+                _COUNTS, "chain_probes", 0) + chain.probes
+            leaf = self._evidence_leaf(chain.leaf)
         if not chain.compacts:
             yield from self._chain_outputs(leaf, fn, consts, unordered)
             return
@@ -1394,8 +1381,8 @@ class LocalRunner:
             yield page
         fitted = not any(host_read(flags, "compact_taken"))
         which = "compacted" if fitted else "fallback"
-        setattr(_HOST_READS, which,
-                getattr(_HOST_READS, which, 0) + len(flags))
+        setattr(_COUNTS, which,
+                getattr(_COUNTS, which, 0) + len(flags))
         METRICS.counter("chain.compact_pages" if fitted
                         else "chain.compact_fallback_pages").inc(len(flags))
         if not fitted:

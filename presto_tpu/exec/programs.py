@@ -43,6 +43,7 @@ import threading
 import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
+from presto_tpu.obs.trace import span
 from presto_tpu.sync import named_lock
 
 # ---------------------------------------------------------------------------
@@ -255,23 +256,51 @@ def persistent_cache_stats() -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 
+# this thread's program calls, never reset (``dispatches``)
+_DISPATCHES = threading.local()
+
+
+def dispatches() -> int:
+    """Programs this thread has called so far: every registry program
+    and the jitted helpers ``dispatched`` wraps.  A statement's count
+    is the difference across its ``execute``, like
+    ``obs.reads.host_reads``; calls a task scheduler's worker threads
+    make (``task_concurrency`` > 1) are theirs, not the query's."""
+    return getattr(_DISPATCHES, "n", 0)
+
+
+def _dispatching(kind: str):
+    """Count one call on this thread; its ``dispatch:<kind>`` span."""
+    _DISPATCHES.n = getattr(_DISPATCHES, "n", 0) + 1
+    return span("dispatch:" + kind, cat="dispatch")
+
+
+def dispatched(kind: str, fn: Callable) -> Callable:
+    """``fn``, a jitted helper called outside the registry, counted and
+    spanned as a registry program's call is; its program, its name and
+    its compiles are ``fn``'s own."""
+    def call(*args, **kwargs):
+        with _dispatching(kind):
+            return fn(*args, **kwargs)
+    return call
+
+
 class Program:
     """A registered callable + its compile accounting.
 
-    Wraps the (usually jitted) function; every call samples the jit
-    trace-cache size, so a growing cache marks a compile event and the
-    call's wall time is attributed to ``compile_s`` (trace+compile
-    dominate a cold first call; steady-state calls add two cheap
-    counter reads)."""
+    Wraps the (usually jitted) function; every call is one of this
+    thread's ``dispatches`` and a ``dispatch:<kind>`` span, and samples
+    the jit trace-cache size, so a growing cache marks a compile event
+    and the call's wall time is attributed to the registry's
+    ``compile_s`` (trace+compile dominate a cold first call;
+    steady-state calls add two cheap counter reads)."""
 
-    __slots__ = ("fn", "kind", "jitted", "calls", "compile_s", "_registry")
+    __slots__ = ("fn", "kind", "jitted", "_registry")
 
     def __init__(self, fn: Callable, kind: str, jitted: bool, registry):
         self.fn = fn
         self.kind = kind
         self.jitted = jitted
-        self.calls = 0
-        self.compile_s = 0.0
         self._registry = registry
 
     def _cache_size(self) -> int:
@@ -283,16 +312,18 @@ class Program:
             return 1
 
     def __call__(self, *args, **kwargs):
-        self.calls += 1
-        if not self.jitted:
-            return self.fn(*args, **kwargs)
+        with _dispatching(self.kind):
+            if not self.jitted:
+                return self.fn(*args, **kwargs)
+            return self._call_jitted(args, kwargs)
+
+    def _call_jitted(self, args, kwargs):
         n0 = self._cache_size()
         t0 = time.perf_counter()
         out = self.fn(*args, **kwargs)
         n1 = self._cache_size()
         if n1 > n0:
             dt = time.perf_counter() - t0
-            self.compile_s += dt
             reg = self._registry
             if reg is not None:
                 with reg._lock:

@@ -86,6 +86,7 @@ class _LiveSpan:
         self.name = name
         self.cat = cat
         self._args = args
+        self._annotation = None
 
     def set(self, **kwargs):
         """Attach args discovered mid-span (row counts, capacities)."""
@@ -101,8 +102,13 @@ class _LiveSpan:
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        """Ends the span; a second call is a no-op (the runner ends the
+        root ``query`` span before the completion event, inside it)."""
+        if self._annotation is None:
+            return False
         dur = time.perf_counter() - self._t0
         self._annotation.__exit__(exc_type, exc, tb)
+        self._annotation = None
         # StopIteration is generator flow control (the executor wraps
         # page pulls in spans), not a failure worth flagging
         if exc_type is not None and not issubclass(exc_type, StopIteration):

@@ -15,13 +15,19 @@ from typing import Optional
 from presto_tpu.catalog import Catalog
 from presto_tpu.exec.local import (
     LocalRunner, MaterializedResult, QueryStats, arith_counts, chain_probes,
-    compact_counts, expand_counts, host_reads,
+    compact_counts, expand_counts,
 )
+from presto_tpu.exec.programs import dispatches
+from presto_tpu.obs.reads import host_reads
 from presto_tpu.session import Session
 from presto_tpu.sql import ast
 from presto_tpu.sql.binder import Binder
 from presto_tpu.sql.parser import parse_statement
 from presto_tpu.types import BIGINT, VARCHAR, Type
+
+
+#: statements that run as queries: traced, timed, counted, cached
+_QUERIES = (ast.Query, ast.Union, ast.With, ast.SetOp)
 
 
 def _substitute_params(node, params):
@@ -186,210 +192,48 @@ class QueryRunner:
             pass
         return obs.trace_dir() is not None
 
+    def _statement_tracer(self, qid: str, trace: str):
+        """The statement's tracer: the one the coordinator registered
+        for ``qid`` when it admitted the statement, else a new one when
+        the statement will trace (registered once it parses as a
+        query), else None."""
+        from presto_tpu import obs
+
+        tracer = obs.lookup(qid)
+        if tracer is not None and tracer.query_id == qid:
+            return tracer
+        return obs.Tracer(qid, trace) if self._tracing_enabled() else None
+
     def execute(self, sql: str, query_id=None,
                 trace_token: Optional[str] = None) -> MaterializedResult:
         import time
 
-        from presto_tpu.events import (
-            QueryCompletedEvent, QueryCreatedEvent, new_query_id,
-        )
+        from presto_tpu import obs
+        from presto_tpu.events import new_query_id, new_trace_token
 
-        t_q0 = time.perf_counter()
-        stmt = parse_statement(sql)
-        parse_s = time.perf_counter() - t_q0
-
-        if isinstance(stmt, (ast.Query, ast.Union, ast.With, ast.SetOp)):
-            from presto_tpu import obs
-            from presto_tpu.events import new_trace_token
-
-            qid = query_id or new_query_id()
-            trace = (trace_token or self.session.trace_token
-                     or new_trace_token())
-            tracer = None
-            if self._tracing_enabled():
-                tracer = obs.register(obs.Tracer(qid, trace))
-                tracer.add_complete("parse", "lifecycle", t_q0, parse_s)
-            t0 = time.time()
-            obs.METRICS.counter("query.started").inc()
-            obs.TASKS.start(qid, "local", trace_token=trace)
-            # live progress: always registered (the statement protocol,
-            # CLI and UI read it) — publication is one thread-local
-            # read per split when nothing else is active
-            progress = obs.register_progress(obs.QueryProgress(qid))
-            # resource timeline: admission may have created it already
-            # (queue-depth points + queued/blocked annotations land
-            # before execution starts); None when timelines are off
-            timeline = obs.ensure_timeline(qid)
-            self.events.query_created(
-                QueryCreatedEvent(qid, sql, self.session.user, t0, trace_token=trace)
-            )
-            planning_s: Optional[float] = None
-            cache_hit: Optional[bool] = None
-            with obs.tracing(tracer), obs.publishing(progress), \
-                    obs.recording(timeline):
-                try:
-                    t1 = time.perf_counter()
-                    with obs.span("plan", cat="lifecycle"):
-                        plan = self._plan_cached(sql, stmt)
-                        self._check_access(plan)
-                        # serving tier: (key, versions) captured AT PLAN
-                        # TIME so a write racing this execution leaves
-                        # the stored entry stale-by-version, never
-                        # silently current (serving/cache.py)
-                        prepared = self._result_cache_prepared(plan)
-                    planning_s = time.perf_counter() - t1
-                    t1 = time.perf_counter()
-                    # estimate-vs-actual: per-operator actuals sink,
-                    # opt-in (one device sync per page)
-                    qstats = (QueryStats()
-                              if self.session.get("collect_stats") else None)
-                    reads0 = host_reads()
-                    compact0 = compact_counts()
-                    expand0 = expand_counts()
-                    arith0 = arith_counts()
-                    probes0 = chain_probes()
-                    with obs.span("execute", cat="lifecycle"):
-                        res = None
-                        if prepared is not None:
-                            res = self._result_cache_hit(plan, prepared)
-                            cache_hit = res is not None
-                        if res is None:
-                            res = self._run_plan(plan, qid, stats=qstats)
-                    execution_s = time.perf_counter() - t1
-                except Exception as e:
-                    obs.METRICS.counter("query.failed").inc()
-                    progress.mark_done()
-                    err = f"{type(e).__name__}: {e}"
-                    obs.TASKS.finish(qid, "FAILED", error=err)
-                    self._finalize_trace(tracer, t_q0)
-                    self.events.query_completed(QueryCompletedEvent(
-                        qid, sql, self.session.user, "FAILED", t0, time.time(),
-                        error=err, trace_token=trace,
-                        planning_ms=self._ms(planning_s),
-                    ))
-                    raise
-            # populate the result cache AFTER the query succeeded (and
-            # outside the failure path: a cache anomaly must never fail
-            # an already-executed query).  The entry carries the
-            # versions captured at plan time, so a write that raced the
-            # execution leaves it stale-by-version.
-            if prepared is not None and not cache_hit:
-                from presto_tpu.serving.cache import default_result_cache
-
-                default_result_cache().store(
-                    prepared, res.names, res.types, res.rows)
-            progress.mark_done()
-            compile_ms = (round(tracer.total_s("xla_compile") * 1e3, 3)
-                          if tracer is not None else None)
-            obs.METRICS.counter("query.finished").inc()
-            obs.METRICS.counter("query.planning_seconds_total").inc(planning_s)
-            obs.METRICS.counter("query.execution_seconds_total").inc(execution_s)
-            obs.METRICS.histogram("query.execution_ms").observe(execution_s * 1e3)
-            obs.TASKS.finish(qid, "FINISHED", rows=len(res.rows))
-            # split-scheduler footprint onto the task row (local tier
-            # only: a mesh run's executor stats would be stale).  The
-            # thread-local accumulator is read, not last_task_stats —
-            # concurrent queries on one runner must not swap footprints
-            ts = self.executor._task_stats.as_dict()
-            if not cache_hit and not self.session.get("distributed") \
-                    and ts.get("splits"):
-                obs.TASKS.update_scheduler(
-                    qid, ts["splits"], ts["concurrency"],
-                    ts["stall_s"] * 1e3, ts["prefetch_hits"])
-            # per-run outcome off the result object (not the shared
-            # runner fields — concurrent queries would swap stats)
-            dist_stages = getattr(res, "dist_stages", None)
-            dist_fallback = getattr(res, "dist_fallback", None)
-            # stage times ride the result for the statement protocol
-            res.planning_ms = self._ms(planning_s)
-            res.compile_ms = compile_ms
-            res.execution_ms = self._ms(execution_s)
-            # blocking device reads this query made on this thread
-            # (exec/local.host_read); 0 for a result-cache hit
-            res.host_reads = host_reads() - reads0
-            # pages of chains that ran compacted, and of chains that
-            # held more live rows than the plan's estimate left room
-            # for and ran again whole (_chain_pages)
-            res.compacted_pages, res.compact_fallback_pages = (
-                n - n0 for n, n0 in zip(compact_counts(), compact0))
-            # expanding probes that ran again at a larger capacity, and
-            # the rows expanding probes emitted (_probe_with_retry)
-            res.expand_retries, res.expanded_rows = (
-                n - n0 for n, n0 in zip(expand_counts(), expand0))
-            # guarded arithmetic sites and limb sums the statement's
-            # chains compiled with / without their runtime guard
-            # (Chain.arith_counts; the plan's intervals decide)
-            res.arith_checked, res.arith_proven = (
-                n - n0 for n, n0 in zip(arith_counts(), arith0))
-            # probes the statement's chains run in a row over each of
-            # their pages (Chain.probes, summed over the chains)
-            res.chain_probes = chain_probes() - probes0
-            # serving-tier surfaces: whether this result came from the
-            # structural cache, and the executor's observed peak bytes
-            # (the admission controller's projection source for the
-            # next run of this statement)
-            res.cache_hit = cache_hit
-            res.query_id = qid  # embedded callers (CLI --doctor) key
-            # the timeline/doctor registries off the result itself
-            res.peak_bytes = (0 if cache_hit
-                              else getattr(self.executor,
-                                           "last_peak_bytes", 0))
-            self._finalize_trace(tracer, t_q0)
-            # post-query diagnosis (obs/doctor.py): ranked findings from
-            # the rulebook over trace + timeline + progress; they ride
-            # the result (statement protocol), the timeline (the
-            # /v1/query/<id>/doctor endpoint) and the completion event
-            # (query-log `findings` field)
-            wall_ms = ((res.planning_ms or 0.0) + (res.execution_ms or 0.0))
-            queued_ms = memory_blocked_ms = None
-            # estimate-vs-actual attribution: the worst-node ratio is
-            # annotated BEFORE the doctor runs (its `misestimate` rule
-            # reads it), feeds the plan-history store, and rides the
-            # result + completion event + query-log line
-            worst = None
-            if qstats is not None and not cache_hit:
-                from presto_tpu.obs.history import (
-                    default_history, operator_rows, worst_estimate,
-                )
-
-                est_map = getattr(plan, "_estimates", None)
-                worst = worst_estimate(qstats, est_map)
-                if timeline is not None:
-                    if worst is not None:
-                        timeline.annotate("worst_estimate", worst)
-                    # per-operator detail rows for the web UI /
-                    # /v1/query/<id>/operators endpoint
-                    timeline.annotate(
-                        "operators", operator_rows(qstats, est_map))
-                default_history().record_query(qstats, est_map)
-            res.worst_estimate = worst
-            res.worst_estimate_ratio = worst["ratio"] if worst else None
-            if timeline is not None:
-                timeline.annotate("wall_ms", wall_ms)
-                if dist_fallback:
-                    timeline.annotate("dist_fallback", dist_fallback)
-                queued_ms = timeline.annotation("queued_ms")
-                memory_blocked_ms = timeline.annotation("memory_blocked_ms")
-            findings = [f.as_dict() for f in obs.doctor.diagnose(
-                qid, tracer=tracer, timeline=timeline, progress=progress,
-                wall_ms=wall_ms, dist_fallback=dist_fallback)]
-            if timeline is not None:
-                timeline.annotate("findings", findings)
-            res.findings = findings
-            res.queued_ms = queued_ms
-            res.memory_blocked_ms = memory_blocked_ms
-            self.events.query_completed(QueryCompletedEvent(
-                qid, sql, self.session.user, "FINISHED", t0, time.time(),
-                rows=len(res.rows), trace_token=trace,
-                dist_stages=dist_stages, dist_fallback=dist_fallback,
-                planning_ms=res.planning_ms, compile_ms=compile_ms,
-                execution_ms=res.execution_ms, cache_hit=cache_hit,
-                queued_ms=queued_ms, memory_blocked_ms=memory_blocked_ms,
-                findings=findings,
-                worst_estimate_ratio=res.worst_estimate_ratio,
-            ))
-            return res
-
+        qid = query_id or new_query_id()
+        trace = (trace_token or self.session.trace_token
+                 or new_trace_token())
+        tracer = self._statement_tracer(qid, trace)
+        if tracer is not None:
+            trace = tracer.trace_token or trace
+        stmt = None
+        try:
+            # the root span and parse are live spans, so they are on a
+            # profile's clock like every other
+            with obs.tracing(tracer):
+                root = obs.span("query", cat="lifecycle")
+                with root:
+                    with obs.span("parse", cat="lifecycle"):
+                        stmt = parse_statement(sql)
+                    if isinstance(stmt, _QUERIES):
+                        if tracer is not None:
+                            obs.register(tracer)
+                        return self._execute_query(sql, stmt, qid, trace,
+                                                   tracer, root)
+        finally:
+            if tracer is not None and isinstance(stmt, _QUERIES):
+                obs.maybe_write_trace(tracer)
         if isinstance(stmt, ast.Explain):
             validate = getattr(stmt, "validate", False)
             # EXPLAIN (TYPE VALIDATE) always gates every rewrite, like
@@ -846,6 +690,205 @@ class QueryRunner:
 
         raise ValueError(f"unsupported statement {stmt!r}")
 
+
+    def _execute_query(self, sql: str, stmt, qid: str, trace: str,
+                       tracer, root) -> MaterializedResult:
+        import time
+
+        from presto_tpu import obs
+        from presto_tpu.events import QueryCompletedEvent, QueryCreatedEvent
+
+        t0 = time.time()
+        obs.METRICS.counter("query.started").inc()
+        obs.TASKS.start(qid, "local", trace_token=trace)
+        # live progress: always registered (the statement protocol,
+        # CLI and UI read it) — publication is one thread-local read
+        # per split when nothing else is active
+        progress = obs.register_progress(obs.QueryProgress(qid))
+        # resource timeline: admission may have created it already
+        # (queue-depth points + queued/blocked annotations land before
+        # execution starts); None when timelines are off
+        timeline = obs.ensure_timeline(qid)
+        self.events.query_created(
+            QueryCreatedEvent(qid, sql, self.session.user, t0, trace_token=trace)
+        )
+        planning_s: Optional[float] = None
+        cache_hit: Optional[bool] = None
+        with obs.publishing(progress), obs.recording(timeline):
+            try:
+                t1 = time.perf_counter()
+                plan_reads0 = host_reads()
+                with obs.span("plan", cat="lifecycle"):
+                    plan = self._plan_cached(sql, stmt)
+                    with obs.span("plan:check", cat="lifecycle"):
+                        self._check_access(plan)
+                        # serving tier: (key, versions) captured AT
+                        # PLAN TIME so a write racing this execution
+                        # leaves the stored entry stale-by-version,
+                        # never silently current (serving/cache.py)
+                        prepared = self._result_cache_prepared(plan)
+                planning_s = time.perf_counter() - t1
+                t1 = time.perf_counter()
+                # estimate-vs-actual: per-operator actuals sink,
+                # opt-in (one device sync per page)
+                qstats = (QueryStats()
+                          if self.session.get("collect_stats") else None)
+                reads0 = host_reads()
+                dispatches0 = dispatches()
+                compact0 = compact_counts()
+                expand0 = expand_counts()
+                arith0 = arith_counts()
+                probes0 = chain_probes()
+                with obs.span("execute", cat="lifecycle"):
+                    res = None
+                    if prepared is not None:
+                        res = self._result_cache_hit(plan, prepared)
+                        cache_hit = res is not None
+                    if res is None:
+                        res = self._run_plan(plan, qid, stats=qstats)
+                execution_s = time.perf_counter() - t1
+            except Exception as e:
+                obs.METRICS.counter("query.failed").inc()
+                progress.mark_done()
+                err = f"{type(e).__name__}: {e}"
+                obs.TASKS.finish(qid, "FAILED", error=err)
+                # the root span ends before the completion event, whose
+                # listeners read the span rollup (query log)
+                root.__exit__(type(e), e, e.__traceback__)
+                self.events.query_completed(QueryCompletedEvent(
+                    qid, sql, self.session.user, "FAILED", t0, time.time(),
+                    error=err, trace_token=trace,
+                    planning_ms=self._ms(planning_s),
+                ))
+                raise
+        with obs.span("statement:finish", cat="lifecycle"):
+            # populate the result cache AFTER the query succeeded (and
+            # outside the failure path: a cache anomaly must never fail an
+            # already-executed query).  The entry carries the versions
+            # captured at plan time, so a write that raced the execution
+            # leaves it stale-by-version.
+            if prepared is not None and not cache_hit:
+                from presto_tpu.serving.cache import default_result_cache
+
+                default_result_cache().store(
+                    prepared, res.names, res.types, res.rows)
+            progress.mark_done()
+            compile_ms = (round(tracer.total_s("xla_compile") * 1e3, 3)
+                          if tracer is not None else None)
+            obs.METRICS.counter("query.finished").inc()
+            obs.METRICS.counter("query.planning_seconds_total").inc(planning_s)
+            obs.METRICS.counter("query.execution_seconds_total").inc(execution_s)
+            obs.METRICS.histogram("query.execution_ms").observe(execution_s * 1e3)
+            obs.TASKS.finish(qid, "FINISHED", rows=len(res.rows))
+            # split-scheduler footprint onto the task row (local tier only:
+            # a mesh run's executor stats would be stale).  The thread-local
+            # accumulator is read, not last_task_stats — concurrent queries
+            # on one runner must not swap footprints
+            ts = self.executor._task_stats.as_dict()
+            if not cache_hit and not self.session.get("distributed") \
+                    and ts.get("splits"):
+                obs.TASKS.update_scheduler(
+                    qid, ts["splits"], ts["concurrency"],
+                    ts["stall_s"] * 1e3, ts["prefetch_hits"])
+            # per-run outcome off the result object (not the shared runner
+            # fields — concurrent queries would swap stats)
+            dist_stages = getattr(res, "dist_stages", None)
+            dist_fallback = getattr(res, "dist_fallback", None)
+            # stage times ride the result for the statement protocol
+            res.planning_ms = self._ms(planning_s)
+            res.compile_ms = compile_ms
+            res.execution_ms = self._ms(execution_s)
+            # blocking device reads this query made on this thread
+            # (obs.reads.host_read) while it planned (the bind-time row
+            # counts) and while it executed; 0 and 0 for a cached plan
+            # and a result-cache hit
+            res.plan_reads = reads0 - plan_reads0
+            res.host_reads = host_reads() - reads0
+            # programs the execution called on this thread (Program
+            # calls and the helpers exec/programs.dispatched wraps)
+            res.dispatches = dispatches() - dispatches0
+            # pages of chains that ran compacted, and of chains that held
+            # more live rows than the plan's estimate left room for and
+            # ran again whole (_chain_pages)
+            res.compacted_pages, res.compact_fallback_pages = (
+                n - n0 for n, n0 in zip(compact_counts(), compact0))
+            # expanding probes that ran again at a larger capacity, and the
+            # rows expanding probes emitted (_probe_with_retry)
+            res.expand_retries, res.expanded_rows = (
+                n - n0 for n, n0 in zip(expand_counts(), expand0))
+            # guarded arithmetic sites and limb sums the statement's chains
+            # compiled with / without their runtime guard
+            # (Chain.arith_counts; the plan's intervals decide)
+            res.arith_checked, res.arith_proven = (
+                n - n0 for n, n0 in zip(arith_counts(), arith0))
+            # probes the statement's chains run in a row over each of their
+            # pages (Chain.probes, summed over the chains)
+            res.chain_probes = chain_probes() - probes0
+            # serving-tier surfaces: whether this result came from the
+            # structural cache, and the executor's observed peak bytes (the
+            # admission controller's projection source for the next run of
+            # this statement)
+            res.cache_hit = cache_hit
+            res.query_id = qid  # embedded callers (CLI --doctor) key
+            # the timeline/doctor registries off the result itself
+            res.peak_bytes = (0 if cache_hit
+                              else getattr(self.executor, "last_peak_bytes", 0))
+            # post-query diagnosis (obs/doctor.py): ranked findings from the
+            # rulebook over trace + timeline + progress; they ride the
+            # result (statement protocol), the timeline (the
+            # /v1/query/<id>/doctor endpoint) and the completion event
+            # (query-log `findings` field)
+            wall_ms = ((res.planning_ms or 0.0) + (res.execution_ms or 0.0))
+            queued_ms = memory_blocked_ms = None
+            # estimate-vs-actual attribution: the worst-node ratio is
+            # annotated BEFORE the doctor runs (its `misestimate` rule reads
+            # it), feeds the plan-history store, and rides the result +
+            # completion event + query-log line
+            worst = None
+            if qstats is not None and not cache_hit:
+                from presto_tpu.obs.history import (
+                    default_history, operator_rows, worst_estimate,
+                )
+
+                est_map = getattr(plan, "_estimates", None)
+                worst = worst_estimate(qstats, est_map)
+                if timeline is not None:
+                    if worst is not None:
+                        timeline.annotate("worst_estimate", worst)
+                    # per-operator detail rows for the web UI /
+                    # /v1/query/<id>/operators endpoint
+                    timeline.annotate(
+                        "operators", operator_rows(qstats, est_map))
+                default_history().record_query(qstats, est_map)
+            res.worst_estimate = worst
+            res.worst_estimate_ratio = worst["ratio"] if worst else None
+            if timeline is not None:
+                timeline.annotate("wall_ms", wall_ms)
+                if dist_fallback:
+                    timeline.annotate("dist_fallback", dist_fallback)
+                queued_ms = timeline.annotation("queued_ms")
+                memory_blocked_ms = timeline.annotation("memory_blocked_ms")
+            findings = [f.as_dict() for f in obs.doctor.diagnose(
+                qid, tracer=tracer, timeline=timeline, progress=progress,
+                wall_ms=wall_ms, dist_fallback=dist_fallback)]
+            if timeline is not None:
+                timeline.annotate("findings", findings)
+            res.findings = findings
+            res.queued_ms = queued_ms
+            res.memory_blocked_ms = memory_blocked_ms
+            root.__exit__(None, None, None)  # before the query log reads it
+            self.events.query_completed(QueryCompletedEvent(
+                qid, sql, self.session.user, "FINISHED", t0, time.time(),
+                rows=len(res.rows), trace_token=trace,
+                dist_stages=dist_stages, dist_fallback=dist_fallback,
+                planning_ms=res.planning_ms, compile_ms=compile_ms,
+                execution_ms=res.execution_ms, cache_hit=cache_hit,
+                queued_ms=queued_ms, memory_blocked_ms=memory_blocked_ms,
+                findings=findings,
+                worst_estimate_ratio=res.worst_estimate_ratio,
+            ))
+            return res
+
     def _columns_of(self, name: str):
         """(column, type) rows for a table OR a view (views bind their
         stored SQL to recover the projected shape — ShowColumnsRewrite
@@ -1188,10 +1231,13 @@ class QueryRunner:
     def _plan_cached(self, sql: str, q: ast.Query):
         plan = self._plans.get(sql)
         if plan is None:
+            from presto_tpu import obs
             from presto_tpu.sql.binder import BindError, annotate_position
 
             try:
-                plan = self._validated(self.binder.plan_ast(q))
+                plan = self.binder.plan_ast(q)
+                with obs.span("plan:check", cat="lifecycle"):
+                    plan = self._validated(plan)
             except BindError as e:
                 # statement text is known here: render the failing AST
                 # node's offset as line:col in the user-facing error
@@ -1202,20 +1248,6 @@ class QueryRunner:
     @staticmethod
     def _ms(seconds: Optional[float]) -> Optional[float]:
         return None if seconds is None else round(seconds * 1e3, 3)
-
-    @staticmethod
-    def _finalize_trace(tracer, t_q0: float) -> None:
-        """Close the root ``query`` span (parse start -> now) and write
-        the per-query Chrome-trace file when a trace dir is set."""
-        if tracer is None:
-            return
-        import time
-
-        from presto_tpu import obs
-
-        tracer.add_complete("query", "lifecycle", t_q0,
-                            time.perf_counter() - t_q0)
-        obs.maybe_write_trace(tracer)
 
     def _check_access(self, plan) -> None:
         from presto_tpu.security import scan_tables

@@ -199,7 +199,9 @@ class _QueryState:
         self.planning_ms: Optional[float] = None
         self.compile_ms: Optional[float] = None
         self.execution_ms: Optional[float] = None
+        self.plan_reads: Optional[int] = None
         self.host_reads: Optional[int] = None
+        self.dispatches: Optional[int] = None
         self.compacted_pages: Optional[int] = None
         self.compact_fallback_pages: Optional[int] = None
         self.expand_retries: Optional[int] = None
@@ -209,6 +211,10 @@ class _QueryState:
         self.chain_probes: Optional[int] = None
         # client-supplied request correlation (X-Presto-Trace-Token)
         self.trace_token: Optional[str] = None
+        # the statement's tracer when it traces, from submission on:
+        # admission, the runner's phases and each response are its
+        # spans (the runner takes it from the registry by query id)
+        self.tracer = None
         # deadline bookkeeping: the effective limit (None = none) and
         # the monotonic instant execution started
         self.deadline_s: Optional[float] = None
@@ -370,6 +376,15 @@ class CoordinatorServer:
                 self.end_headers()
                 self.wfile.write(body)
 
+            def _respond(self, q: _QueryState, token: int) -> None:
+                """A statement page, built and written under the
+                statement's tracer (``statement:respond``)."""
+                from presto_tpu import obs
+
+                with obs.tracing(q.tracer), \
+                        obs.span("statement:respond", cat="lifecycle"):
+                    self._json(200, outer._page_response(q, token))
+
             def _html(self, code: int, body: str) -> None:
                 raw = body.encode()
                 self.send_response(code)
@@ -432,7 +447,7 @@ class CoordinatorServer:
                     # returns a terminal (or pollable) page — a
                     # deadline-exceeding query can never hang the POST
                     q.done.wait(timeout=outer._blocking_wait(q))
-                self._json(200, outer._page_response(q, 0))
+                self._respond(q, 0)
 
             def do_GET(self):
                 parts = [p for p in self.path.split("/")
@@ -569,7 +584,7 @@ class CoordinatorServer:
                     # into a long-poll without delaying finished pages
                     if not q.done.is_set():
                         q.done.wait(timeout=0.3)
-                    self._json(200, outer._page_response(q, token))
+                    self._respond(q, token)
                     return
                 self._json(404, {"error": "not found"})
 
@@ -747,9 +762,17 @@ class CoordinatorServer:
     # ------------------------------------------------------------------
     def _submit(self, sql: str,
                 trace_token: Optional[str] = None) -> _QueryState:
+        from presto_tpu import obs
+
         qid = uuid.uuid4().hex[:16]
         q = _QueryState(qid, sql)
         q.trace_token = trace_token
+        if self.runner._tracing_enabled():
+            from presto_tpu.events import new_trace_token
+
+            q.tracer = obs.register(obs.Tracer(
+                qid, trace_token or self.runner.session.trace_token
+                or new_trace_token()))
         with self._lock:
             self.queries[qid] = q
 
@@ -766,11 +789,13 @@ class CoordinatorServer:
             # keep distinct statement error codes (QUERY_QUEUE_FULL /
             # EXCEEDED_QUEUE_TIME) instead of a generic failure.
             try:
-                ticket = self.admission.admit(
-                    q.id, self.runner.session.user, priority=prio,
-                    timeout=(self.max_queued_time
-                             if self.max_queued_time > 0 else None),
-                    statement_key=sql)
+                with obs.tracing(q.tracer), \
+                        obs.span("statement:admit", cat="lifecycle"):
+                    ticket = self.admission.admit(
+                        q.id, self.runner.session.user, priority=prio,
+                        timeout=(self.max_queued_time
+                                 if self.max_queued_time > 0 else None),
+                        statement_key=sql)
                 with self._lock:
                     q.ticket = ticket
             except QueryQueueFullError as e:
@@ -819,7 +844,9 @@ class CoordinatorServer:
                 q.planning_ms = getattr(res, "planning_ms", None)
                 q.compile_ms = getattr(res, "compile_ms", None)
                 q.execution_ms = getattr(res, "execution_ms", None)
+                q.plan_reads = getattr(res, "plan_reads", None)
                 q.host_reads = getattr(res, "host_reads", None)
+                q.dispatches = getattr(res, "dispatches", None)
                 q.compacted_pages = getattr(res, "compacted_pages", None)
                 q.compact_fallback_pages = getattr(
                     res, "compact_fallback_pages", None)
@@ -920,7 +947,9 @@ class CoordinatorServer:
         if q.execution_ms is not None:
             out["stats"]["executionMs"] = q.execution_ms
         if q.host_reads is not None:
+            out["stats"]["planReads"] = q.plan_reads
             out["stats"]["hostReads"] = q.host_reads
+            out["stats"]["dispatches"] = q.dispatches
         if q.compacted_pages is not None:
             out["stats"]["compactedPages"] = q.compacted_pages
             out["stats"]["compactFallbackPages"] = q.compact_fallback_pages

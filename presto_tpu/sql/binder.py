@@ -33,6 +33,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 from presto_tpu.catalog import Catalog
 from presto_tpu.page import Dictionary
 from presto_tpu.expr.ir import AggCall, Call, ColumnRef, Expr, Literal, call, infer_type
+from presto_tpu.obs.trace import span
 from presto_tpu.planner.plan import (
     AggregationNode,
     Channel,
@@ -733,38 +734,47 @@ class Binder:
                 validate_rewrites = analysis.rewrite_validation_enabled() or (
                     self.session is not None
                     and bool(self.session.get("validate_rewrites")))
-            node, names = self._plan_query_like(q)
+            # table resolution and its statistics (the connectors' row
+            # counts: obs.reads.host_read "row_count") are bind's
+            with span("plan:bind", cat="lifecycle"):
+                node, names = self._plan_query_like(q)
             out = OutputNode(node, names)
-            if analysis.validation_enabled() or (
-                    self.session is not None
-                    and bool(self.session.get("validate_plans"))):
-                # pre-optimization half of the validate_plans contract:
-                # a clean bound plan isolates any later violation to a
-                # rewrite (the runner validates the optimized plan)
-                analysis.assert_valid(out)
-            if analysis.kernel_validation_enabled() or (
-                    self.session is not None
-                    and bool(self.session.get("validate_kernels"))):
-                # same pre-optimization split for the kernel-soundness
-                # tier: a clean bound plan pins any post-optimization
-                # hazard on the rewrite that introduced it
-                analysis.assert_kernel_sound(out)
+            with span("plan:check", cat="lifecycle"):
+                if analysis.validation_enabled() or (
+                        self.session is not None
+                        and bool(self.session.get("validate_plans"))):
+                    # pre-optimization half of the validate_plans
+                    # contract: a clean bound plan isolates any later
+                    # violation to a rewrite (the runner validates the
+                    # optimized plan)
+                    analysis.assert_valid(out)
+                if analysis.kernel_validation_enabled() or (
+                        self.session is not None
+                        and bool(self.session.get("validate_kernels"))):
+                    # same pre-optimization split for the
+                    # kernel-soundness tier: a clean bound plan pins any
+                    # post-optimization hazard on the rewrite that
+                    # introduced it
+                    analysis.assert_kernel_sound(out)
             # iterative rule engine over the bound plan
             # (sql/planner/iterative/IterativeOptimizer.java)
             from presto_tpu.planner.iterative import IterativeOptimizer
 
-            opt = IterativeOptimizer(validate=validate_rewrites)
-            out = opt.optimize(out)
+            with span("plan:optimize", cat="lifecycle"):
+                opt = IterativeOptimizer(validate=validate_rewrites)
+                out = opt.optimize(out)
             out._optimizer_report = opt.stats
-            self._enable_index_joins(out)
-            # estimate capture: stamp the FINAL plan with its bind-time
-            # row estimates under the structural stats keys, so EXPLAIN
-            # ANALYZE can print est-vs-actual per operator and the
-            # history store can attribute misestimates (planner/stats.
-            # capture_estimates; feedback applied above via _stats.history)
-            from presto_tpu.planner.stats import capture_estimates
+            with span("plan:estimates", cat="lifecycle"):
+                self._enable_index_joins(out)
+                # estimate capture: stamp the FINAL plan with its
+                # bind-time row estimates under the structural stats
+                # keys, so EXPLAIN ANALYZE can print est-vs-actual per
+                # operator and the history store can attribute
+                # misestimates (planner/stats.capture_estimates;
+                # feedback applied above via _stats.history)
+                from presto_tpu.planner.stats import capture_estimates
 
-            out._estimates = capture_estimates(out, self._stats)
+                out._estimates = capture_estimates(out, self._stats)
             return out
         except (BindError, SyntaxError):
             raise

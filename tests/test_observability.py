@@ -6,6 +6,7 @@ query-log pattern, system.runtime tables, and the
 X-Presto-Trace-Token correlation filter — unified here behind
 ``presto_tpu/obs`` (docs/observability.md)."""
 
+import itertools
 import json
 import threading
 import time
@@ -565,12 +566,21 @@ def test_q13_build_and_probe_carry_the_join_scopes(monkeypatch):
 
 @pytest.fixture(scope="module")
 def statement_server():
+    """A coordinator over SF0.01 resident in a ``MemoryConnector`` (the
+    benchmark's connector: its bind-time row counts read the device),
+    with a program registry of its own."""
     from presto_tpu.client import StatementClient
+    from presto_tpu.connectors.memory import MemoryConnector
+    from presto_tpu.exec.programs import ProgramRegistry
     from presto_tpu.server.coordinator import CoordinatorServer
 
+    tpch = Tpch(sf=0.01, split_rows=8192)
+    mem = MemoryConnector()
+    for table in ("lineitem", "orders", "customer", "part"):
+        mem.load_from(tpch, table)
     catalog = Catalog()
-    catalog.register("tpch", Tpch(sf=0.01))
-    srv = CoordinatorServer(QueryRunner(catalog))
+    catalog.register("tpch", mem)
+    srv = CoordinatorServer(QueryRunner(catalog, programs=ProgramRegistry()))
     srv.start()
     try:
         yield StatementClient(srv.uri)
@@ -578,24 +588,49 @@ def statement_server():
         srv.stop()
 
 
+_FRESH = itertools.count(1)
+
+
+def _statement(client, sql: str, trace: str):
+    """The final page's stats and the statement's tracer (None when it
+    does not trace) for ``sql`` under a text the server has not seen
+    (so it binds and plans); waits for ``statement:respond``, which the
+    handler thread appends once the page is written."""
+    client.execute(f"SET SESSION trace = {trace}")
+    pages = []
+    client.execute(sql + " " * next(_FRESH), on_progress=pages.append)
+    tracer = obs.lookup(client.last_query_id)
+    deadline = time.time() + 5.0
+    while tracer is not None and time.time() < deadline and not any(
+            s.name == "statement:respond" for s in list(tracer.spans)):
+        time.sleep(0.01)
+    return pages[-1], tracer
+
+
+def _inside(inner, outer) -> bool:
+    return (inner.tid == outer.tid and outer.t0 <= inner.t0
+            and inner.t0 + inner.dur <= outer.t0 + outer.dur)
+
+
 @pytest.mark.parametrize("q", [6, 14, 1, 3])
-def test_host_reads_in_stats_and_spans(statement_server, q):
-    """``stats.hostReads`` is in the final page tracing on or off, and
-    with it on equals the number of ``host_read:*`` spans."""
-    client = statement_server
+def test_reads_and_dispatches_in_stats_and_spans(statement_server, q):
+    """``stats.planReads`` + ``stats.hostReads`` equals the
+    ``host_read:*`` spans and ``stats.dispatches`` the ``dispatch:*``
+    spans, and all three are the same with tracing on and off (PR 24's
+    invariant had ``hostReads`` alone)."""
     counts = {}
     for trace in ("false", "true"):
-        client.execute(f"SET SESSION trace = {trace}")
-        pages = []
-        client.execute(QUERIES[q], on_progress=pages.append)
-        counts[trace] = pages[-1]["hostReads"]
-        tracer = obs.lookup(client.last_query_id)
+        stats, tracer = _statement(statement_server, QUERIES[q], trace)
+        counts[trace] = (stats["planReads"], stats["hostReads"],
+                         stats["dispatches"])
         if trace == "false":
             assert tracer is None
             continue
-        reads = [s.name for s in tracer.spans
-                 if s.name.startswith("host_read:")]
-        assert len(reads) == counts[trace]
+        names = [s.name for s in tracer.spans]
+        reads = [n for n in names if n.startswith("host_read:")]
+        assert len(reads) == stats["planReads"] + stats["hostReads"]
+        # the bind-time row counts: one read a page of each table
+        assert reads.count("host_read:row_count") == stats["planReads"] >= 1
         assert reads[-1] == "host_read:result"
         if q in (14, 3):  # one uniqueness check per primary-key build
             assert "host_read:unique_ok" in reads
@@ -603,41 +638,153 @@ def test_host_reads_in_stats_and_spans(statement_server, q):
         # it: one read says whether every page fitted, and the stats
         # count the pages
         assert reads.count("host_read:compact_taken") == (q in (14, 3))
-        assert (pages[-1]["compactedPages"] > 0) == (q in (14, 3))
-        assert pages[-1]["compactFallbackPages"] == 0
-    assert counts["false"] == counts["true"] >= 1
+        assert (stats["compactedPages"] > 0) == (q in (14, 3))
+        assert stats["compactFallbackPages"] == 0
+        dispatched = [n for n in names if n.startswith("dispatch:")]
+        assert len(dispatched) == stats["dispatches"] >= 2
+        assert "dispatch:chain" in dispatched
+    assert counts["false"] == counts["true"]
+
+
+@pytest.mark.parametrize("q", [6, 14, 1, 3])
+def test_new_spans_are_present_and_nested(statement_server, q):
+    """``plan:*`` inside ``plan``; ``dispatch:*`` and ``chain:lower``
+    inside ``execute``; ``parse``, ``plan`` and ``execute`` inside the
+    root ``query``; ``statement:*`` under the statement's own tracer
+    (admission and the finish on the query's thread, the response on
+    the handler's)."""
+    _, tracer = _statement(statement_server, QUERIES[q], "true")
+    spans = list(tracer.spans)
+    one = {}
+    for s in spans:
+        one.setdefault(s.name, []).append(s)
+    for name in ("query", "parse", "plan", "execute", "plan:bind",
+                 "plan:optimize", "plan:estimates", "statement:admit",
+                 "statement:finish"):
+        assert len(one[name]) == 1, name
+    # one a page the client fetches: the POST's, then each poll's
+    assert len(one["statement:respond"]) >= 1
+    [root], [plan], [execute] = one["query"], one["plan"], one["execute"]
+    assert all(_inside(one[n][0], root) for n in ("parse", "plan", "execute"))
+    for s in spans:
+        if s.name.startswith("plan:"):
+            assert _inside(s, plan), s.name
+        if s.name.startswith("dispatch:") or s.name == "chain:lower":
+            assert _inside(s, execute), s.name
+    # plan:check: the binder's, the runner's after the plan and its
+    # access check
+    assert 2 <= len(one["plan:check"]) <= 3
+    assert len(one["chain:lower"]) >= 2
+    admit, finish = one["statement:admit"][0], one["statement:finish"][0]
+    assert admit.tid == root.tid and admit.t0 + admit.dur <= root.t0
+    assert finish.tid == root.tid and finish.t0 >= execute.t0 + execute.dur
+    respond = one["statement:respond"][0]
+    assert respond.tid != root.tid and respond.t0 >= admit.t0
+
+
+def test_no_span_is_made_without_a_tracer(statement_server, monkeypatch):
+    """Tracing off, a statement opens no live span anywhere (the
+    coordinator's, the runner's, the executor's): every ``span`` call
+    is the shared no-op."""
+    from presto_tpu.obs import trace
+
+    made = []
+    real = trace._LiveSpan.__init__
+
+    def spy(self, *args, **kwargs):
+        made.append(args[1])
+        real(self, *args, **kwargs)
+
+    statement_server.execute("SET SESSION trace = false")
+    monkeypatch.setattr(trace._LiveSpan, "__init__", spy)
+    for q in (6, 3):
+        statement_server.execute(QUERIES[q] + "  ")
+    assert made == []
+    assert obs.lookup(statement_server.last_query_id) is None
+
+
+def test_program_names_and_count_unchanged_by_the_spans():
+    """The spans and counters around program calls change no program:
+    after q6, q14, q1, q3 the registry holds the programs, under the
+    names, that it held before them (PR 38's tree, sandbox)."""
+    from presto_tpu.connectors.memory import MemoryConnector
+    from presto_tpu.exec.programs import ProgramRegistry
+
+    tpch = Tpch(sf=0.01)
+    mem = MemoryConnector()
+    for table in ("lineitem", "orders", "customer", "part"):
+        mem.load_from(tpch, table)
+    catalog = Catalog()
+    catalog.register("tpch", mem)
+    registry = ProgramRegistry()
+    runner = QueryRunner(catalog, programs=registry)
+    runner.session.set("trace", "true")
+    for q in (6, 14, 1, 3):
+        runner.execute(QUERIES[q])
+    assert registry.program_count() == 22
+    assert registry.callable_count() == 24
+    names = sorted(p.fn.__name__ for p in registry._programs.values())
+    assert names == sorted(
+        ["agg_final"] * 2 + ["agg_fold"] * 2 + [
+            "agg_packed_final", "agg_packed_fold", "agg_tower_final",
+            "agg_tower_fold", "chain_leaf", "chain_leaf_filter",
+            "chain_leaf_filter_agg_k0a1", "chain_leaf_filter_agg_k2a8",
+            "chain_leaf_filter_compact_probe_agg_k0a2",
+            "chain_leaf_filter_probe",
+            "chain_leaf_filter_probe_agg_k3a1_compact_in_probe0",
+            "sort", "topn"] + ["chain_leaf_project"] * 4
+        + ["join_build"] * 3)
 
 
 def test_host_read_opens_no_span_without_tracer(monkeypatch):
     import jax.numpy as jnp
     import numpy as np
 
-    from presto_tpu.exec import local
+    from presto_tpu.obs import reads
 
     opened = []
-    real_span = obs.span
+    real_span = reads.span
 
     def spy(*args, **kwargs):
         opened.append(real_span(*args, **kwargs))
         return opened[-1]
 
-    monkeypatch.setattr(obs, "span", spy)
+    monkeypatch.setattr(reads, "span", spy)
     calls = obs.METRICS.counter("device.get_calls").value
     nbytes = obs.METRICS.counter("device.get_bytes").value
-    before = local.host_reads()
+    before = reads.host_reads()
     assert obs.current_tracer() is None
-    out = local.host_read(jnp.arange(3, dtype=jnp.int32), "unit")
+    out = reads.host_read(jnp.arange(3, dtype=jnp.int32), "unit")
     assert isinstance(out, np.ndarray) and out.tolist() == [0, 1, 2]
     assert opened == [obs.NULL_SPAN]
     tr = obs.Tracer("q_host_read")
     with obs.tracing(tr):
-        local.host_read((jnp.zeros(2, jnp.int32), jnp.ones(1, jnp.bool_)),
+        reads.host_read((jnp.zeros(2, jnp.int32), jnp.ones(1, jnp.bool_)),
                         "unit")
     assert [(s.name, s.cat) for s in tr.spans] == [
         ("host_read:unit", "device")]
-    assert local.host_reads() == before + 2
+    assert reads.host_reads() == before + 2
     assert obs.METRICS.counter("device.get_calls").value == calls + 2
     assert obs.METRICS.counter("device.get_bytes").value == nbytes + 12 + 9
+
+
+def test_dispatch_counts_and_spans_a_helper_call():
+    """``exec/programs.dispatched``: one count on the calling thread
+    and one ``dispatch:<kind>`` span where a tracer is active; the
+    helper's program keeps its name."""
+    import jax.numpy as jnp
+
+    from presto_tpu.exec import local, programs
+
+    mask = jnp.array([True, False, True, False])
+    before = programs.dispatches()
+    assert local._extent_live(mask).tolist() == [3, 2]
+    tr = obs.Tracer("q_dispatch")
+    with obs.tracing(tr):
+        local._extent_live(mask)
+    assert programs.dispatches() == before + 2
+    assert [(s.name, s.cat) for s in tr.spans] == [
+        ("dispatch:extent_live", "dispatch")]
 
 
 def test_spans_are_profiler_annotations_during_a_session(tmp_path):
@@ -668,8 +815,12 @@ def test_spans_are_profiler_annotations_during_a_session(tmp_path):
               if plane.name.startswith("/host:")
               for line in plane.lines for e in line.events
               if e.name.startswith("presto:")}
-    # retroactive spans (parse, xla_compile, the root) are not events
-    assert events == {"presto:" + n for n in spans
-                      - {"parse", "xla_compile", "query"}}
-    assert {"presto:plan", "presto:op:Aggregation",
+    # the one retroactive span, xla_compile, is not an event; the root
+    # and parse are live since PR 39, like the plan's, the executor's
+    # and the statement's edges
+    assert events == {"presto:" + n for n in spans - {"xla_compile"}}
+    assert {"presto:query", "presto:parse", "presto:plan",
+            "presto:plan:bind", "presto:plan:optimize",
+            "presto:op:Aggregation", "presto:dispatch:chain",
+            "presto:chain:lower", "presto:statement:finish",
             "presto:host_read:result"} <= events
