@@ -285,6 +285,21 @@ def dispatched(kind: str, fn: Callable) -> Callable:
     return call
 
 
+# this thread's sort-path aggregations and the sorts they ran, in the
+# programs it called, never reset (``sorted_runs``)
+_SORTED_RUNS = threading.local()
+
+
+def sorted_runs() -> Tuple[int, int]:
+    """(sort-path aggregations, their sorts) in the programs this thread
+    has called so far: each call adds what its program's trace counted
+    (``ops/aggregate.traced_sorted_runs``: partials and tower merges,
+    and the batches of each, ``_batches``).  A statement's counts are
+    the differences across its ``execute``, like ``dispatches``."""
+    return (getattr(_SORTED_RUNS, "aggs", 0),
+            getattr(_SORTED_RUNS, "sorts", 0))
+
+
 class Program:
     """A registered callable + its compile accounting.
 
@@ -293,15 +308,18 @@ class Program:
     the jit trace-cache size, so a growing cache marks a compile event
     and the call's wall time is attributed to the registry's
     ``compile_s`` (trace+compile dominate a cold first call;
-    steady-state calls add two cheap counter reads)."""
+    steady-state calls add two cheap counter reads).  A call that traces
+    keeps what the trace counted of the sort path (``runs``), and every
+    call adds it to this thread's ``sorted_runs``."""
 
-    __slots__ = ("fn", "kind", "jitted", "_registry")
+    __slots__ = ("fn", "kind", "jitted", "_registry", "runs")
 
     def __init__(self, fn: Callable, kind: str, jitted: bool, registry):
         self.fn = fn
         self.kind = kind
         self.jitted = jitted
         self._registry = registry
+        self.runs = (0, 0)
 
     def _cache_size(self) -> int:
         if not self.jitted:
@@ -312,12 +330,24 @@ class Program:
             return 1
 
     def __call__(self, *args, **kwargs):
+        from presto_tpu.ops.aggregate import traced_sorted_runs
+
+        runs0 = traced_sorted_runs()
         with _dispatching(self.kind):
             if not self.jitted:
-                return self.fn(*args, **kwargs)
-            return self._call_jitted(args, kwargs)
+                out = self.fn(*args, **kwargs)
+                traced = True
+            else:
+                out, traced = self._call_jitted(args, kwargs)
+        if traced:
+            self.runs = tuple(
+                n - n0 for n, n0 in zip(traced_sorted_runs(), runs0))
+        _SORTED_RUNS.aggs = getattr(_SORTED_RUNS, "aggs", 0) + self.runs[0]
+        _SORTED_RUNS.sorts = getattr(_SORTED_RUNS, "sorts", 0) + self.runs[1]
+        return out
 
     def _call_jitted(self, args, kwargs):
+        """(the call's result, whether it traced)."""
         n0 = self._cache_size()
         t0 = time.perf_counter()
         out = self.fn(*args, **kwargs)
@@ -342,7 +372,7 @@ class Program:
             if tr is not None:
                 tr.add_complete("xla_compile", "compile", t0, dt,
                                 kind=self.kind, programs=n1 - n0)
-        return out
+        return out, n1 > n0
 
 
 class ProgramRegistry:

@@ -14,8 +14,12 @@ hostile to the TPU's vector units, so group resolution is re-designed:
   BigintGroupByHash specialization.
 
 * **Sort path**: general case. Pack (exact, when domains fit in 63
-  bits) or hash-mix the key columns into one int64, argsort once,
-  derive group ids from sorted-run boundaries, then segment-reduce.
+  bits) or hash-mix the key columns into one int64 and sort it once
+  with every aggregate's inputs riding along (from three lanes on as
+  the rows of one int64 stack, ``_sorted_runs``), in as many sorts of
+  the same key as the page's size allows (``_batches``); group ids
+  come from sorted-run boundaries and the segment reductions walk the
+  sorted order, so no row is gathered or scattered back.
   Deterministic output order (sorted by packed/hashed key).
 
 Aggregates are expressed as (state columns, merge, finalize) triples so
@@ -38,7 +42,8 @@ accumulator whose folded interval still escapes its state width.
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Sequence, Tuple
+import threading
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1331,8 +1336,8 @@ def _merge_states(state_cols: List[List[jax.Array]], aggs, gid, n,
             vals = arr_col[:, 1:1 + cap_e]
             keys = arr_col[:, 1 + cap_e:1 + 2 * cap_e] if by else vals
             egid = jnp.where(lane_ok, gid[:, None], n)
-            # ctx=None: the sort ctx's gather order covers row-length
-            # arrays, not the rows*cap_e flattened lanes
+            # ctx=None: the sort ctx's runs cover row-length arrays,
+            # not the rows*cap_e flattened lanes
             halves, _ = _topn_halves(
                 None, egid.reshape(-1), keys.reshape(-1), vals.reshape(-1),
                 lane_ok.reshape(-1), n, cap_e, storage,
@@ -1801,18 +1806,19 @@ def pack_or_hash_keys(datas, valids, domains) -> Tuple[jax.Array, bool]:
 
 @dataclasses.dataclass(frozen=True)
 class _SortCtx:
-    """Sorted-run geometry from _sorted_group_ids, enabling large-G
-    segment sums as gather+cumsum+boundary-difference instead of XLA
-    scatter-add (scatter serializes on TPU and compiles pathologically
-    slowly at big shapes; cumsum is one vector pass).
+    """Run geometry of rows that arrive grouped (equal keys contiguous:
+    a sorted batch, ``_sorted_runs``, or a streaming input,
+    ``_presorted_group_ids``), enabling large-G segment sums as
+    cumsum + boundary difference over the values in the order they
+    already have: no gather of rows and no XLA scatter-add (scatter
+    serializes on TPU and compiles pathologically slowly at big shapes;
+    cumsum is one vector pass).
 
-    order:  (rows,) row index per sorted position
-    starts: (max_groups,) sorted position of each group's first row
-    ends:   (max_groups,) sorted position of each group's last row
+    starts: (max_groups,) position of each group's first row
+    ends:   (max_groups,) position of each group's last row
     group_live: (max_groups,) group index < num_groups
     """
 
-    order: jax.Array
     starts: jax.Array
     ends: jax.Array
     group_live: jax.Array
@@ -1826,56 +1832,103 @@ class _SortCtx:
             glive = self.group_live[:, None]
         else:
             glive = self.group_live
-        vals_z = jnp.where(dead, jnp.zeros_like(vals), vals)
-        vs = jnp.take(vals_z, self.order, axis=0)
-        cs = jnp.cumsum(vs, axis=0)
-        ends = jnp.clip(self.ends, 0, vs.shape[0] - 1)
-        starts = jnp.clip(self.starts, 0, vs.shape[0] - 1)
-        seg = (jnp.take(cs, ends, axis=0) - jnp.take(cs, starts, axis=0)
-               + jnp.take(vs, starts, axis=0))
+        # the masked values feed the cumsum only: a group's sum is the
+        # running sum at its end less the one before its start
+        cs = jnp.cumsum(jnp.where(dead, jnp.zeros_like(vals), vals), axis=0)
+        ends = jnp.clip(self.ends, 0, cs.shape[0] - 1)
+        before = self.starts - 1
+        lead = jnp.take(cs, jnp.clip(before, 0, cs.shape[0] - 1), axis=0)
+        if cs.ndim > 1:
+            before = before[:, None]
+        seg = (jnp.take(cs, ends, axis=0)
+               - jnp.where(before >= 0, lead, jnp.zeros_like(lead)))
         return jnp.where(glive, seg, jnp.zeros_like(seg))
 
 
-@jax.named_scope("agg:sort")
-def _sorted_group_ids(key: jax.Array, live: jax.Array, max_groups: int,
-                      want_ctx: bool = False):
-    """Shared sort-path grouping: returns per-row group ids (dead rows
-    -> max_groups), the live group count, and a representative row per
-    group (first sorted occurrence); with ``want_ctx`` also the
-    _SortCtx for cumsum-based segment reductions."""
-    sentinel = jnp.iinfo(key.dtype).max
-    key_live = jnp.where(live, key, sentinel)
-    order = jnp.argsort(key_live)
-    sk = key_live[order]
-    is_live_sorted = sk != sentinel
-    first = jnp.concatenate([jnp.ones(1, jnp.bool_), sk[1:] != sk[:-1]]) & is_live_sorted
-    gid_sorted = jnp.cumsum(first.astype(jnp.int32)) - 1
-    gid_sorted = jnp.where(is_live_sorted, jnp.minimum(gid_sorted, max_groups), max_groups)
+def _runs(first: jax.Array, live: jax.Array, max_groups: int, end):
+    """Group ids, group count and run geometry from the run-start flags
+    of rows that arrive grouped.  Groups past ``max_groups`` (truncation)
+    take id ``max_groups`` like dead rows; the first ``max_groups``
+    keep their exact bounds.  ``end``: one past the last row that can
+    belong to a group."""
+    gid_raw = jnp.cumsum(first.astype(jnp.int32)) - 1
+    gid = jnp.where(live, jnp.minimum(gid_raw, max_groups), max_groups)
     num_groups = jnp.sum(first.astype(jnp.int32))
-    gid = jnp.zeros_like(gid_sorted).at[order].set(gid_sorted)
-    gid = jnp.where(live, gid, max_groups).astype(jnp.int32)
-    rep_slot = jnp.where(first, gid_sorted, max_groups)
-    rep_rows = (
-        jnp.zeros(max_groups + 1, dtype=jnp.int32)
-        .at[rep_slot]
-        .set(order.astype(jnp.int32), mode="drop")
-    )[:max_groups]
-    if not want_ctx:
-        return gid, num_groups, rep_rows
-    idx = jnp.arange(sk.shape[0], dtype=jnp.int32)
-    starts = (
-        jnp.zeros(max_groups + 1, dtype=jnp.int32)
-        .at[rep_slot]
-        .set(idx, mode="drop")
-    )[:max_groups]
-    live_count = jnp.sum(is_live_sorted.astype(jnp.int32))
+    # the first row of each group, and of the first group past the
+    # capacity (where the last kept group ends): a scatter of
+    # max_groups + 1 entries
+    slot = jnp.where(first & (gid_raw <= max_groups), gid_raw, max_groups + 1)
+    idx = jnp.arange(first.shape[0], dtype=jnp.int32)
+    starts = (jnp.zeros(max_groups + 2, dtype=jnp.int32)
+              .at[slot].set(idx, mode="drop"))[:max_groups + 1]
     g = jnp.arange(max_groups, dtype=jnp.int32)
-    next_start = jnp.where(g + 1 < num_groups,
-                           jnp.concatenate([starts[1:], jnp.zeros(1, jnp.int32)]),
-                           live_count)
-    ctx = _SortCtx(order=order, starts=starts, ends=next_start - 1,
+    next_start = jnp.where(g + 1 < num_groups, starts[1:], end)
+    ctx = _SortCtx(starts=starts[:max_groups], ends=next_start - 1,
                    group_live=g < num_groups)
-    return gid, num_groups, rep_rows, ctx
+    return gid, num_groups, ctx
+
+
+def _to_int64(x: jax.Array) -> jax.Array:
+    """``x`` as an int64 lane of the sort's stack, losslessly: floats by
+    their bits, narrower integers sign- or zero-extended."""
+    if x.dtype == jnp.int64:
+        return x
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        x = jax.lax.bitcast_convert_type(
+            x, jnp.dtype(f"int{8 * x.dtype.itemsize}"))
+    elif x.dtype.itemsize == 8:
+        return jax.lax.bitcast_convert_type(x, jnp.int64)
+    return x.astype(jnp.int64)
+
+
+def _from_int64(y: jax.Array, dtype) -> jax.Array:
+    """``_to_int64``'s inverse."""
+    dtype = jnp.dtype(dtype)
+    if dtype == jnp.int64:
+        return y
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jax.lax.bitcast_convert_type(
+            y.astype(f"int{8 * dtype.itemsize}"), dtype)
+    if dtype.itemsize == 8:
+        return jax.lax.bitcast_convert_type(y, dtype)
+    return y.astype(dtype)
+
+
+@jax.named_scope("agg:sort")
+def _sorted_runs(key_live: jax.Array, lanes: Sequence[jax.Array],
+                 max_groups: int, zero=None):
+    """THE sort of the sort path: the live-masked key (dead rows hold
+    the dtype's maximum and sort last) and the batch's lanes in one
+    stable ``lax.sort``, so the lanes arrive in group order and nothing
+    is fetched after it.  From ``_STACK_FROM`` lanes on they ride as the
+    rows of one int64 stack, each beside its own copy of the key, so the
+    sort is one kernel whatever their number: the TPU's sort code grows
+    with every operand it carries, and stays in HBM as long as its
+    program is loaded (``_SORT_STACK_BYTES``).  ``zero``:
+    ``_zero_after``'s, added to the key and the lanes.  Returns (sorted
+    key, sorted lanes, live rows, group ids, group count, _SortCtx), all
+    in sorted order."""
+    sentinel = jnp.iinfo(key_live.dtype).max
+    stacked = len(lanes) >= _STACK_FROM
+    ins = [_to_int64(x) if stacked or zero is not None else x for x in lanes]
+    if zero is not None:
+        key_live = key_live + zero.astype(key_live.dtype)
+        ins = [x + zero.astype(jnp.int64) for x in ins]
+    if stacked:
+        stack = jnp.stack(ins)
+        keys, stack = jax.lax.sort(
+            (jnp.broadcast_to(key_live, stack.shape), stack),
+            dimension=1, num_keys=1, is_stable=True)
+        sk, outs = keys[0], list(stack)
+    else:
+        sk, *outs = jax.lax.sort((key_live, *ins), num_keys=1, is_stable=True)
+    out = [y if y.dtype == x.dtype else _from_int64(y, x.dtype)
+           for y, x in zip(outs, lanes)]
+    live = sk != sentinel
+    first = jnp.concatenate([jnp.ones(1, jnp.bool_), sk[1:] != sk[:-1]]) & live
+    gid, num_groups, ctx = _runs(first, live, max_groups,
+                                 jnp.sum(live.astype(jnp.int32)))
+    return sk, out, live, gid, num_groups, ctx
 
 
 @jax.named_scope("agg:sort")
@@ -1884,30 +1937,214 @@ def _presorted_group_ids(key: jax.Array, live: jax.Array, max_groups: int):
     analog): input rows arrive grouped (equal keys contiguous), so run
     boundaries come from comparing each live row with the previous LIVE
     row (cummax forward-fill skips filtered holes) — no sort at all.
-    Returns the same (gid, num_groups, rep_rows, ctx) shape as
-    _sorted_group_ids with an identity traversal order."""
+    Returns (gid, num_groups, each group's first row, _SortCtx), the
+    reductions walking the rows in their own order."""
     rows = key.shape[0]
     idx = jnp.arange(rows, dtype=jnp.int32)
     last_live = jax.lax.cummax(jnp.where(live, idx, -1))
     prev_live = jnp.concatenate([jnp.full(1, -1, jnp.int32), last_live[:-1]])
     prev_key = key[jnp.clip(prev_live, 0, rows - 1)]
     first = live & ((prev_live < 0) | (prev_key != key))
-    gid_raw = jnp.cumsum(first.astype(jnp.int32)) - 1
-    gid = jnp.where(live, jnp.minimum(gid_raw, max_groups), max_groups).astype(jnp.int32)
-    num_groups = jnp.sum(first.astype(jnp.int32))
-    rep_slot = jnp.where(first, gid_raw, max_groups)
-    starts = (
-        jnp.zeros(max_groups + 1, dtype=jnp.int32)
-        .at[rep_slot]
-        .set(idx, mode="drop")
-    )[:max_groups]
-    g = jnp.arange(max_groups, dtype=jnp.int32)
-    next_start = jnp.where(g + 1 < num_groups,
-                           jnp.concatenate([starts[1:], jnp.zeros(1, jnp.int32)]),
-                           rows)
-    ctx = _SortCtx(order=idx, starts=starts, ends=next_start - 1,
-                   group_live=g < num_groups)
-    return gid, num_groups, starts, ctx
+    gid, num_groups, ctx = _runs(first, live, max_groups, rows)
+    return gid, num_groups, ctx.starts, ctx
+
+
+# ---------------------------------------------------------------------------
+# the payload that rides the key sort
+# ---------------------------------------------------------------------------
+
+#: the most bytes one sort's stack of lanes holds, in and out: each lane
+#: a row costs its int64 and its copy of the key, twice (32 bytes).
+#: More lanes go through further sorts of the same key (``_batches``).
+#: A further sort costs its code, 3.6-6.0 MB of HBM at 2^15-2^17 rows
+#: on a v5e (one to nine lanes beside an int64 key), for as long as its
+#: program is loaded and in every loaded program at once; a lane costs
+#: its 32 bytes a row only while its sort runs.  So a page of up to
+#: 2^17 rows sorts 16 lanes at once, one of 2^21 rows or more one.
+_SORT_STACK_BYTES = 1 << 26
+
+#: the fewest lanes that ride as a stack; fewer ride as operands of
+#: their own beside the one key.  A stack sorts its key once a lane;
+#: its code is about that of two int64 operands (2^17 rows: the key
+#: with one int64 and one int32 operand 5.43 MB, a stack of one 5.17,
+#: of five 6.04), and each further operand adds 0.9 MB an int32 word
+_STACK_FROM = 3
+
+
+def _lanes_per_sort(rows: int) -> int:
+    return max(1, _SORT_STACK_BYTES // (32 * rows))
+
+
+#: aggregates whose partial states read their second argument
+_ARG2_FNS = frozenset(COVAR_FNS) | {
+    "min_by", "max_by", "evaluate_classifier_predictions",
+    "learn_regressor", "learn_classifier", "map_agg", "hll_sketch",
+    "multimap_agg", "max_by_n", "min_by_n"}
+
+# this thread's sort-path aggregations and their sorts, counted while
+# they are traced, never reset (``traced_sorted_runs``)
+_TRACED = threading.local()
+
+
+def traced_sorted_runs() -> Tuple[int, int]:
+    """(sort-path aggregations, sorts they run) that this thread has
+    traced so far: ``grouped_aggregate``'s sort branch and
+    ``merge_aggregate`` with keys, counted once per trace, not per
+    call.  A program's counts are the differences across its trace
+    (``exec/programs.Program``)."""
+    return getattr(_TRACED, "aggs", 0), getattr(_TRACED, "sorts", 0)
+
+
+def _count_traced(sorts: int) -> None:
+    _TRACED.aggs = getattr(_TRACED, "aggs", 0) + 1
+    _TRACED.sorts = getattr(_TRACED, "sorts", 0) + sorts
+
+
+def _ride_inputs(agg: AggCall) -> List[Tuple[Expr, str]]:
+    """The expressions an aggregate's partial state reads, each with
+    what of it rides the sort: ``"bit"`` (a filter: its value and
+    validity as one flag), ``"valid"`` (``count``: the validity only) or
+    ``"data"`` (the value and its validity).  ``count_star`` reads
+    nothing: the live rows are the sorted key's."""
+    out: List[Tuple[Expr, str]] = []
+    if agg.filter is not None:
+        out.append((agg.filter, "bit"))
+    if agg.fn == "count_star":
+        return out
+    out.append((agg.arg, "valid" if agg.fn == "count" else "data"))
+    if agg.fn in _ARG2_FNS:
+        out.append((agg.arg2, "data"))
+    return out
+
+
+def _lane_cost(x, long_decimal: bool) -> Tuple[int, int, bool]:
+    """(lanes, flag bits, rides the row index) of an array of ``x``'s
+    shape and dtype (``_Payload.value``'s rule)."""
+    if x.ndim == 1:
+        if x.dtype == jnp.bool_:
+            return 0, 1, False
+        return 1, 0, False
+    if long_decimal:
+        return int(x.shape[-1]), 0, False
+    return 0, 0, True
+
+
+def _width(cost: Tuple[int, int, bool]) -> int:
+    """The lanes of the stack: flags 32 to a lane, the row index one."""
+    lanes, bits, index = cost
+    return lanes + -(-bits // 32) + int(index)
+
+
+def _add(a, b):
+    return a[0] + b[0], a[1] + b[1], a[2] or b[2]
+
+
+def _batches(costs: Sequence[Tuple[int, int, bool]], per_sort: int,
+             first=(0, 0, False)) -> List[List[int]]:
+    """Aggregates by sort: in order, each joins the current sort while
+    its stack stays within ``per_sort`` lanes (``_lanes_per_sort``),
+    else starts the next one (an aggregate wider than that rides
+    alone).  ``first``: what the first sort carries besides (the key
+    columns, which give the output keys).  Always one sort at least:
+    the groups come from it."""
+    out: List[List[int]] = [[]]
+    acc = first
+    for i, cost in enumerate(costs):
+        nxt = _add(acc, cost)
+        if out[-1] and _width(nxt) > per_sort:
+            out.append([])
+            nxt = cost
+        out[-1].append(i)
+        acc = nxt
+    return out
+
+
+class _Payload:
+    """One sort's payload, built in row order: a lane per value (a long
+    decimal one lane per limb), every flag packed as a bit of an int32
+    lane, and the row index for values too wide to ride (containers,
+    raw strings), which are then gathered by it.  ``value``/``bit``
+    return readers of the sorted batch."""
+
+    def __init__(self, rows: int):
+        self.rows = rows
+        self.lanes: List[jax.Array] = []
+        self.bits: List[jax.Array] = []
+        self.index = False
+
+    def _lane(self, x):
+        self.lanes.append(x)
+        i = len(self.lanes) - 1
+        return lambda s: s[0][i]
+
+    def bit(self, mask):
+        self.bits.append(mask)
+        j = len(self.bits) - 1
+        return lambda s: ((s[1][j // 32] >> (j % 32)) & 1) != 0
+
+    def value(self, x, long_decimal: bool):
+        _, bits, index = _lane_cost(x, long_decimal)
+        if bits:
+            return self.bit(x)
+        if not index:
+            if x.ndim == 1:
+                return self._lane(x)
+            parts = [self._lane(x[:, j]) for j in range(x.shape[-1])]
+            return lambda s: jnp.stack([p(s) for p in parts], axis=-1)
+        self.index = True
+        return lambda s: jnp.take(x, s[2], axis=0)
+
+    def operands(self) -> List[jax.Array]:
+        flags = []
+        for k in range(0, len(self.bits), 32):
+            f = jnp.zeros(self.rows, jnp.int32)
+            for j, m in enumerate(self.bits[k:k + 32]):
+                f = f | (m.astype(jnp.int32) << j)
+            flags.append(f)
+        index = [jnp.arange(self.rows, dtype=jnp.int32)] if self.index else []
+        return self.lanes + flags + index
+
+    def sorted(self, out: Sequence[jax.Array]):
+        n = len(self.lanes)
+        nf = -(-len(self.bits) // 32)
+        return (out[:n], out[n:n + nf], out[n + nf] if self.index else None)
+
+
+class _SortedInputs:
+    """``ExprCompiler``'s stand-in over a sorted batch: ``compile(e)``
+    gives ``e``'s (data, valid) in sorted order, as they came out of
+    the sort (``_partial_states`` then reduces in that order)."""
+
+    def __init__(self, table: Dict[int, tuple]):
+        self._table = table
+
+    def compile(self, expr):
+        dv = self._table[id(expr)]
+        return lambda _page: dv
+
+
+@dataclasses.dataclass
+class _SortedPage:
+    """What ``_partial_states`` reads of a page besides its inputs:
+    the live rows (sorted order) and the blocks' dictionaries."""
+
+    blocks: tuple
+    row_mask: jax.Array
+
+
+def _zero_after(done) -> jax.Array:
+    """An int32 zero that XLA cannot fold, computed from the states in
+    ``done``.  Added to the next sort's key and stack, it keeps that
+    sort after ``done``'s reductions and apart from the sorts before it:
+    the compiler drops an ``optimization_barrier`` before it merges
+    sorts of equal keys back into one, lanes and all."""
+    s = jnp.zeros((), jnp.int64)
+    for x in jax.tree_util.tree_leaves(done):
+        x = x.reshape(-1)[0]
+        if jnp.issubdtype(x.dtype, jnp.floating):
+            x = jax.lax.bitcast_convert_type(x.astype(jnp.float64), jnp.int64)
+        s = s + x.astype(jnp.int64)
+    return (s == s + 1).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -2026,18 +2263,125 @@ def grouped_aggregate(
     # page's, and such a page cannot truncate.  Not above: on the
     # packed-direct path the slot IS the key.
     max_groups = min(max_groups, page.capacity)
-    gid, num_groups, rep_rows, ctx = _sorted_group_ids(
-        key, live, max_groups, want_ctx=True)
-    states = _partial_states(page, aggs, gid, max_groups, ctx=ctx, c=c,
-                             one_lane=one_lane)
-    key_blocks = []
-    for (d, v), e, dic in zip(kd, group_exprs, key_dicts):
-        kb_data = d[rep_rows].astype(e.type.np_dtype)
-        kb_valid = v[rep_rows]
-        key_blocks.append(Block(kb_data, kb_valid, e.type, dic))
-    out_mask = jnp.arange(max_groups) < num_groups
+    inputs = [_ride_inputs(a) for a in aggs]
+    fns = {id(e): c.compile(e) for ins in inputs for e, _ in ins}
+    shapes = jax.eval_shape(
+        lambda p: {k: f(p)[0] for k, f in fns.items()}, page)
+
+    def cost(i):
+        out = (0, 0, False)
+        for e, what in inputs[i]:
+            out = _add(out, (0, 1, False))
+            if what == "data":
+                out = _add(out, _lane_cost(shapes[id(e)],
+                                           e.type.is_long_decimal))
+        return out
+
+    def ride(i, p, payload, table):
+        for e, what in inputs[i]:
+            if id(e) in table:
+                continue
+            d, v = fns[id(e)](p)
+            if what == "bit":
+                m = payload.bit(d & v)
+                table[id(e)] = (m, m)
+            elif what == "valid":
+                table[id(e)] = (None, payload.bit(v))
+            else:
+                table[id(e)] = (payload.value(_rows(d, p.capacity),
+                                              e.type.is_long_decimal),
+                                payload.bit(v))
+
+    def reduce(batch, table, live_s, gid_s, ctx):
+        return _partial_states(
+            _SortedPage(page.blocks, live_s), [aggs[i] for i in batch],
+            gid_s, max_groups, ctx=ctx, c=_SortedInputs(table),
+            one_lane=[one_lane[i] for i in batch] if one_lane else ())
+
+    def rekey(p):
+        kd_p = [c.compile(e)(p) for e in group_exprs]
+        return pack_or_hash_keys(canonicalize_codes(
+            [d for d, _ in kd_p], key_dicts), [v for _, v in kd_p],
+            key_domains)[0]
+
+    states, key_blocks, num_groups, out_mask = _ride_the_sort(
+        page, key, rekey, kd, [e.type for e in group_exprs], key_dicts,
+        key_domains[0][0] if exact and len(kd) == 1 else None,
+        [cost(i) for i in range(len(aggs))], ride, reduce, max_groups)
     out = _emit(key_blocks, states, aggs, out_mask, mode, group_exprs, key_dicts, agg_dicts)
     return (out, num_groups) if return_count else out
+
+
+def _rows(x: jax.Array, rows: int) -> jax.Array:
+    """``x`` as a lane: a scalar broadcast to the page's rows."""
+    return jnp.broadcast_to(x, (rows,)) if x.ndim == 0 else x
+
+
+def _ride_the_sort(page, key, rekey, key_cols, key_types, key_dicts,
+                   key_lo, costs, ride, reduce, max_groups: int):
+    """The sort path of ``grouped_aggregate`` and ``merge_aggregate``:
+    the aggregates' inputs ride sorts of the key in batches
+    (``_batches``), each batch reduced to its ``(max_groups,)`` states
+    before the next one's row-order lanes are built (``_zero_after``).
+
+    ``key``: the row-order packed or hashed key, ``rekey(page)`` the
+    same for a later batch; ``key_cols``: the row-order key columns,
+    which ride the first sort to give the output keys (never unpacked
+    from a packed key: the TPU divides an int64 in software, 7 MB of
+    code for TPC-H q3's three keys).  ``key_lo``: where the key is one
+    column packed exactly, its domain's low end (``_key_codes``: the
+    packed key is the value less ``key_lo`` plus one, 0 for NULL); the
+    output key then comes from the sorted key, by a subtraction, and
+    the column does not ride.  ``costs[i]``: what
+    aggregate ``i`` puts on a sort (``_lane_cost``); ``ride(i, page,
+    payload, table)`` puts it there, ``table`` mapping each input to
+    readers of the sorted batch; ``reduce(batch, table, live, gid,
+    ctx)`` gives the batch's states from what ``table`` read.  Returns
+    (states, key blocks, group count, live groups)."""
+    rows = page.capacity
+    if key_lo is not None:
+        key_cols = []
+    first = (0, 0, False)
+    for d, _ in key_cols:
+        first = _add(_add(first, _lane_cost(d, False)), (0, 1, False))
+    batches = _batches(costs, _lanes_per_sort(rows), first)
+    _count_traced(len(batches))
+    states: List[Optional[list]] = [None] * len(costs)
+    done = None
+    for b, batch in enumerate(batches):
+        k = key if b == 0 else rekey(page)
+        key_live = jnp.where(page.row_mask, k,
+                             jnp.asarray(jnp.iinfo(k.dtype).max, k.dtype))
+        payload = _Payload(rows)
+        keys = [(payload.value(_rows(d, rows), False), payload.bit(v))
+                for d, v in key_cols] if b == 0 else []
+        table: Dict[int, tuple] = {}
+        for i in batch:
+            ride(i, page, payload, table)
+        sk, out, live_s, gid_s, ng, ctx = _sorted_runs(
+            key_live, payload.operands(), max_groups,
+            None if done is None else _zero_after(done))
+        s = payload.sorted(out)
+        read = {k_: tuple(r if r is None else r(s) for r in rs)
+                for k_, rs in table.items()}
+        part = reduce(batch, read, live_s, gid_s, ctx)
+        for i, st in zip(batch, part):
+            states[i] = st
+        done = part
+        if b == 0:
+            num_groups, out_mask = ng, ctx.group_live
+            if key_lo is None:
+                key_blocks = [
+                    Block(jnp.take(dr(s), ctx.starts, axis=0).astype(t.np_dtype),
+                          jnp.take(vr(s), ctx.starts, axis=0) & out_mask,
+                          t, dic)
+                    for (dr, vr), t, dic in zip(keys, key_types, key_dicts)]
+            else:
+                code = jnp.take(sk, ctx.starts).astype(jnp.int64)
+                key_blocks = [Block((code - 1 + key_lo).astype(t.np_dtype),
+                                    (code > 0) & out_mask, t, dic)
+                              for t, dic in zip(key_types, key_dicts)]
+    return states, key_blocks, num_groups, out_mask
 
 
 def _unpack_key_blocks(cards, domains, group_exprs, key_dicts, prod, capacity) -> List[Block]:
@@ -2116,12 +2460,33 @@ def merge_aggregate(
         return (out, jnp.ones((), jnp.int32)) if return_count else out
 
     key, exact = pack_or_hash_keys(datas, valids, key_domains)
-    gid, num_groups, rep_rows, ctx = _sorted_group_ids(
-        key, live, max_groups, want_ctx=True)
-    merged = _merge_states(state_cols, aggs, gid, max_groups, ctx=ctx)
-    key_blocks = []
-    for d, v, t, dic in zip(datas, valids, key_types, key_dicts):
-        key_blocks.append(Block(d[rep_rows].astype(t.np_dtype), v[rep_rows], t, dic))
-    out_mask = jnp.arange(max_groups) < num_groups
+    starts = [num_keys + sum(len(state_types(a)) for a in aggs[:i])
+              for i in range(len(aggs))]
+
+    def cost(i):
+        out = (0, 0, False)
+        for col, t in zip(state_cols[i], state_types(aggs[i])):
+            out = _add(out, _lane_cost(col, t.is_long_decimal))
+        return out
+
+    def ride(i, p, payload, table):
+        table[i] = tuple(
+            payload.value(p.blocks[starts[i] + j].data, t.is_long_decimal)
+            for j, t in enumerate(state_types(aggs[i])))
+
+    def reduce(batch, table, live_s, gid_s, ctx):
+        return _merge_states([list(table[i]) for i in batch],
+                             [aggs[i] for i in batch], gid_s, max_groups,
+                             ctx=ctx)
+
+    def rekey(p):
+        return pack_or_hash_keys(canonicalize_codes(
+            [p.blocks[i].data for i in range(num_keys)], key_dicts),
+            [p.blocks[i].valid for i in range(num_keys)], key_domains)[0]
+
+    merged, key_blocks, num_groups, out_mask = _ride_the_sort(
+        partial, key, rekey, list(zip(datas, valids)), key_types,
+        key_dicts, key_domains[0][0] if exact and num_keys == 1 else None,
+        [cost(i) for i in range(len(aggs))], ride, reduce, max_groups)
     out = _emit(key_blocks, merged, aggs, out_mask, mode, group_exprs, key_dicts, agg_dicts)
     return (out, num_groups) if return_count else out

@@ -17,7 +17,7 @@ from presto_tpu.exec.local import (
     LocalRunner, MaterializedResult, QueryStats, arith_counts, chain_probes,
     compact_counts, expand_counts,
 )
-from presto_tpu.exec.programs import dispatches
+from presto_tpu.exec.programs import dispatches, sorted_runs
 from presto_tpu.obs.reads import host_reads
 from presto_tpu.session import Session
 from presto_tpu.sql import ast
@@ -739,6 +739,7 @@ class QueryRunner:
                 expand0 = expand_counts()
                 arith0 = arith_counts()
                 probes0 = chain_probes()
+                sorted0 = sorted_runs()
                 with obs.span("execute", cat="lifecycle"):
                     res = None
                     if prepared is not None:
@@ -824,6 +825,11 @@ class QueryRunner:
             # probes the statement's chains run in a row over each of their
             # pages (Chain.probes, summed over the chains)
             res.chain_probes = chain_probes() - probes0
+            # sort-path aggregations (partials and tower merges) the
+            # statement's programs ran, and the sorts of their batches
+            # (exec/programs.sorted_runs)
+            res.sorted_run_aggs, res.sorted_run_sorts = (
+                n - n0 for n, n0 in zip(sorted_runs(), sorted0))
             # serving-tier surfaces: whether this result came from the
             # structural cache, and the executor's observed peak bytes (the
             # admission controller's projection source for the next run of

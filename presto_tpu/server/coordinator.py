@@ -209,6 +209,8 @@ class _QueryState:
         self.arith_checked: Optional[int] = None
         self.arith_proven: Optional[int] = None
         self.chain_probes: Optional[int] = None
+        self.sorted_run_aggs: Optional[int] = None
+        self.sorted_run_sorts: Optional[int] = None
         # client-supplied request correlation (X-Presto-Trace-Token)
         self.trace_token: Optional[str] = None
         # the statement's tracer when it traces, from submission on:
@@ -855,6 +857,8 @@ class CoordinatorServer:
                 q.arith_checked = getattr(res, "arith_checked", None)
                 q.arith_proven = getattr(res, "arith_proven", None)
                 q.chain_probes = getattr(res, "chain_probes", None)
+                q.sorted_run_aggs = getattr(res, "sorted_run_aggs", None)
+                q.sorted_run_sorts = getattr(res, "sorted_run_sorts", None)
                 q.cache_hit = getattr(res, "cache_hit", None)
                 q.queued_ms = getattr(res, "queued_ms", None)
                 q.memory_blocked_ms = getattr(res, "memory_blocked_ms",
@@ -961,6 +965,9 @@ class CoordinatorServer:
             out["stats"]["arithProven"] = q.arith_proven
         if q.chain_probes is not None:
             out["stats"]["chainProbes"] = q.chain_probes
+        if q.sorted_run_aggs is not None:
+            out["stats"]["sortedRunAggs"] = q.sorted_run_aggs
+            out["stats"]["sortedRunSorts"] = q.sorted_run_sorts
         # serving tier: result provenance (structural result cache)
         if q.cache_hit is not None:
             out["stats"]["cacheHit"] = q.cache_hit

@@ -525,7 +525,7 @@ def test_scopes_change_neither_answers_nor_program_count(
                         lambda name: contextlib.nullcontext())
     monkeypatch.setattr(local, "_named", lambda f, name: f)
     for module, names in (
-            (aggregate, ("_sorted_group_ids", "_presorted_group_ids",
+            (aggregate, ("_sorted_runs", "_presorted_group_ids",
                          "_partial_states", "_merge_states")),
             (join, ("_lookup_first", "_lookup_range"))):
         for name in names:
