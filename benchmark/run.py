@@ -110,6 +110,16 @@ class Run:
         merged = self.busy[min(self.busy)]
         return [xplane.covered(merged, *span) for span in self.pass_intervals]
 
+    def busiest_s_per_pass(self) -> List[float]:
+        """Seconds the busiest chip was busy inside each traced pass:
+        per pass, the largest covered time over the chips; on one chip
+        ``busy_s_per_pass``."""
+        if not self.busy:
+            return []
+        return [max(xplane.covered(merged, *span)
+                    for merged in self.busy.values())
+                for span in self.pass_intervals]
+
     def busy_shares(self) -> Dict[int, float]:
         """chip -> its busy share of the traced stretch."""
         stretch = self.stretch()

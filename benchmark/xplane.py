@@ -10,9 +10,11 @@ by its HLO text; the ``XLA Modules`` line gives the program
 idle share is 1 minus busy over the stretch.  ``Async XLA Ops`` holds
 what is in flight beside them (copies, asynchronous collectives) and
 does not count as busy.  A CPU rehearsal has no device plane; there the host
-events that carry an ``hlo_op`` stat stand in as chip 0, so that the
-reduction runs end to end in the tests (such a run says
-``"platform": "cpu"`` and its numbers are never device numbers).
+events that carry an ``hlo_op`` stat stand in as the chip their
+``device_ordinal`` stat names (chip 0 where it names none), so that the
+reduction runs end to end in the tests, on virtual devices as on chips
+(such a run says ``"platform": "cpu"`` and its numbers are never device
+numbers).
 
 The benchmark marks each statement of the traced stretch with a
 ``jax.profiler.TraceAnnotation`` named ``bench:<query>:<seq>`` on the
@@ -87,7 +89,7 @@ def read(path: str) -> Trace:
     chips: Dict[int, List[Op]] = {}
     async_ops: Dict[int, List[Op]] = {}
     marks: Dict[str, Interval] = {}
-    host_ops: List[Op] = []
+    host_ops: Dict[int, List[Op]] = {}  # device ordinal -> stand-ins
     for plane in data.planes:
         if plane.name.startswith(DEVICE_PLANE):
             tail = plane.name[len(DEVICE_PLANE):].split()[0]
@@ -122,13 +124,14 @@ def read(path: str) -> Trace:
                     elif not chips:
                         stats = dict(e.stats)
                         if "hlo_op" in stats:
-                            host_ops.append(Op(
+                            ordinal = int(stats.get("device_ordinal", 0))
+                            host_ops.setdefault(ordinal, []).append(Op(
                                 e.start_ns / 1e9,
                                 (e.start_ns + e.duration_ns) / 1e9, e.name,
                                 str(stats.get("hlo_module", ""))))
     stands_in = not chips and bool(host_ops)
     if stands_in:
-        chips[0] = host_ops
+        chips.update(host_ops)
     for ops in list(chips.values()) + list(async_ops.values()):
         ops.sort(key=lambda o: o.start)
     return Trace(chips, async_ops, marks, stands_in)

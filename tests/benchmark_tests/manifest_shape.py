@@ -8,6 +8,9 @@ None depends on where an entry stands in its list or on how many
 entries there are: a later PR appends a configuration, a cell and
 per-layer metrics, edits no file under the manifest's ``paths``, and
 every check here still passes (``benchmark/specs.py`` has the rule).
+That holds for the cells that may stand outside the manifest and the
+readers that may wait for a cell on several chips, below: entering one
+is an appended entry, and nothing here is edited for it.
 """
 
 import json
@@ -16,10 +19,16 @@ import re
 
 from benchmark import loadgen, run, specs
 
-#: defined as files and run by these tests, but not entered in the
-#: manifest: on the chip a pass takes 15-26 s (PERF.md, PR 22)
-NOT_ENTERED = ["tpch_sf1.mesh_join"]
-#: readers that wait for a cell on several chips: no manifest entry yet
+#: cells defined as files and run by these tests that may stand outside
+#: the manifest: on the chip a statement of ``tpch_sf1.mesh_join`` takes
+#: 15-26 s while the host re-traces the mesh programs (PERF.md section 4).
+#: Entering one is an appended ``workloads`` entry and needs no edit
+#: here: a cell listed here and entered passes too
+MAY_STAND_OUTSIDE = ("tpch_sf1.mesh_join",)
+#: readers that may wait for a cell on several chips: absent from
+#: ``per_layer`` until one is entered; once listed, their entry names in
+#: ``workloads`` only manifest cells on two chips or more (the reader
+#: itself carries no list: it says nothing on one chip)
 WAITING_READERS = ("collective_ms", "min_chip_busy_pct")
 #: what every cell reports end to end
 END_TO_END = {"pass_p50_ms", "rows_per_s", "peak_hbm_gb", "setup_s"}
@@ -63,16 +72,39 @@ def cell_resolves_to_its_files(manifest: dict, root: str, cell: str) -> None:
     assert sorted(config["reduced"]) == sorted(loaded.config["reduced"])
 
 
+def manifest_and_files_name_the_same_cells(manifest: dict,
+                                            root: str) -> None:
+    """Every manifest cell has its files; every cell with files is in
+    the manifest or may stand outside it; no cell is named twice."""
+    entered, defined = cells(manifest), set(specs.cell_names(root))
+    assert len(set(entered)) == len(entered), "a cell entered twice"
+    assert set(entered) <= defined, "an entered cell without its files"
+    assert set(MAY_STAND_OUTSIDE) <= defined, \
+        "a cell let stand outside without its files"
+    assert defined <= set(entered) | set(MAY_STAND_OUTSIDE), \
+        "a cell with files, neither entered nor let stand outside"
+
+
+def waiting_reader_names_mesh_cells(manifest: dict, metric: str) -> None:
+    """A waiting reader that is listed names, in ``workloads``, only
+    manifest cells on two chips or more."""
+    chips = {w["name"]: w["chips"] for w in manifest["workloads"]}
+    listed = entry_of(manifest, metric).get("workloads")
+    assert listed, f"{metric}: a waiting reader's entry lists its cells"
+    assert len(set(listed)) == len(listed)
+    assert all(chips.get(c, 0) >= 2 for c in listed), listed
+
+
 def manifest_and_files_name_the_same_things(manifest: dict,
                                             root: str) -> None:
-    assert sorted(cells(manifest) + NOT_ENTERED) == specs.cell_names(root)
+    manifest_and_files_name_the_same_cells(manifest, root)
     assert manifest["command"] == ["python3", "benchmark/run.py"]
     assert manifest["paths"] == ["benchmark", "tests/benchmark_tests"]
-    found = {n: r.UNIT for n, r in readers(root).items()
-             if n not in WAITING_READERS}
     listed = {m["name"]: m["unit"] for m in manifest["per_layer"]}
-    assert found == listed
     assert len(listed) == len(manifest["per_layer"])  # no name twice
+    waiting = set(WAITING_READERS) - set(listed)
+    found = {n: r.UNIT for n, r in readers(root).items() if n not in waiting}
+    assert found == listed
     end_to_end = {m["name"] for m in manifest["end_to_end"]}
     assert END_TO_END <= end_to_end
     assert {m["moves"] for m in manifest["per_layer"]} <= end_to_end
@@ -82,7 +114,7 @@ def manifest_and_files_name_the_same_things(manifest: dict,
         {w["config"] for w in manifest["workloads"]})
     assert len(set(configs)) == len(configs)
     # a configuration under a mix is one cell: the pair is given once,
-    # the cell not entered included
+    # the cells standing outside included
     pairs = [(c["config"], c["traffic"]) for c in (
         specs.read_json(root, "workloads", n + ".json")
         for n in specs.cell_names(root))]
@@ -128,8 +160,14 @@ def metric_entry_is_sound(manifest: dict, root: str, metric: str) -> None:
 def manifest_and_reader_name_the_same_cells(manifest: dict, root: str,
                                             metric: str) -> None:
     """A general metric has no list in either place; a mechanism's has
-    the same list in both, of cells the manifest has, each once."""
+    the same list in both, of cells the manifest has, each once; a
+    waiting reader's list is in the manifest alone, of cells on several
+    chips."""
     listed = entry_of(manifest, metric).get("workloads")
+    if metric in WAITING_READERS:
+        assert getattr(readers(root)[metric], "WORKLOADS", None) is None
+        waiting_reader_names_mesh_cells(manifest, metric)
+        return
     assert listed == getattr(readers(root)[metric], "WORKLOADS", None)
     if listed is not None:
         assert listed and set(listed) <= set(cells(manifest))

@@ -15,6 +15,16 @@ copy of the manifest with one entry appended to ``configs`` and
 ``workloads`` and two to ``per_layer``.  The shape checks are order-free
 both ways, so they pass as well with the two ``per_layer`` entries in
 front of the last one (which place the driver accepts is the driver's).
+
+Then the four-chip cell whose files are already there,
+``tpch_sf1.mesh_join``, enters the same copy as its ``model_config`` PR
+will enter it: by entries alone, one appended to ``workloads`` and the
+two readers that wait for it, ``collective_ms`` and
+``min_chip_busy_pct``, appended to ``per_layer`` with its name in
+``workloads``.  No file of the copy changes; every check that iterates
+the manifest, those of ``test_general_metrics.py`` per cell included,
+passes in both entry orders; the cell runs traced on four virtual
+devices and prints both readers.
 XLA:CPU, ``--cpu-rehearsal``, SF0.01, one copy for the whole file.
 """
 
@@ -27,6 +37,7 @@ import pytest
 
 import bench_rehearsal as rehearsal
 import manifest_shape as shape
+import test_general_metrics as general
 from benchmark import specs
 
 CONFIG = "tpch_next"
@@ -259,6 +270,128 @@ def test_the_shape_checks_refuse_a_pair_that_disagrees(grown, spoil, check):
     check(grown["appended"], grown["root"])
     spoiled = copy.deepcopy(grown["appended"])
     spoil(spoiled)
+    with pytest.raises(AssertionError):
+        check(spoiled, grown["root"])
+    with pytest.raises(AssertionError):
+        shape.every_check(spoiled, grown["root"])
+
+
+# -- the four-chip cell enters by entries alone ------------------------------
+
+MESH_CELL = "tpch_sf1.mesh_join"
+#: the waiting readers, each with which way is better
+MESH_READERS = {"collective_ms": "lower", "min_chip_busy_pct": "higher"}
+
+
+def _mesh_entries(root: str) -> dict:
+    """The manifest's side of the four-chip cell: its ``workloads``
+    entry as its file has it, and the two readers that wait for it."""
+    cell = specs.read_json(root, "workloads", MESH_CELL + ".json")
+    units = shape.readers(root)
+    return {
+        "workloads": [{k: cell[k] for k in
+                       ("name", "config", "traffic", "chips", "why")}],
+        "per_layer": [
+            {"name": name, "unit": units[name].UNIT, "better": better,
+             "source": "device_trace", "layer": "Mesh tier / exchange",
+             "moves": "pass_p50_ms", "workloads": [MESH_CELL]}
+            for name, better in MESH_READERS.items()],
+    }
+
+
+@pytest.fixture(scope="module")
+def mesh_entered(grown):
+    added = _mesh_entries(grown["root"])
+    appended = copy.deepcopy(grown["appended"])
+    before_last = copy.deepcopy(grown["appended"])
+    for group, entries in added.items():
+        appended[group] += entries
+        before_last[group][-1:-1] = entries
+    result = rehearsal.last_line(rehearsal.run_cell(
+        grown["checkout"], MESH_CELL, trace=1, devices=4, seconds=3.0))
+    return {"appended": appended, "before_last": before_last,
+            "added": added, "after": _hashes(grown["root"]),
+            "result": result}
+
+
+@pytest.mark.parametrize("placed", ["appended", "before_last"])
+def test_the_mesh_cell_enters_by_entries_alone(grown, mesh_entered,
+                                               monkeypatch, placed):
+    manifest, root = mesh_entered[placed], grown["root"]
+    assert manifest["workloads"][-1 if placed == "appended" else -2][
+        "name"] == MESH_CELL
+    assert MESH_CELL in shape.MAY_STAND_OUTSIDE  # listed and entered
+    # the files, the cells and their chips, every entry and its reader
+    shape.every_check(manifest, root)
+    for cell in shape.cells(manifest):
+        for name in sorted(general.SCOPED):
+            general.scope_reader_reports(monkeypatch, name, cell, root)
+        general.hbm_roofline_reports(monkeypatch, cell, root)
+
+
+def test_the_mesh_cell_edits_no_file(grown, mesh_entered):
+    assert mesh_entered["after"] == grown["after"]
+    parent = grown["appended"]
+    for group, entries in mesh_entered["added"].items():
+        grew = mesh_entered["appended"][group]
+        assert grew[:len(parent[group])] == parent[group]
+        assert grew[len(parent[group]):] == entries
+
+
+def test_the_mesh_cell_runs_traced_with_both_readers(grown, mesh_entered):
+    result = mesh_entered["result"]
+    assert result["correct"] is True and result["failed"] == 0
+    assert result["device"]["count"] == 4
+    assert set(MESH_READERS) <= set(result["metrics"])
+    assert result["metrics"]["min_chip_busy_pct"]["value"] > 0
+    # named nowhere else: every general metric an old cell prints
+    general_names = {m["name"] for m in mesh_entered["appended"]["per_layer"]
+                     if "workloads" not in m}
+    old = set(grown["runs"][OLD_CELL]["metrics"])
+    assert old & general_names <= set(result["metrics"])
+
+
+def _entered_twice(m, monkeypatch):
+    m["workloads"].append(copy.deepcopy(shape.entry_of(m, MESH_CELL,
+                                                       "workloads")))
+
+
+def _neither_entered_nor_let_stand_outside(m, monkeypatch):
+    m["workloads"].remove(shape.entry_of(m, MESH_CELL, "workloads"))
+    for name in MESH_READERS:
+        m["per_layer"].remove(shape.entry_of(m, name))
+    monkeypatch.setattr(shape, "MAY_STAND_OUTSIDE", ())
+
+
+def _a_waiting_reader_without_its_cells(m, monkeypatch):
+    del shape.entry_of(m, "collective_ms")["workloads"]
+
+
+def _a_waiting_reader_for_a_one_chip_cell(m, monkeypatch):
+    shape.entry_of(m, "min_chip_busy_pct")["workloads"].append(OLD_CELL)
+
+
+def _a_reader_that_does_not_wait_left_out(m, monkeypatch):
+    m["per_layer"].remove(shape.entry_of(m, "protocol_ms"))
+
+
+@pytest.mark.parametrize("spoil,check", [
+    (_entered_twice, shape.manifest_and_files_name_the_same_cells),
+    (_neither_entered_nor_let_stand_outside,
+     shape.manifest_and_files_name_the_same_cells),
+    (_a_waiting_reader_without_its_cells, _same_cells("collective_ms")),
+    (_a_waiting_reader_for_a_one_chip_cell, _same_cells("min_chip_busy_pct")),
+    (_a_reader_that_does_not_wait_left_out,
+     shape.manifest_and_files_name_the_same_things),
+], ids=lambda f: getattr(f, "__name__", "").strip("_") or None)
+def test_the_mesh_rules_refuse_a_pair_that_disagrees(grown, mesh_entered,
+                                                      monkeypatch, spoil,
+                                                      check):
+    """Each rule has been seen to fail, on a copy of the manifest with
+    the mesh cell entered: it passes as grown, and not once spoiled."""
+    check(mesh_entered["appended"], grown["root"])
+    spoiled = copy.deepcopy(mesh_entered["appended"])
+    spoil(spoiled, monkeypatch)
     with pytest.raises(AssertionError):
         check(spoiled, grown["root"])
     with pytest.raises(AssertionError):

@@ -34,7 +34,8 @@ def test_every_cell_resolves_to_its_files(cell):
 
 
 def test_manifest_and_files_name_the_same_things():
-    """``shape.NOT_ENTERED`` accounts for the files of the cell above."""
+    """``shape.MAY_STAND_OUTSIDE`` lets the cell above, whose files are
+    there, stand outside the manifest."""
     shape.manifest_and_files_name_the_same_things(MANIFEST, specs.ROOT)
 
 
@@ -99,8 +100,8 @@ def test_mesh_cell_runs_end_to_end_traced(checkout):
     assert set(result["metrics"]) <= {r.NAME for r in run.layer_metric_readers()}
     assert {"planning_ms", "protocol_ms", "executor_host_ms", "device_busy_ms",
             "device_idle_pct", "compiles_in_window", "first_call_s", "load_s",
-            "collective_ms"} <= set(result["metrics"])
-    assert "hbm_roofline_pct" not in result["metrics"]  # one-chip cells
+            "collective_ms", "min_chip_busy_pct"} <= set(result["metrics"])
+    assert "hbm_roofline_pct" not in result["metrics"]  # no peak for the CPU
     assert "setup_s" not in result["metrics"]  # end to end: untraced run
     assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
     for entries in result["breakdown"].values():

@@ -4,11 +4,15 @@
 ``hbm_roofline_pct`` report in every cell, those later PRs add too: 0
 where a traced pass ran nothing under the reader's scope, nothing where
 the run cannot say.  On passes made by hand, as ``test_fkjoin_cell.py``
-makes them.  Also here: ``scopes.operations`` drops the control flow
-around the operations of a line, and ``tables.load`` takes a connector
-that declares neither sort order nor bucketing.
+makes them, with each cell's row counts from its own configuration's
+generator.  The per-cell checks are functions of ``(cell, root)``, so
+``test_a_cell_enters_as_files.py`` runs them over a grown copy too.
+Also here: ``scopes.operations`` drops the control flow around the
+operations of a line, and ``tables.load`` takes a connector that
+declares neither sort order nor bucketing.
 """
 
+import functools
 import os
 
 import pytest
@@ -28,26 +32,39 @@ SCOPED = {
     "agg_sort_ms": ("op:Aggregation", "agg:sort"),
 }
 DELISTED = sorted(SCOPED) + ["hbm_roofline_pct"]
-ROW_COUNTS = {"customer": 150_000, "orders": 1_500_000,
-              "lineitem": 6_001_215, "part": 200_000}
+PEAK = 819e9
 
 
-def _made_run(monkeypatch, cell, ops, chips=None, peaks=True):
+@functools.lru_cache(maxsize=None)
+def row_counts(config: str, root: str = specs.ROOT) -> dict:
+    """Rows of each resident table of the configuration at its scale,
+    as its own generator counts them: a configuration a later PR adds
+    brings its own tables and counts."""
+    loaded = specs.read_json(root, "configs", config + ".json")
+    conn = tables.generator_for(loaded, root)
+    return {table: conn.row_count(table) for table in loaded["tables"]}
+
+
+def _made_run(monkeypatch, cell, ops, chips=None, peaks=True,
+              root=specs.ROOT, busy=None):
     """A ``run.Run`` of ``cell`` with one traced pass of one second,
     ``ops`` as chip 0's scoped operations and chip 0 busy for the first
-    half of the pass."""
-    loaded = specs.load_cell(cell)
+    half of the pass (``busy``: chip -> seconds from the pass's start,
+    where given)."""
+    loaded = specs.load_cell(cell, root)
     if chips is not None:
         loaded = specs.Cell(loaded.name, chips, loaded.config, loaded.traffic,
                             loaded.layout, loaded.queries)
     one = loadgen.Pass(0, 1, 0.0, [
         loadgen.QueryRecord(q.name, i, 0.0, 0.0, True, stats={})
         for i, q in enumerate(loaded.queries)])
-    trace = xplane.Trace({0: [xplane.Op(0.0, 0.5, "fusion", "jit_f")]},
+    trace = xplane.Trace({c: [xplane.Op(0.0, s, "fusion", "jit_f")]
+                          for c, s in (busy or {0: 0.5}).items()},
                          {}, {}, False)
     run = bench_run.Run(
-        loaded, {}, {"hbm_bytes_per_s": 819e9} if peaks else None,
-        ROW_COUNTS, {}, [one], {}, trace=trace, traced=[one])
+        loaded, {}, {"hbm_bytes_per_s": PEAK} if peaks else None,
+        row_counts(loaded.config["name"], root), {}, [one], {}, trace=trace,
+        traced=[one])
     run.__dict__["pass_intervals"] = [(0.0, 1.0)]
     monkeypatch.setattr(scopes, "for_run", lambda r: ops)
     return run
@@ -59,40 +76,76 @@ def test_no_list_in_reader_or_manifest(name):
     assert "very cell" in READERS[name].__doc__  # "Every cell ..."
 
 
+def scope_reader_reports(monkeypatch, name, cell, root=specs.ROOT):
+    """Whatever the cell: the scope's time where a pass ran under it,
+    0.0 on a scoped pass without it, None on a trace without scopes."""
+    reader = shape.readers(root)[name]
+    mine = [scopes.ScopedOp(0.10, 0.35, "jit_f", SCOPED[name]),
+            scopes.ScopedOp(0.40, 0.50, "jit_g", ("op:Filter",))]
+    run = _made_run(monkeypatch, cell, mine, root=root)
+    assert reader.read(run) == pytest.approx(250.0)
+    others = [scopes.ScopedOp(0.10, 0.35, "jit_f", ("op:TopN",)),
+              scopes.ScopedOp(0.40, 0.50, "jit_g", ())]
+    assert reader.read(_made_run(monkeypatch, cell, others, root=root)) == 0.0
+    assert reader.read(_made_run(monkeypatch, cell, None, root=root)) is None
+
+
+def hbm_roofline_reports(monkeypatch, cell, root=specs.ROOT):
+    """The reader decides by what it finds: a peak on record, a device
+    plane; on N chips it divides by N peaks.  Never 0: nothing where it
+    cannot say."""
+    reader = shape.readers(root)["hbm_roofline_pct"]
+
+    def made(**kw):
+        return _made_run(monkeypatch, cell, None, root=root, **kw)
+
+    run = made()
+    need = bytes_needed.pass_bytes(run.cell.config, run.cell.queries,
+                                   run.row_counts)
+    assert need > 0
+    assert reader.read(run) == pytest.approx(
+        100.0 * need / (run.cell.chips * PEAK) / 0.5)
+    for chips in (1, 4):  # the cell's own count or not
+        assert reader.read(made(chips=chips)) == pytest.approx(
+            100.0 * need / (chips * PEAK) / 0.5)
+    assert reader.read(made(peaks=False)) is None
+    stood_in = made()
+    stood_in.trace.stands_in = True  # the CPU rehearsal: no device plane
+    assert reader.read(stood_in) is None
+    idle = made()
+    idle.trace.chips[0] = []
+    assert reader.read(idle) is None
+
+
 @pytest.mark.parametrize("cell", CELLS)
 @pytest.mark.parametrize("name", sorted(SCOPED))
 def test_scope_reader_reports_in_every_cell(monkeypatch, name, cell):
-    """Whatever the cell: the scope's time where a pass ran under it,
-    0.0 on a scoped pass without it, None on a trace without scopes."""
-    mine = [scopes.ScopedOp(0.10, 0.35, "jit_f", SCOPED[name]),
-            scopes.ScopedOp(0.40, 0.50, "jit_g", ("op:Filter",))]
-    run = _made_run(monkeypatch, cell, mine)
-    assert READERS[name].read(run) == pytest.approx(250.0)
-    others = [scopes.ScopedOp(0.10, 0.35, "jit_f", ("op:TopN",)),
-              scopes.ScopedOp(0.40, 0.50, "jit_g", ())]
-    assert READERS[name].read(_made_run(monkeypatch, cell, others)) == 0.0
-    assert READERS[name].read(_made_run(monkeypatch, cell, None)) is None
+    scope_reader_reports(monkeypatch, name, cell)
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_hbm_roofline_reports_in_every_one_chip_cell_with_a_peak(
         monkeypatch, cell):
-    """The reader decides by what it finds: one chip, a peak on record,
-    a device plane.  Never 0: nothing where it cannot say."""
+    """Every cell of the manifest, and the same cell taken on four
+    chips: there the value is the mesh's, over four peaks."""
+    hbm_roofline_reports(monkeypatch, cell)
+
+
+def test_hbm_roofline_on_a_mesh_reads_the_busiest_chip(monkeypatch):
+    """Four chips busy 0.5, 0.8, 0.2 and 0.0 s of the pass: the time is
+    the busiest one's, the peak four chips'."""
     reader = READERS["hbm_roofline_pct"]
-    run = _made_run(monkeypatch, cell, None)
+    run = _made_run(monkeypatch, "tpch_sf1.mesh_join", None,
+                    busy={0: 0.5, 1: 0.8, 2: 0.2, 3: 0.0})
+    assert run.cell.chips == 4
+    assert run.busy_s_per_pass() == [0.5]  # chip 0's, as before
+    assert run.busiest_s_per_pass() == [0.8]
     need = bytes_needed.pass_bytes(run.cell.config, run.cell.queries,
-                                   ROW_COUNTS)
-    assert need > 0
-    assert reader.read(run) == pytest.approx(100.0 * need / 819e9 / 0.5)
-    assert reader.read(_made_run(monkeypatch, cell, None, peaks=False)) is None
-    assert reader.read(_made_run(monkeypatch, cell, None, chips=4)) is None
-    stood_in = _made_run(monkeypatch, cell, None)
-    stood_in.trace.stands_in = True  # the CPU rehearsal: no device plane
-    assert reader.read(stood_in) is None
-    idle = _made_run(monkeypatch, cell, None)
-    idle.trace.chips[0] = []
-    assert reader.read(idle) is None
+                                   run.row_counts)
+    assert reader.read(run) == pytest.approx(100.0 * need / (4 * PEAK) / 0.8)
+    # on one chip the busiest is chip 0, to the bit
+    one = _made_run(monkeypatch, "tpch_sf1.join_agg", None)
+    assert one.busiest_s_per_pass() == one.busy_s_per_pass() == [0.5]
 
 
 # -- control flow in an ``XLA Ops`` line -------------------------------------

@@ -1,17 +1,22 @@
 """The reduction from a profiler trace to numbers (benchmark/xplane.py)
 against a small trace recorded on one ``TPU v5 lite`` (q6 over the
-SF0.01 rehearsal tables, four statements; benchmark/testdata/), and the
-bytes-needed function against values computed by hand."""
+SF0.01 rehearsal tables, four statements; benchmark/testdata/), the
+bytes-needed function against values computed by hand, and
+``hbm_roofline_pct`` over the recorded one-chip pass and the recorded
+four-chip statement."""
 
 import os
 
 import pytest
 
 import bench_rehearsal as rehearsal
-from benchmark import bytes_needed, specs, xplane
+from benchmark import bytes_needed, loadgen, run as bench_run, specs, tables
+from benchmark import xplane
 
-TRACE = os.path.join(rehearsal.BENCHMARK, "testdata",
-                     "tiny_q6_v5e.xplane.pb.gz")
+TESTDATA = os.path.join(rehearsal.BENCHMARK, "testdata")
+TRACE = os.path.join(TESTDATA, "tiny_q6_v5e.xplane.pb.gz")
+MESH_TRACE = os.path.join(TESTDATA, "mesh_q14_v5e_4chips.xplane.pb.gz")
+PASS_TRACE = os.path.join(TESTDATA, "join_agg_pass_v5e.xplane.pb.gz")
 
 
 @pytest.fixture(scope="module")
@@ -70,8 +75,7 @@ def test_recorded_four_chip_trace_one_mesh_statement():
     """One q14 of ``tpch_sf1.mesh_join`` on four ``TPU v5 lite`` chips
     (26.6 s, text new to the server): every chip busy 1.43 s at the end
     of it, and no collective operation ran."""
-    mesh = xplane.read(os.path.join(rehearsal.BENCHMARK, "testdata",
-                                    "mesh_q14_v5e_4chips.xplane.pb.gz"))
+    mesh = xplane.read(MESH_TRACE)
     assert sorted(mesh.chips) == [0, 1, 2, 3]
     assert [len(mesh.chips[c]) for c in range(4)] == [3707, 2700, 2700, 2700]
     (lo, hi), = mesh.marks.values()
@@ -88,6 +92,59 @@ def test_recorded_four_chip_trace_one_mesh_statement():
     assert xplane.covered(merged, lo + 2.0, hi - 3.0) == 0.0
     assert xplane.op_totals(mesh.chips[0], lo, hi, top=1)[0][0] \
         == "jit_per_device_wave/fusion.23"
+
+
+def _recorded_run(cell, path):
+    """A ``run.Run`` of ``cell`` whose one traced pass is the recorded
+    trace's statements in order, with the ``TPU v5 lite`` peak of
+    ``peaks.json`` and the row counts of the cell's configuration."""
+    trace = xplane.read(path)
+    one = loadgen.Pass(0, 1, 0.0, [
+        loadgen.QueryRecord(mark.split(":")[1], int(mark.split(":")[2]),
+                            0.0, 0.0, True, stats={})
+        for mark in sorted(trace.marks, key=lambda m: trace.marks[m])])
+    loaded = specs.load_cell(cell)
+    conn = tables.generator_for(loaded.config)
+    rows = {t: conn.row_count(t) for t in loaded.config["tables"]}
+    peaks = specs.read_json(specs.ROOT, "peaks.json")["TPU v5 lite"]
+    return bench_run.Run(loaded, {}, peaks, rows, {}, [one], {},
+                         trace=trace, traced=[one])
+
+
+def _hbm_roofline():
+    return {r.NAME: r for r in bench_run.layer_metric_readers()}[
+        "hbm_roofline_pct"]
+
+
+def test_hbm_roofline_on_the_recorded_mesh_statement_by_hand():
+    """One q14 on four chips: SF1's q14 bytes over four chips' peak,
+    over the time the busiest chip (chip 3, not chip 0) was busy."""
+    run = _recorded_run("tpch_sf1.mesh_join", MESH_TRACE)
+    assert run.cell.chips == 4 and run.row_counts["lineitem"] == 5_997_031
+    # lineitem: l_partkey 8 + l_extendedprice 8 + l_discount 8 +
+    # l_shipdate 4; part: p_partkey 8 + p_type 4
+    need = 28 * 5_997_031 + 12 * 200_000
+    assert bytes_needed.pass_bytes(run.cell.config, run.cell.queries,
+                                   run.row_counts) == need == 170_316_868
+    (lo, hi), = run.pass_intervals
+    per_chip = [xplane.covered(xplane.busy(run.trace.chips[c]), lo, hi)
+                for c in range(4)]
+    busiest = max(per_chip)
+    assert per_chip.index(busiest) == 3 and busiest > per_chip[0]
+    assert busiest == pytest.approx(1.43465533, rel=1e-8)
+    assert run.busiest_s_per_pass() == [busiest]
+    value = _hbm_roofline().read(run)
+    assert value == pytest.approx(100.0 * need / (4 * 819e9) / busiest,
+                                  rel=1e-12)
+    assert value == pytest.approx(0.0036238164567567, rel=1e-9)
+
+
+def test_hbm_roofline_on_the_recorded_one_chip_pass_reads_as_the_parent():
+    """One pass of ``tpch_sf1.join_agg`` (q3 then q14): the number the
+    one-chip reader read before the mesh formula, to the bit."""
+    run = _recorded_run("tpch_sf1.join_agg", PASS_TRACE)
+    assert run.busiest_s_per_pass() == run.busy_s_per_pass()
+    assert _hbm_roofline().read(run) == 0.019584653520843178
 
 
 Op = xplane.Op
