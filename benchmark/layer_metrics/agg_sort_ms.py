@@ -1,8 +1,9 @@
 """Kernels: per traced pass, the time chip 0 ran operations under the
-scope ``agg:sort`` at any depth (``ops/aggregate._sorted_group_ids``,
-``_presorted_group_ids``: the argsort of the packed keys and the
-group-id scatter); median over the traced passes.  Every cell; 0 where
-no operation of a pass ran under the scope (every aggregation of the
+scope ``agg:sort`` at any depth (``ops/aggregate._sorted_runs``: the
+one sort of the key with the batch's lanes, and the run boundaries;
+``_presorted_group_ids``: the run boundaries of input that arrives
+grouped); median over the traced passes.  Every cell; 0 where no
+operation of a pass ran under the scope (every aggregation of the
 pass took the masked-reduce path, or there is none), nothing where the
 trace has no scopes at all."""
 

@@ -1,6 +1,7 @@
 """Executor: per traced pass, the summed ``host_read:<why>`` spans of
-its queries (``exec/local.host_read``: the host waiting for a device
-value in mid-query), ``host_read:result`` left out because the
+its queries (``obs/reads.host_read``: the host waiting for a device
+value in mid-query, and the plan's ``host_read:row_count`` reads at
+bind time too), ``host_read:result`` left out because the
 ``device_get`` span has it; median over the traced passes.  A program
 without such spans reports nothing."""
 

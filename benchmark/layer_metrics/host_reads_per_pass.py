@@ -1,7 +1,7 @@
 """Executor: the server's ``hostReads`` (statement stats: blocking
-device reads the query made, ``exec/local.host_read``) summed per
-pass; median over the window's passes.  A program without the counter
-reports nothing."""
+device reads the query made across ``execute``, ``obs/reads.host_read``)
+summed per pass; median over the window's passes.  A program without
+the counter reports nothing."""
 
 from benchmark import stats
 

@@ -21,17 +21,27 @@ Then the four-chip cell whose files are already there,
 will enter it: by entries alone, one appended to ``workloads`` and the
 two readers that wait for it, ``collective_ms`` and
 ``min_chip_busy_pct``, appended to ``per_layer`` with its name in
-``workloads``.  No file of the copy changes; every check that iterates
-the manifest, those of ``test_general_metrics.py`` per cell included,
-passes in both entry orders; the cell runs traced on four virtual
-devices and prints both readers.
-XLA:CPU, ``--cpu-rehearsal``, SF0.01, one copy for the whole file.
+``workloads``.  Where the repo's manifest lists the cell already, its
+entries are the ones that name it and nothing is appended twice.  No
+file of the copy changes; every check that iterates the manifest, those
+of ``test_general_metrics.py`` per cell included, passes in both entry
+orders; the cell runs traced on four virtual devices and prints both
+readers.
+
+Last, the entered state itself: a file copy of the grown copy whose own
+``BENCHMARK.json`` has the cell entered, with a reader of its own
+mechanism besides the two waiting ones, stands for the repo's manifest
+once that PR is in, and every check and the same placement of the cell
+pass on it too.
+XLA:CPU, ``--cpu-rehearsal``, SF0.01, one rehearsal copy for the whole
+file.
 """
 
 import copy
 import hashlib
 import json
 import os
+import shutil
 
 import pytest
 
@@ -299,43 +309,98 @@ def _mesh_entries(root: str) -> dict:
     }
 
 
+def _names_the_cell(entry: dict) -> bool:
+    """A ``workloads`` entry of the cell, or a ``per_layer`` entry whose
+    ``workloads`` lists it."""
+    return entry["name"] == MESH_CELL or MESH_CELL in entry.get(
+        "workloads", ())
+
+
+def _mesh_placements(manifest: dict, root: str) -> dict:
+    """The four-chip cell entered into ``manifest``, whatever its state.
+    Where the manifest lists the cell, ``added`` is every entry that
+    names it, the waiting readers and any reader of its own mechanism,
+    and ``appended`` is the manifest as it stands; where it does not,
+    ``added`` is ``_mesh_entries`` and ``appended`` the manifest with
+    them at the ends.  ``without`` has no entry that names the cell,
+    and ``before_last`` is ``without`` with ``added`` one place in from
+    the end of each group."""
+    entered = MESH_CELL in shape.cells(manifest)
+    if entered:
+        added = {group: [copy.deepcopy(e) for e in manifest[group]
+                         if _names_the_cell(e)]
+                 for group in ("workloads", "per_layer")}
+    else:
+        added = _mesh_entries(root)
+    without = copy.deepcopy(manifest)
+    for group, entries in added.items():
+        without[group] = [e for e in without[group] if e not in entries]
+    appended = copy.deepcopy(manifest)
+    before_last = copy.deepcopy(without)
+    for group, entries in added.items():
+        if not entered:
+            appended[group] += copy.deepcopy(entries)
+        before_last[group][-1:-1] = copy.deepcopy(entries)
+    return {"entered": entered, "added": added, "without": without,
+            "appended": appended, "before_last": before_last}
+
+
 @pytest.fixture(scope="module")
 def mesh_entered(grown):
-    added = _mesh_entries(grown["root"])
-    appended = copy.deepcopy(grown["appended"])
-    before_last = copy.deepcopy(grown["appended"])
-    for group, entries in added.items():
-        appended[group] += entries
-        before_last[group][-1:-1] = entries
+    placed = _mesh_placements(grown["appended"], grown["root"])
     result = rehearsal.last_line(rehearsal.run_cell(
         grown["checkout"], MESH_CELL, trace=1, devices=4, seconds=3.0))
-    return {"appended": appended, "before_last": before_last,
-            "added": added, "after": _hashes(grown["root"]),
-            "result": result}
+    return {**placed, "after": _hashes(grown["root"]), "result": result}
 
 
-@pytest.mark.parametrize("placed", ["appended", "before_last"])
-def test_the_mesh_cell_enters_by_entries_alone(grown, mesh_entered,
-                                               monkeypatch, placed):
-    manifest, root = mesh_entered[placed], grown["root"]
-    assert manifest["workloads"][-1 if placed == "appended" else -2][
-        "name"] == MESH_CELL
+def _enters_in_every_check(monkeypatch, manifest: dict, root: str) -> None:
+    """The files, the cells and their chips, every entry and its
+    reader, and each cell's scope and roofline reports."""
+    shape.every_check(manifest, root)  # none entered twice among them
+    assert MESH_CELL in shape.cells(manifest)
     assert MESH_CELL in shape.MAY_STAND_OUTSIDE  # listed and entered
-    # the files, the cells and their chips, every entry and its reader
-    shape.every_check(manifest, root)
     for cell in shape.cells(manifest):
         for name in sorted(general.SCOPED):
             general.scope_reader_reports(monkeypatch, name, cell, root)
         general.hbm_roofline_reports(monkeypatch, cell, root)
 
 
+@pytest.mark.parametrize("placed", ["appended", "before_last"])
+def test_the_mesh_cell_enters_by_entries_alone(grown, mesh_entered,
+                                               monkeypatch, placed):
+    manifest = mesh_entered[placed]
+    names = shape.cells(manifest)
+    if placed == "before_last":
+        assert names[-2] == MESH_CELL
+    elif mesh_entered["entered"]:
+        # where the repo's manifest has it, after its own PR's append
+        assert manifest == grown["appended"]
+    else:
+        assert names[-1] == MESH_CELL
+    _enters_in_every_check(monkeypatch, manifest, grown["root"])
+
+
+def _is_without_and_added(placed: dict) -> None:
+    """Each placement is the manifest without the cell plus the cell's
+    entries, in their order, and nothing else."""
+    without, added = placed["without"], placed["added"]
+    for key in ("appended", "before_last"):
+        manifest = placed[key]
+        assert set(manifest) == set(without)
+        for group, value in manifest.items():
+            entries = added.get(group)
+            if entries is None:
+                assert value == without[group], group
+                continue
+            assert [e for e in value if e not in entries] == without[group]
+            assert [e for e in value if e in entries] == entries
+
+
 def test_the_mesh_cell_edits_no_file(grown, mesh_entered):
     assert mesh_entered["after"] == grown["after"]
-    parent = grown["appended"]
-    for group, entries in mesh_entered["added"].items():
-        grew = mesh_entered["appended"][group]
-        assert grew[:len(parent[group])] == parent[group]
-        assert grew[len(parent[group]):] == entries
+    if not mesh_entered["entered"]:
+        assert mesh_entered["without"] == grown["appended"]
+    _is_without_and_added(mesh_entered)
 
 
 def test_the_mesh_cell_runs_traced_with_both_readers(grown, mesh_entered):
@@ -357,9 +422,8 @@ def _entered_twice(m, monkeypatch):
 
 
 def _neither_entered_nor_let_stand_outside(m, monkeypatch):
-    m["workloads"].remove(shape.entry_of(m, MESH_CELL, "workloads"))
-    for name in MESH_READERS:
-        m["per_layer"].remove(shape.entry_of(m, name))
+    for group in ("workloads", "per_layer"):
+        m[group] = [e for e in m[group] if not _names_the_cell(e)]
     monkeypatch.setattr(shape, "MAY_STAND_OUTSIDE", ())
 
 
@@ -375,7 +439,7 @@ def _a_reader_that_does_not_wait_left_out(m, monkeypatch):
     m["per_layer"].remove(shape.entry_of(m, "protocol_ms"))
 
 
-@pytest.mark.parametrize("spoil,check", [
+MESH_SPOILS = pytest.mark.parametrize("spoil,check", [
     (_entered_twice, shape.manifest_and_files_name_the_same_cells),
     (_neither_entered_nor_let_stand_outside,
      shape.manifest_and_files_name_the_same_cells),
@@ -384,15 +448,130 @@ def _a_reader_that_does_not_wait_left_out(m, monkeypatch):
     (_a_reader_that_does_not_wait_left_out,
      shape.manifest_and_files_name_the_same_things),
 ], ids=lambda f: getattr(f, "__name__", "").strip("_") or None)
+
+
+def _refuses(monkeypatch, manifest: dict, root: str, spoil, check) -> None:
+    """The rule passes on ``manifest`` and not once it is spoiled, and
+    so neither does the whole."""
+    check(manifest, root)
+    spoiled = copy.deepcopy(manifest)
+    spoil(spoiled, monkeypatch)
+    with pytest.raises(AssertionError):
+        check(spoiled, root)
+    with pytest.raises(AssertionError):
+        shape.every_check(spoiled, root)
+
+
+@MESH_SPOILS
 def test_the_mesh_rules_refuse_a_pair_that_disagrees(grown, mesh_entered,
                                                       monkeypatch, spoil,
                                                       check):
     """Each rule has been seen to fail, on a copy of the manifest with
     the mesh cell entered: it passes as grown, and not once spoiled."""
-    check(mesh_entered["appended"], grown["root"])
-    spoiled = copy.deepcopy(mesh_entered["appended"])
-    spoil(spoiled, monkeypatch)
-    with pytest.raises(AssertionError):
-        check(spoiled, grown["root"])
-    with pytest.raises(AssertionError):
-        shape.every_check(spoiled, grown["root"])
+    _refuses(monkeypatch, mesh_entered["appended"], grown["root"], spoil,
+             check)
+
+
+# -- the entered state, rehearsed ---------------------------------------------
+
+#: stands for the readers of its own mechanism the cell's PR brings
+MECHANISM = "next_mesh_statements_per_pass"
+MECHANISM_READER = f'''"""The four-chip cell's own: statements per pass."""
+
+NAME = "{MECHANISM}"
+UNIT = "count"
+WORKLOADS = ["{MESH_CELL}"]
+
+
+def read(run):
+    return float(len(run.passes[0].queries)) if run.passes else None
+'''
+
+
+def _copy_with_the_mechanism(source: str, dest: str, leave_out=()) -> None:
+    """``source`` to ``dest`` as files, without what runs leave behind
+    and without ``leave_out`` (paths relative to ``source``), and with
+    the mechanism's reader added."""
+    def ignore(directory, names):
+        return [n for n in names
+                if n in ("__pycache__", ".cache", "trace_out")
+                or os.path.relpath(os.path.join(directory, n), source)
+                in leave_out]
+
+    shutil.copytree(source, dest, ignore=ignore)
+    with open(os.path.join(dest, "layer_metrics", MECHANISM + ".py"),
+              "w") as f:
+        f.write(MECHANISM_READER)
+
+
+@pytest.fixture(scope="module")
+def entered(grown, tmp_path_factory):
+    """The repo once the cell's PR is in, made from the rehearsal's copy
+    by file copies: ``repo_root`` holds the copy's files without the
+    next PR's, and ``repo`` a ``BENCHMARK.json`` with the cell entered
+    and one reader of its own mechanism appended; ``root`` and
+    ``grown`` add the next PR's files and entries to them as the
+    ``grown`` fixture adds them to the repo, and ``placed`` is the
+    four-chip half's placement of the cell in that grown manifest."""
+    new = set(grown["after"]) - set(grown["before"])
+    repo = str(tmp_path_factory.mktemp("bench_entered"))
+    repo_root = os.path.join(repo, "benchmark")
+    _copy_with_the_mechanism(grown["root"], repo_root, leave_out=new)
+    manifest = _mesh_placements(grown["parent"], repo_root)["appended"]
+    manifest["per_layer"].append({
+        "name": MECHANISM, "unit": "count", "better": "lower",
+        "source": "program_counter", "layer": "Mesh tier / exchange",
+        "moves": "pass_p50_ms", "workloads": [MESH_CELL]})
+    _write_json(repo, "BENCHMARK.json", value=manifest)
+    root = os.path.join(str(tmp_path_factory.mktemp("bench_entered_next")),
+                        "benchmark")
+    _copy_with_the_mechanism(grown["root"], root)
+    appended = shape.load(repo)
+    for group, entries in grown["added"].items():
+        appended[group] += copy.deepcopy(entries)
+    return {"repo": shape.load(repo), "repo_root": repo_root,
+            "grown": appended, "root": root,
+            "placed": _mesh_placements(appended, root)}
+
+
+@pytest.mark.parametrize("state", ["repo", "grown", "appended",
+                                   "before_last"])
+def test_the_entered_state_passes_every_check(entered, monkeypatch, state):
+    """With the cell already entered, the repo's manifest, the next
+    PR's growth of it and both placements of the cell in that growth
+    pass every check; the placements append nothing twice: what names
+    the cell is the cell's entry, both waiting readers, the mechanism's
+    reader and whatever readers of its own the repo lists, each once."""
+    placed = entered["placed"]
+    if state == "repo":
+        manifest, root = entered["repo"], entered["repo_root"]
+    else:
+        manifest = entered["grown"] if state == "grown" else placed[state]
+        root = entered["root"]
+    _enters_in_every_check(monkeypatch, manifest, root)
+    shape.is_listed_for(manifest, root, MECHANISM, MESH_CELL)
+    for name in MESH_READERS:
+        shape.manifest_and_reader_name_the_same_cells(manifest, root, name)
+    if state != "repo":
+        assert shape.cells(manifest)[-1] == CELL
+    if state in ("appended", "before_last"):
+        assert placed["entered"]
+        assert [e["name"] for e in placed["added"]["workloads"]] == \
+            [MESH_CELL]
+        names = [e["name"] for e in placed["added"]["per_layer"]]
+        assert len(set(names)) == len(names)
+        assert set(names) >= {*MESH_READERS, MECHANISM}
+        _is_without_and_added(placed)
+        if state == "appended":
+            assert manifest == entered["grown"]
+        else:
+            assert shape.cells(manifest)[-2] == MESH_CELL
+
+
+@MESH_SPOILS
+def test_the_entered_state_refuses_a_pair_that_disagrees(entered,
+                                                         monkeypatch, spoil,
+                                                         check):
+    """The same rules, each seen to fail on the grown manifest in which
+    the cell stood entered before the next PR came."""
+    _refuses(monkeypatch, entered["grown"], entered["root"], spoil, check)

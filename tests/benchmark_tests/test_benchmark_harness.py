@@ -22,7 +22,10 @@ def checkout(tmp_path_factory):
     return rehearsal.make_copy(str(tmp_path_factory.mktemp("bench")))
 
 
-def test_the_cell_not_entered_resolves_too():
+def test_the_four_chip_cell_resolves_entered_or_not():
+    """``tpch_sf1.mesh_join``'s files resolve to four chips, the mesh
+    and the distributed session, whether the manifest lists the cell
+    or lets it stand outside."""
     loaded = specs.load_cell("tpch_sf1.mesh_join")
     assert loaded.chips == 4 and loaded.layout["require_mesh"] is True
     assert loaded.layout["session"] == {"distributed": "true"}
@@ -35,7 +38,7 @@ def test_every_cell_resolves_to_its_files(cell):
 
 def test_manifest_and_files_name_the_same_things():
     """``shape.MAY_STAND_OUTSIDE`` lets the cell above, whose files are
-    there, stand outside the manifest."""
+    there, stand outside the manifest or be entered in it."""
     shape.manifest_and_files_name_the_same_things(MANIFEST, specs.ROOT)
 
 
